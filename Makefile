@@ -1,11 +1,12 @@
 PYTHON ?= python
 
-.PHONY: check test entry hooks chaos chaos-serve bench-serve metrics \
+.PHONY: check test hooks chaos chaos-serve bench-serve metrics \
 	regress mesh paged paged-kernel fleet-mr aot slo governor history \
 	analyze fleetscope servescope deploy elastic replay memscope
 
-# Full commit gate: whole test suite + both driver entry points.
-check: test entry
+# Full commit gate: the whole test suite (CPU). The chip gate is
+# `python chip_smoke.py`, run on the TPU through the chip tool.
+check: test
 
 test:
 	$(PYTHON) -m pytest tests/ -x -q
@@ -63,7 +64,8 @@ paged:
 # marker so tier-1 keeps its timeout margin; this target runs them.)
 paged-kernel:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest \
-		tests/test_paged_kernel.py -m paged_kernel -q
+		tests/test_paged_kernel.py tests/test_tpu_lowering.py \
+		-m paged_kernel -q
 
 # Compiler-visible fleet aggregation suite (docs/compiler_fleet.md):
 # the mapreduce primitives (f32 bit-exact vs psum, bf16/int8 quantized
@@ -257,11 +259,6 @@ memscope:
 aot:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_aot.py \
 		-m aot -q
-
-entry:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import jax, __graft_entry__ as g; \
-fn, args = g.entry(); jax.jit(fn)(*args); print('entry ok')"
-	$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
 # Install the pre-commit test gate into .git/hooks.
 hooks:

@@ -326,7 +326,7 @@ class TestLoopback:
         s2.stop()
 
     def test_n_slave_convergence_parity(self):
-        """VERDICT round-1 weak #7: prove N-slave training converges like
+        """prove N-slave training converges like
         1-slave training on a real dataset (digits, 4 epochs): both must
         reach the same accuracy class."""
         kw = _kw(max_epochs=4, minibatch=300)
@@ -358,7 +358,7 @@ class TestLoopback:
         assert abs(results[1] - results[2]) <= 45, results
 
     def test_average_merge_convergence_tight(self, monkeypatch):
-        """VERDICT r3 #6a: under ``merge="average"`` the blended updates
+        """under ``merge="average"`` the blended updates
         make N-slave convergence deterministic-ish, so the bounds can be
         TIGHT (the async ``overwrite`` test above stays loose — that is
         its nature)."""
@@ -395,7 +395,7 @@ class TestLoopback:
         assert abs(results[1] - results[2]) <= 12, results
 
     def test_fleet_payload_covers_all_leaves_and_solver_state(self):
-        """VERDICT r3 #6b: (1) GD payloads derive from the unit's slot
+        """(1) GD payloads derive from the unit's slot
         contract — GDSelfAttention's out projection rides them (it
         silently desynchronized before); (2) stateful solvers ship
         moments + step both ways; momentum stays weights-only
@@ -615,7 +615,7 @@ class TestChecksum:
 
 class TestSafeCodec:
     """fleet/safecodec.py + the codec="safe" wire mode: a leaked secret
-    must not be remote code execution (VERDICT r2 weak #6)."""
+    must not be remote code execution."""
 
     @pytest.fixture
     def safe_wire(self):

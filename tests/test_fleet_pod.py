@@ -1,4 +1,4 @@
-"""Fleet x pod composition (VERDICT r2 #5 / SURVEY §5's stated
+"""Fleet x pod composition (SURVEY §5's stated
 translation): each fleet slave's one-tick job is the shard_map-ped fused
 step over the slave's LOCAL device mesh — jobs/updates ride the DCN-role
 fleet protocol, the gradient merge inside the tick psums over the

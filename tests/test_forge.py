@@ -200,7 +200,7 @@ class TestForgeRoundtrip:
         assert anon.list() == []
 
     def test_history_and_diff(self, server, tmp_path):
-        """VERDICT r4 #8: upload twice -> history lists both versions
+        """upload twice -> history lists both versions
         chronologically -> fetch either -> diff reports the manifest
         and file-content changes between them (the reference's git-tag
         history, forge_server.py:103-440)."""

@@ -123,7 +123,7 @@ def test_pipelined_data_parallel_matches_single_device():
 
 
 def test_fused_convnet_matches_graph_mode():
-    """Conv + pooling topologies fuse too (VERDICT round-1 item 2)."""
+    """Conv + pooling topologies fuse too."""
     from sklearn.datasets import load_digits
     d = load_digits()
     X = d.images.astype(numpy.float32)[..., None]  # (N, 8, 8, 1) NHWC

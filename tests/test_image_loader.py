@@ -1,5 +1,5 @@
 """Tests for the file/image loader pipeline (reference test_loader
-image-loading coverage + VERDICT round-1 item 4)."""
+image-loading coverage)."""
 
 import os
 
@@ -256,7 +256,7 @@ class TestImageMSE:
 @pytest.mark.slow
 class TestConvnetEndToEnd:
     def test_convnet_trains_through_image_pipeline(self, image_tree):
-        """VERDICT round-1 item 4 'done' criterion: a CIFAR-style convnet
+        """The 'done' criterion: a CIFAR-style convnet
         trains end-to-end through the image pipeline."""
         from veles_tpu.models.standard import StandardWorkflow
 

@@ -1,5 +1,5 @@
 """Tests for the genetics gray tier, fleet task farm, and ensemble
-combiner (VERDICT round-1 items 7-8)."""
+combiner."""
 
 import numpy
 import pytest

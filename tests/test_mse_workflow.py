@@ -1,7 +1,7 @@
 """End-to-end MSE (regression) workflows: the Znicz EvaluatorMSE +
 DecisionMSE model family, and their ride on the partial-fusion tier
 (the full fused engine recognizes softmax chains only — MSE used to be
-one of the VERDICT r2 graph-mode-cliff casualties)."""
+one of the graph-mode-cliff casualties)."""
 
 import numpy
 

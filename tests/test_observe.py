@@ -769,6 +769,21 @@ class TestCompileTracker:
         finally:
             root.common.observe.peak_tflops = saved
 
+    def test_peak_table_matches_device_kind_exactly(self):
+        """Exact keys: "TPU v5" (the v5p) must not claim an unknown v5
+        kind by substring; an unlisted TPU kind raises instead of
+        yielding a missing MFU key; the CPU has no peak without the
+        override."""
+        from veles_tpu.observe import xla_stats
+
+        assert xla_stats.peak_tflops("TPU v5 lite") == 197.0
+        assert xla_stats.peak_tflops("TPU v5") == 459.0
+        with pytest.raises(LookupError, match="TPU v5 ultra"):
+            xla_stats.peak_tflops("TPU v5 ultra")
+        assert xla_stats.peak_tflops() is None  # the CPU platform
+        assert all(source for _, source in
+                   xla_stats.PEAK_BF16_TFLOPS.values())
+
     def test_device_memory_gauges_exist_on_every_backend(self):
         from veles_tpu.observe.xla_stats import publish_device_stats
 

@@ -1,4 +1,4 @@
-"""Golden accuracy-parity harness (VERDICT r2 #3).
+"""Golden accuracy-parity harness.
 
 Offline it always runs: the three reference topology families on the
 real 8x8 UCI digits with ABSOLUTE error bounds (3.0% / 0.7% / 0.7% —

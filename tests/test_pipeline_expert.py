@@ -195,7 +195,7 @@ class TestSequenceParallelTraining:
 
 
 class TestPipelineTraining:
-    """Differentiable pipeline (VERDICT r2 #4): the train step's grads
+    """Differentiable pipeline: the train step's grads
     must match the sequential single-device reference, and training
     must actually reduce the loss."""
 
@@ -300,7 +300,7 @@ class TestPipelineTraining:
 
 
 class TestExpertTraining:
-    """Differentiable MoE (VERDICT r2 #4): grads through dispatch,
+    """Differentiable MoE: grads through dispatch,
     all_to_all and the gate-probability combine."""
 
     def _setup(self, n_experts=8, ep=8, tokens=64, d=16, h=32):

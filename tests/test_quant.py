@@ -164,8 +164,7 @@ def test_cache_attend_scale_folding_matches_explicit_dequant():
     length, dim = kq.shape[1], q.shape[-1]
     inv = 1.0 / numpy.sqrt(dim)
     mask_addend = jnp.zeros(length, jnp.float32)
-    got = int8_cache_attend(q * inv, khm, kshm, vhm, vshm, mask_addend,
-                            use_pallas=False)
+    got = int8_cache_attend(q * inv, khm, kshm, vhm, vshm, mask_addend)
     deq_k = kq.astype(jnp.float32) * ks[..., None]
     deq_v = vq.astype(jnp.float32) * vs[..., None]
     mask = jnp.ones((1, 1, 1, length), bool)
@@ -175,30 +174,10 @@ def test_cache_attend_scale_folding_matches_explicit_dequant():
                                   atol=1e-6)
 
 
-def test_cache_attend_kernel_matches_xla_formulation():
-    """The Pallas dequant-fused attend (interpret mode off-TPU) ==
-    the XLA formulation of the same math, mask included, at a
-    tile-friendly shape."""
-    from veles_tpu.ops.quant import int8_cache_attend
-
-    (q, khm, kshm, vhm, vshm, *_) = _attend_fixture(
-        batch=2, length=128, heads=2, dim=32, seed=11)
-    inv = 1.0 / numpy.sqrt(q.shape[-1])
-    mask_addend = jnp.where(jnp.arange(128) <= 50, 0.0,
-                            -1e30).astype(jnp.float32)
-    want = int8_cache_attend(q * inv, khm, kshm, vhm, vshm,
-                             mask_addend, use_pallas=False)
-    got = int8_cache_attend(q * inv, khm, kshm, vhm, vshm, mask_addend,
-                            use_pallas=True, interpret=True)
-    numpy.testing.assert_allclose(numpy.asarray(got),
-                                  numpy.asarray(want), rtol=2e-5,
-                                  atol=2e-5)
-
-
 def test_cache_attend_per_row_masks_match_per_row_calls():
     """The (B, T) per-row mask form (the slot engine's per-slot
     lengths): each row must equal a separate call with that row's
-    1-D mask — on the XLA formulation and the kernel (interpret)."""
+    1-D mask."""
     from veles_tpu.ops.quant import int8_cache_attend
 
     (q, khm, kshm, vhm, vshm, *_) = _attend_fixture(
@@ -208,17 +187,39 @@ def test_cache_attend_per_row_masks_match_per_row_calls():
     masks = jnp.stack([
         jnp.where(jnp.arange(128) <= n, 0.0, -1e30).astype(jnp.float32)
         for n in lengths])
-    for pallas in (False, True):
-        got = int8_cache_attend(q * inv, khm, kshm, vhm, vshm, masks,
-                                use_pallas=pallas, interpret=True)
-        for row in range(2):
-            want = int8_cache_attend(
-                q[row:row + 1] * inv, khm[row:row + 1],
-                kshm[row:row + 1], vhm[row:row + 1], vshm[row:row + 1],
-                masks[row], use_pallas=pallas, interpret=True)
-            numpy.testing.assert_allclose(
-                numpy.asarray(got[row:row + 1]), numpy.asarray(want),
-                rtol=2e-5, atol=2e-5)
+    got = int8_cache_attend(q * inv, khm, kshm, vhm, vshm, masks)
+    for row in range(2):
+        want = int8_cache_attend(
+            q[row:row + 1] * inv, khm[row:row + 1],
+            kshm[row:row + 1], vhm[row:row + 1], vshm[row:row + 1],
+            masks[row])
+        numpy.testing.assert_allclose(
+            numpy.asarray(got[row:row + 1]), numpy.asarray(want),
+            rtol=2e-5, atol=2e-5)
+
+
+def test_forced_pallas_that_cannot_run_raises():
+    """An explicit use_pallas=True (or FORCE_PALLAS) on a shape the
+    kernel cannot take used to run the XLA product silently — a forced
+    benchmark then measured XLA against XLA. It raises, naming the
+    knob."""
+    import pytest
+    from veles_tpu.ops import quant
+
+    x = jnp.ones((4, 48), jnp.float32)          # k % 32 != 0
+    q8 = jnp.ones((48, 512), jnp.int8)
+    scale = jnp.ones((512,), jnp.float32)
+    with pytest.raises(ValueError, match="use_pallas=True"):
+        quant.int8_matmul(x, q8, scale, use_pallas=True, interpret=True)
+    x = jnp.ones((4, 64), jnp.float32)
+    q8 = jnp.ones((64, 100), jnp.int8)          # n has no lane block
+    prev = quant.FORCE_PALLAS
+    quant.FORCE_PALLAS = True
+    try:
+        with pytest.raises(ValueError, match="FORCE_PALLAS"):
+            quant.int8_matmul(x, q8, jnp.ones((100,), jnp.float32))
+    finally:
+        quant.FORCE_PALLAS = prev
 
 
 def test_quantize_kv_roundtrip_bound():

@@ -1,6 +1,6 @@
 """Regression-sentinel tests (docs/observability.md): the incremental
 atomic BENCH artifact writer, the any-format loader (including the
-VERDICT r5 truncated-tail recovery against the REAL committed
+truncated-tail recovery against the REAL committed BENCH_r05
 artifact), the spread-aware comparator, and the CLI exit codes `make
 regress` gates CI on — the seeded-regression fixture here is the proof
 the gate actually exits nonzero."""
@@ -61,7 +61,7 @@ class TestBenchArtifact:
 class TestLoader:
     def test_recovers_real_r05_truncated_tail(self):
         """The committed round artifact lost its headline to tail
-        truncation (VERDICT r5); the loader must still salvage every
+        truncation; the loader must still salvage every
         complete key so the round stays comparable."""
         keys, info = load_bench(R05)
         assert info["recovered"] is True

@@ -1,6 +1,6 @@
 """Partial fusion (parallel/segments.py): numerical identity + wiring.
 
-The VERDICT r2 "graph-mode cliff" fix, tier 1: any chain of JitUnits —
+The "graph-mode cliff" fix, tier 1: any chain of JitUnits —
 including workflows the full fused engine declines — collapses into
 per-tick composite dispatches with graph-mode numerics.
 """

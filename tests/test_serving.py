@@ -211,7 +211,7 @@ class TestWebStatus:
             assert resp.status == 200
 
     def test_live_workflow_graph(self, server):
-        """VERDICT r3 #8: the dashboard renders the running workflow's
+        """The dashboard renders the running workflow's
         unit DAG (posted by the notifier) as an SVG with activity
         counters — the reference's viz.js graph page."""
         from veles_tpu.dummy import DummyLauncher
@@ -262,7 +262,7 @@ class TestWebStatus:
             assert resp.read().decode().startswith("<svg")
 
     def test_live_stream_pushes_plot_refresh(self, server):
-        """VERDICT r4 #7 (live plot viewing): /stream is an SSE feed —
+        """/stream is an SSE feed —
         one state event on connect, another when a plot file lands or
         is re-rendered (mtime bump) — driving one full refresh cycle
         the way the dashboard JS does."""
@@ -345,7 +345,7 @@ class TestWebStatus:
 
 class TestContinuousDecoder:
     """Continuous batching: sequences joining mid-flight must decode
-    exactly what single-request generate() produces (VERDICT r4 #10)."""
+    exactly what single-request generate() produces."""
 
     @pytest.fixture(scope="class")
     def model(self):

@@ -1,5 +1,5 @@
 """Tests for Kohonen SOM, the AlexNet topology, and the autotune CLI
-(SURVEY §7 item 10 + BASELINE conv anchor + VERDICT item 10)."""
+(SURVEY §7 item 10 + BASELINE conv anchor)."""
 
 import numpy
 import pytest
@@ -127,7 +127,7 @@ class TestAlexNet:
 
 class TestAutotuneCLI:
     def test_cache_roundtrip(self, tmp_path, monkeypatch):
-        """VERDICT item 10: --autotune persists winners and _tuned_blocks
+        """--autotune persists winners and _tuned_blocks
         reads them back (devices/device_infos.json semantics)."""
         from veles_tpu.core.config import root
         from veles_tpu.ops import gemm

@@ -1,6 +1,6 @@
 """Sweep-tier fusion: class-sweep scanning of arbitrary JitUnit chains.
 
-The VERDICT-r3 #1 tier: workflows the full fused engine declines (custom
+The sweep tier: workflows the full fused engine declines (custom
 host units, custom layer types) must reach sweep-granular dispatch, not
 per-tick dispatch, while matching graph mode numerically — metrics
 exactly, weights to fp-reassociation tolerance. Every tier applies the

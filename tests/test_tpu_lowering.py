@@ -1,0 +1,147 @@
+"""Every Pallas kernel a TPU path can select must lower for the TPU.
+
+The fused paged-attention kernel shipped (PR 18) having only ever run
+under ``interpret=True``; its dots put the batch dimension in the
+middle and Mosaic refused them at lowering — on the default TPU serving
+path. Two checks keep that from recurring, neither needing a chip:
+
+- ``jax.export.export(jax.jit(f), platforms=["tpu"])`` runs the
+  Pallas→Mosaic lowering cross-platform, on the CPU, in seconds (tier-1);
+- the installed libtpu can also COMPILE for a v5e it does not have
+  (``jax.experimental.topologies``, a compile-only client), which runs
+  Mosaic's own passes — what it says after lowering (layouts, VMEM,
+  unsupported shape casts). It runs in a subprocess and skips where
+  libtpu cannot describe a topology.
+
+Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
+128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+SLOTS, HEADS, PAGE, POOL, PAGES_PER_SLOT = 8, 16, 128, 49, 6
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+def kernel_cases():
+    """[(name, fn, abstract args)] — every kernel behind a TPU gate."""
+    from veles_tpu.ops import attention, gemm, paged_attention, quant
+
+    table = _sds((SLOTS, PAGES_PER_SLOT), "int32")
+    lengths = _sds((SLOTS,), "int32")
+    cases = []
+    for head_dim in (64, 128):
+        for dtype in ("bfloat16", "float32"):
+            q = _sds((SLOTS, HEADS, head_dim), dtype)
+            pool = _sds((POOL, PAGE, HEADS, head_dim), dtype)
+            cases.append((
+                "paged_attend_d%d_%s" % (head_dim, dtype),
+                lambda q, k, v, pt, ln: paged_attention.paged_attend(
+                    q, k, v, pt, ln, page_size=PAGE, interpret=False),
+                (q, pool, pool, table, lengths)))
+        q = _sds((SLOTS, HEADS, head_dim), "bfloat16")
+        pool = _sds((POOL, HEADS, head_dim, PAGE), "int8")
+        scale = _sds((POOL, HEADS, PAGE), "float32")
+        cases.append((
+            "paged_attend_int8_d%d" % head_dim,
+            lambda q, k, ks, v, vs, pt, ln:
+                paged_attention.paged_attend_int8(
+                    q, k, ks, v, vs, pt, ln, page_size=PAGE,
+                    interpret=False),
+            (q, pool, scale, pool, scale, table, lengths)))
+    for n in (3072, 4096, 32768):
+        cases.append((
+            "int8_matmul_k1024_n%d" % n,
+            lambda x, q8, s: quant.int8_matmul(x, q8, s, use_pallas=True),
+            (_sds((8, 1024), "bfloat16"), _sds((1024, n), "int8"),
+             _sds((n,), "float32"))))
+    a = _sds((512, 1024), "bfloat16")
+    b = _sds((1024, 512), "bfloat16")
+    cases.append(("pallas_matmul", lambda a, b: gemm.pallas_matmul(a, b),
+                  (a, b)))
+    cases.append(("pallas_dense",
+                  lambda a, b, bias: gemm.pallas_dense(
+                      a, b, bias, activation="tanh"),
+                  (a, b, _sds((512,), "float32"))))
+    # flash attention exactly at the _use_pallas_flash gate (T >= 4096,
+    # head_dim % 128 == 0); FORCE_FLASH stands in for the platform probe
+    qkv = _sds((1, 4096, 2, 128), "bfloat16")
+
+    def flash(q, k, v):
+        prev = attention.FORCE_FLASH
+        attention.FORCE_FLASH = True
+        try:
+            return attention.attention(q, k, v, causal=True)
+        finally:
+            attention.FORCE_FLASH = prev
+
+    cases.append(("flash_attention_t4096_d128", flash, (qkv, qkv, qkv)))
+    return cases
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", kernel_cases(), ids=lambda c: c[0])
+def test_kernel_lowers_for_tpu(case):
+    _, fn, args = case
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+_COMPILE_CHILD = """
+import sys
+sys.path.insert(0, %(repo)r)
+sys.path.insert(0, %(tests)r)
+import jax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as exc:
+    print("NO-TOPOLOGY %%s" %% exc)
+    sys.exit(0)
+print("KIND %%s" %% topo.devices[0].device_kind)
+on_chip = SingleDeviceSharding(topo.devices[0])
+import test_tpu_lowering
+for name, fn, args in test_tpu_lowering.kernel_cases():
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip)
+            for a in args]
+    try:
+        jax.jit(fn).lower(*args).compile()
+        print("COMPILED %%s" %% name)
+    except Exception as exc:
+        print("REFUSED %%s: %%s" %% (name, str(exc)[:800].replace("\\n", " | ")))
+"""
+
+
+@pytest.mark.paged_kernel
+def test_kernels_compile_for_v5e_without_a_chip():
+    """The full Mosaic pipeline, offline: AOT-compile every case for a
+    compile-only v5e topology. Skips where libtpu cannot describe one."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c", _COMPILE_CHILD % {
+                "repo": REPO, "tests": os.path.join(REPO, "tests")}],
+            env=env, capture_output=True, text=True, timeout=240)
+    except subprocess.TimeoutExpired:
+        pytest.skip("the compile-only TPU client did not answer here")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    if any(line.startswith("NO-TOPOLOGY") for line in lines):
+        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
+    refused = [line for line in lines if line.startswith("REFUSED")]
+    compiled = [line for line in lines if line.startswith("COMPILED")]
+    assert not refused, "\n".join(refused)
+    assert len(compiled) == len(kernel_cases())

@@ -142,11 +142,6 @@ root.common.update({
         "snapshots": os.path.join(_home, "snapshots"),
         "datasets": os.path.join(_home, "datasets"),
         "events": os.path.join(_home, "events"),
-        # XLA persistent compilation cache: first fused-tick compile on a
-        # TPU costs tens of seconds; subsequent processes reload it from
-        # here (the TPU-era descendant of the reference's kernel binary
-        # cache, accelerated_units.py:605-673)
-        "xla_cache": os.path.join(_home, "cache", "xla"),
         # runtime sockets (manhole) live here, one per pid
         "run": os.path.join(_home, "run"),
     },
@@ -220,13 +215,18 @@ root.common.update({
 })
 
 
+def site_config_paths():
+    """Where site overrides are looked for: /etc, $HOME and the CWD."""
+    return ("/etc/default/veles_tpu.json",
+            os.path.expanduser("~/.veles_tpu/site_config.json"),
+            os.path.join(os.getcwd(), "site_config.json"))
+
+
 def _apply_site_overrides():
     """Layered site configuration (reference ``site_config.py`` and
     ``config.py:292-307``): JSON overrides merged from /etc, $HOME and CWD."""
     import sys
-    for path in ("/etc/default/veles_tpu.json",
-                 os.path.expanduser("~/.veles_tpu/site_config.json"),
-                 os.path.join(os.getcwd(), "site_config.json")):
+    for path in site_config_paths():
         try:
             with open(path, "r") as fin:
                 overrides = json.load(fin)
@@ -243,21 +243,29 @@ def _apply_site_overrides():
 _apply_site_overrides()
 
 
-def _enable_xla_compilation_cache():
-    """Point jax at the persistent compilation cache directory. Must run
-    before the first compilation; importing veles_tpu does it.
-    ``VELES_TPU_NO_XLA_CACHE=1`` opts out (e.g. the multichip dryrun's
-    virtual-CPU child, where AOT entries compiled for other machine
-    types spam feature-mismatch warnings)."""
-    if os.environ.get("VELES_TPU_NO_XLA_CACHE"):
+#: The XLA persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: does not place it: one fixed directory inside the checkout, resolved
+#: from this file. The path is part of every entry's key, so it must not
+#: move with ``VELES_TPU_HOME``, ``$HOME``, the cwd, a pid or the time —
+#: a cache that moves never hits (the TPU-era descendant of the
+#: reference's kernel binary cache, accelerated_units.py:605-673).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def _place_compilation_cache():
+    """Point jax at the persistent compilation cache before the first
+    compilation; importing veles_tpu does it. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax has already read it and
+    nothing is set in code. A directory that cannot be created raises:
+    on the chip a cold compile of every program is an error, not a
+    mode."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    try:
-        import jax
-        path = root.common.dirs.get("xla_cache")
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:  # never let cache plumbing break the import
-        pass
+    import jax
+    os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 
 
-_enable_xla_compilation_cache()
+_place_compilation_cache()

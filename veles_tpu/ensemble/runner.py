@@ -4,20 +4,20 @@ gathered into an ensemble JSON."""
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 
 from veles_tpu.core import prng
+from veles_tpu.core.children import run_device_child
 from veles_tpu.core.logger import Logger
 
 
 class EnsembleTrainer(Logger):
-    """Train N instances (reference ``--ensemble-train N:r``)."""
+    """Train N instances (reference ``--ensemble-train N:r``), one
+    child at a time — each needs the chip (``core/children.py``)."""
 
     def __init__(self, workflow_file, config_file=None, instances=4,
-                 train_ratio=0.8, output="ensemble.json", extra_args=(),
-                 max_parallel=2):
+                 train_ratio=0.8, output="ensemble.json", extra_args=()):
         super().__init__(logger_name="EnsembleTrainer")
         self.workflow_file = workflow_file
         self.config_file = config_file
@@ -25,11 +25,10 @@ class EnsembleTrainer(Logger):
         self.train_ratio = train_ratio
         self.output = output
         self.extra_args = list(extra_args)
-        self.max_parallel = max_parallel
 
     def run(self):
         rng = prng.get("ensemble")
-        jobs = []
+        results = []
         for index in range(self.instances):
             fd, result_file = tempfile.mkstemp(suffix=".json",
                                                prefix="ensemble_")
@@ -41,37 +40,19 @@ class EnsembleTrainer(Logger):
                    "--seed", str(seed),
                    "--train-ratio", str(self.train_ratio)]
             cmd += self.extra_args
-            jobs.append({"index": index, "seed": seed,
-                         "result_file": result_file, "cmd": cmd})
-
-        results = []
-        running = []
-
-        def harvest():
-            nonlocal running
-            job, proc = running.pop(0)
-            proc.wait()
-            entry = {"index": job["index"], "seed": job["seed"],
-                     "returncode": proc.returncode}
-            if proc.returncode == 0:
-                with open(job["result_file"]) as fin:
+            self.info("training instance %d (seed=%d)", index, seed)
+            returncode, stderr_path = run_device_child(
+                cmd, "ensemble-%d" % index)
+            entry = {"index": index, "seed": seed,
+                     "returncode": returncode}
+            if returncode == 0:
+                with open(result_file) as fin:
                     entry["results"] = json.load(fin)
             else:
-                self.warning("instance %d failed (rc=%d)", job["index"],
-                             proc.returncode)
-            os.unlink(job["result_file"])
+                self.warning("instance %d failed (rc=%d); its stderr is "
+                             "in %s", index, returncode, stderr_path)
+            os.unlink(result_file)
             results.append(entry)
-
-        for job in jobs:
-            while len(running) >= self.max_parallel:
-                harvest()
-            self.info("training instance %d (seed=%d)", job["index"],
-                      job["seed"])
-            running.append((job, subprocess.Popen(
-                job["cmd"], stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL)))
-        while running:
-            harvest()
 
         payload = {"workflow": self.workflow_file,
                    "train_ratio": self.train_ratio,
@@ -111,13 +92,17 @@ class EnsembleTester(Logger):
             cmd = [sys.executable, "-m", "veles_tpu", workflow_file,
                    self.config_file or "-", "-w", str(snapshot),
                    "--result-file", result_file] + self.extra_args
-            proc = subprocess.run(cmd, stdout=subprocess.DEVNULL,
-                                  stderr=subprocess.DEVNULL)
+            returncode, stderr_path = run_device_child(
+                cmd, "enstest-%d" % entry["index"])
             entry_out = {"index": entry["index"],
-                         "returncode": proc.returncode}
-            if proc.returncode == 0:
+                         "returncode": returncode}
+            if returncode == 0:
                 with open(result_file) as fin:
                     entry_out["results"] = json.load(fin)
+            else:
+                self.warning("instance %d test failed (rc=%d); its "
+                             "stderr is in %s", entry["index"],
+                             returncode, stderr_path)
             os.unlink(result_file)
             outputs.append(entry_out)
         return {"ensemble": self.ensemble_file, "tests": outputs}

@@ -4,9 +4,10 @@ Reference ``genetics/optimization_workflow.py:70-283``: each chromosome's
 fitness comes from a FULL training run in a subprocess (pickled config +
 result-file read-back). Kept here: the subprocess-per-evaluation contract
 (CLI override strings instead of pickled configs — same layering),
-generation loop with no-improvement early stop, and parallel evaluation
-(a local process pool plays the slave-fleet role; fleet distribution hands
-the same subprocess commands to slaves).
+generation loop with no-improvement early stop. Local evaluations run
+ONE child at a time — each needs the chip (``core/children.py``);
+parallel evaluation is the fleet's job (fleet distribution hands the
+same subprocess commands to slaves, each with its own device).
 
 Fitness: the result JSON's ``EvaluationFitness`` if present, else
 ``-best_validation_errors`` (maximized either way).
@@ -14,10 +15,10 @@ Fitness: the result JSON's ``EvaluationFitness`` if present, else
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 
+from veles_tpu.core.children import run_device_child
 from veles_tpu.core.logger import Logger
 from veles_tpu.genetics.core import Population
 
@@ -27,7 +28,7 @@ class GeneticsOptimizer(Logger):
     ``GeneticsOptimizer``)."""
 
     def __init__(self, workflow_file, config_file=None, genes=(),
-                 population_size=12, generations=5, max_parallel=2,
+                 population_size=12, generations=5,
                  no_improvement_limit=3, extra_args=(), seed=None,
                  fleet=None, representation="numeric"):
         super().__init__(logger_name="GeneticsOptimizer")
@@ -36,7 +37,6 @@ class GeneticsOptimizer(Logger):
         self.population = Population(list(genes), size=population_size,
                                      representation=representation)
         self.generations = generations
-        self.max_parallel = max_parallel
         self.no_improvement_limit = no_improvement_limit
         self.extra_args = list(extra_args)
         self.seed = seed
@@ -97,48 +97,29 @@ class GeneticsOptimizer(Logger):
                           member.fitness)
 
     def evaluate_generation(self):
-        """Run all unevaluated members, ``max_parallel`` at a time."""
+        """Run all unevaluated members, one child at a time."""
         if self._farm is not None:
             return self._evaluate_fleet()
-        pending = [m for m in self.population.members
-                   if m.fitness is None]
-        env = dict(os.environ)
-        running = []  # (member, proc, result_file)
-
-        def harvest(block):
-            nonlocal running
-            still = []
-            for member, proc, result_file in running:
-                if block:
-                    proc.wait()
-                if proc.poll() is None:
-                    still.append((member, proc, result_file))
-                    continue
-                if proc.returncode != 0:
-                    self.warning("evaluation failed (rc=%d): %s",
-                                 proc.returncode, member)
-                    member.fitness = -1e30
-                else:
-                    with open(result_file) as fin:
-                        member.fitness = self.fitness_from_results(
-                            json.load(fin))
-                    self.info("evaluated %s -> %.4f", member.values,
-                              member.fitness)
-                os.unlink(result_file)
-            running = still
-
-        for member in pending:
-            while len(running) >= self.max_parallel:
-                harvest(block=True)
+        for index, member in enumerate(self.population.members):
+            if member.fitness is not None:
+                continue
             fd, result_file = tempfile.mkstemp(suffix=".json",
                                                prefix="genetics_")
             os.close(fd)
-            proc = subprocess.Popen(
-                self._command(member, result_file), env=env,
-                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-            running.append((member, proc, result_file))
-        while running:
-            harvest(block=True)
+            returncode, stderr_path = run_device_child(
+                self._command(member, result_file),
+                "genetics-g%d-%d" % (self.population.generation, index))
+            if returncode != 0:
+                self.warning("evaluation failed (rc=%d): %s; its stderr "
+                             "is in %s", returncode, member, stderr_path)
+                member.fitness = -1e30
+            else:
+                with open(result_file) as fin:
+                    member.fitness = self.fitness_from_results(
+                        json.load(fin))
+                self.info("evaluated %s -> %.4f", member.values,
+                          member.fitness)
+            os.unlink(result_file)
 
     # -- the optimization loop -------------------------------------------------
     def run(self):

@@ -122,10 +122,15 @@ class Launcher(Logger):
             supports_mesh = (hasattr(wf, "mesh_")
                              or hasattr(wf, "fused_tick"))
             if not supports_mesh:
-                self.warning("a device mesh is configured but %s has no "
-                             "mesh support — the mesh is ignored",
-                             type(wf).__name__)
-            elif getattr(wf, "mesh_", None) is None:
+                # a requested mesh that cannot be honoured raises (as
+                # fused=True does): running on one device would look
+                # like a pod run at 1/Nth speed
+                raise ValueError(
+                    "a device mesh is configured (--mesh / "
+                    "root.common.mesh.axes) but %s has no mesh support; "
+                    "drop the mesh or run a mesh-capable workflow"
+                    % type(wf).__name__)
+            if getattr(wf, "mesh_", None) is None:
                 import jax
                 from veles_tpu.parallel.mesh import build_mesh
                 mesh = build_mesh()

@@ -81,13 +81,27 @@ class FullBatchLoader(Loader):
         if self.validation_ratio:
             self._resplit_validation()
         self._analyze_normalization()
-        if self.on_device:
-            try:
-                self.original_data.to_device()
-            except Exception as exc:
-                # graceful fallback to host gather (reference OOM path)
-                self.warning("keeping dataset on host: %s", exc)
-                self.on_device = False
+        self._upload(self.original_data, "data")
+
+    def _upload(self, array, what):
+        """Move a dataset array into HBM. ONLY running out of device
+        memory keeps it on the host (the reference's OOM path,
+        ``fullbatch.py:170-242``) — and says at error level what that
+        costs; any other device error is a fault and propagates."""
+        if not self.on_device:
+            return
+        try:
+            array.to_device()
+        except (MemoryError, jax.errors.JaxRuntimeError) as exc:
+            if not (isinstance(exc, MemoryError)
+                    or "RESOURCE_EXHAUSTED" in str(exc)):
+                raise
+            self.error(
+                "device out of memory uploading the dataset %s (%s): "
+                "keeping the dataset on the HOST — the fused tick is "
+                "disabled, and every minibatch is gathered on the host "
+                "and copied to the device each step", what, exc)
+            self.on_device = False
 
     def get_raw_labels(self):
         return self._raw_labels
@@ -98,12 +112,7 @@ class FullBatchLoader(Loader):
         super().analyze_dataset()
         if self._raw_labels is not None:
             self.original_labels.reset(self.map_labels(self._raw_labels))
-            if self.on_device:
-                try:
-                    self.original_labels.to_device()
-                except Exception as exc:
-                    self.warning("keeping labels on host: %s", exc)
-                    self.on_device = False
+            self._upload(self.original_labels, "labels")
 
     def _resplit_validation(self):
         """Move the tail of TRAIN into VALID (reference
@@ -284,12 +293,7 @@ class FullBatchLoaderMSE(LoaderMSEMixin, FullBatchLoader):
                 numpy, targets), numpy.float32))
         if not self.targets_shape:
             self.targets_shape = targets.shape[1:]
-        if self.on_device:
-            try:
-                self.original_targets.to_device()
-            except Exception as exc:
-                self.warning("keeping targets on host: %s", exc)
-                self.on_device = False
+        self._upload(self.original_targets, "targets")
 
     def create_minibatch_data(self):
         super().create_minibatch_data()

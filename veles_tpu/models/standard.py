@@ -139,12 +139,13 @@ class StandardWorkflow(Workflow):
                         "fused=True but the topology/loader is not "
                         "fusible on this slave")
                 if mesh is not None:
-                    self.warning(
+                    raise ValueError(
                         "a device mesh is configured but this slave's "
                         "topology/loader cannot run the sharded fused "
-                        "tick (see parallel/fused.py supports()) — "
-                        "falling back to per-unit graph mode on one "
-                        "device")
+                        "tick (minibatch size must divide by the data "
+                        "axis; see parallel/fused.py supports()); drop "
+                        "--mesh / root.common.mesh.axes or fix the "
+                        "topology")
             # a slave executes exactly ONE tick per job: break the repeater
             # loop-back and fire the EndPoint right after the backward chain
             # so the job callback ships the update (reference
@@ -237,14 +238,14 @@ class StandardWorkflow(Workflow):
                     "fused=True but the topology/loader is not fusible")
             if mesh is not None:
                 # the user explicitly asked for pod mode (--mesh /
-                # config); a silent single-device fallback would look
-                # like a pod run at 1/Nth speed
-                self.warning(
+                # config); a single-device fallback would look like a
+                # pod run at 1/Nth speed
+                raise ValueError(
                     "a device mesh is configured but this topology/"
                     "loader cannot run the sharded fused tick "
                     "(minibatch size must divide by the data axis; see "
-                    "parallel/fused.py supports()) — falling back to "
-                    "partial fusion on one device")
+                    "parallel/fused.py supports()); drop --mesh / "
+                    "root.common.mesh.axes or fix the topology")
             self._enable_segments()
             return
         self.fused_tick = fused.FusedTick(

@@ -1,6 +1,6 @@
 """Artifact-proof bench sentinel: incremental atomic writes + regression gate.
 
-VERDICT r5's headline complaint: the round's BENCH artifact lost its
+The complaint on record (BENCH_r05): the round's BENCH artifact lost its
 headline keys to tail truncation — a number that cannot be re-read from
 the artifact was never really measured. Two halves fix that:
 
@@ -339,7 +339,7 @@ def load_bench(path):
                 return line, info
         except ValueError:
             pass
-        # the VERDICT r5 case: the tail lost its head — salvage the
+        # the BENCH_r05 case: the tail lost its head — salvage the
         # complete pairs instead of declaring the round unmeasured
         info["recovered"] = True
         return recover_keys(tail), info

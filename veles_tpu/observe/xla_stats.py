@@ -38,20 +38,21 @@ import time
 from collections import deque
 
 #: published peak dense-matmul throughput per chip (TFLOP/s), bf16 — the
-#: MXU's native precision and the honest MFU ceiling. ORDERED
-#: most-specific-first: substring matching must let "TPU v4 lite" (v4i)
-#: claim its own peak before the plain "TPU v4" entry does. The bench
-#: (``bench.py``) and the online MFU gauge share THIS one table.
-PEAK_BF16_TFLOPS = (
-    ("TPU v4 lite", 138.0),
-    ("TPU v4", 275.0),
-    ("TPU v5 lite", 197.0),
-    ("TPU v5e", 197.0),
-    ("TPU v5p", 459.0),
-    ("TPU v5", 459.0),
-    ("TPU v6 lite", 918.0),
-    ("TPU v6e", 918.0),
-)
+#: MXU's native precision and the honest MFU ceiling — keyed by the
+#: EXACT ``device_kind`` string JAX reports, each row with its source.
+#: Exact keys on purpose: substring matching let "TPU v5" (the v5p)
+#: claim any unknown v5 kind. The bench (``bench.py``) and the online
+#: MFU gauge share THIS one table.
+PEAK_BF16_TFLOPS = {
+    "TPU v4 lite": (138.0, "Jouppi et al. 2021, 'Ten Lessons' (TPUv4i)"),
+    "TPU v4": (275.0, "Google Cloud documentation, 'TPU v4'"),
+    "TPU v5 lite": (197.0, "Google Cloud documentation, 'TPU v5e'"),
+    "TPU v5e": (197.0, "Google Cloud documentation, 'TPU v5e'"),
+    "TPU v5p": (459.0, "Google Cloud documentation, 'TPU v5p'"),
+    "TPU v5": (459.0, "Google Cloud documentation, 'TPU v5p'"),
+    "TPU v6 lite": (918.0, "Google Cloud documentation, 'TPU v6e'"),
+    "TPU v6e": (918.0, "Google Cloud documentation, 'TPU v6e'"),
+}
 
 #: device.memory_stats() keys re-published as gauges (when present)
 _MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
@@ -60,27 +61,30 @@ _MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
 
 def peak_tflops(device_kind=None):
     """The bf16 peak for ``device_kind`` (default: the first local
-    device), or ``root.common.observe.peak_tflops`` when set (the
-    override for unlisted chips — and for CPU test runs that want a
-    deterministic MFU denominator). None when unknown."""
+    device). ``root.common.observe.peak_tflops`` overrides the table
+    (an unlisted chip, or a CPU test run that wants a deterministic MFU
+    denominator). Without the override: a ``device_kind`` the table
+    lists EXACTLY gives its row; on platform ``tpu`` an unlisted kind
+    raises — a device that is not in the table is an error, not a
+    missing key; off the TPU there is no peak (None)."""
     from veles_tpu.core.config import root
 
     override = root.common.observe.get("peak_tflops", None)
     if override:
-        try:
-            return float(override)
-        except (TypeError, ValueError):
-            pass
+        return float(override)
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
+        import jax
+        device = jax.devices()[0]
+        if device.platform != "tpu":
             return None
-    for name, tflops in PEAK_BF16_TFLOPS:
-        if name.lower() in str(device_kind).lower():
-            return tflops
-    return None
+        device_kind = device.device_kind
+    row = PEAK_BF16_TFLOPS.get(str(device_kind))
+    if row is None:
+        raise LookupError(
+            "no bf16 peak on record for TPU device_kind %r: add a row "
+            "(with its source) to observe.xla_stats.PEAK_BF16_TFLOPS or "
+            "set root.common.observe.peak_tflops" % (device_kind,))
+    return row[0]
 
 
 def abstractify(args, kwargs):

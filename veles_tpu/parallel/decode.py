@@ -45,7 +45,7 @@ def init_kv_cache(n_blocks, batch, max_len, heads, head_dim,
     tier. At decode lengths the cache read rivals the weight read, so
     this halves the OTHER half of the memory-bound loop's traffic.
     Layout is (L, B, H, D, T) — head-major, positions minor: the
-    dequant-fused attend kernel's dots then tile the MXU natively
+    dequant-fused attend's dots then tile the MXU natively
     (q x K contracts D with T on lanes; V x p contracts T), and XLA
     cannot sneak a materialized bf16 widening of the cache in between
     (measured 4-8x slower in every positions-major layout)."""
@@ -153,7 +153,7 @@ def _cache_attend(q, k_all, v_all, mask):
     ONE copy of the math for the single-device and tensor-parallel
     decode paths (the TP guarantee of token-identity depends on it).
     The int8-cache variant lives in ``ops/quant.int8_cache_attend``
-    (head-major layout + the dequant-fused Pallas kernel)."""
+    (head-major layout, dequantization fused into the dots)."""
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
     # q (B,1,H,D) x cache K (B,L,H,D) -> (B,H,1,L)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k_all.astype(q.dtype),
@@ -336,8 +336,8 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
         else:
             key = jax.random.key(0)  # unused by greedy, jit wants one
     if quantize == "int8-kv":
-        # round the quantized cache up to whole 128-lane tiles so the
-        # dequant-fused attend kernel's T gate engages (masking makes
+        # round the quantized cache up to whole 128-lane tiles: T is
+        # the lane dimension of the head-major layout (masking makes
         # the extra positions inert)
         max_len = -(-max_len // 128) * 128
     # the cache follows the serving dtype: with bf16 params/table the
@@ -373,7 +373,7 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
 #: ``ceil((longest live sequence + chunk) / TILE) * TILE`` instead of
 #: ``max_len`` — one compiled program per tile count, the same
 #: compile-bounding trick as the prompt buckets. 128 = the TPU lane
-#: width, and the granule the int8-KV attend kernel's T gate wants.
+#: width — T is the lane dimension of the int8-KV head-major layout.
 SLOT_SPAN_TILE = 128
 
 

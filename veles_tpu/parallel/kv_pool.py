@@ -325,7 +325,8 @@ def _paged_admit_hit(state, slots, lengths, logits, req_keys):
 
 
 def _paged_slot_step(params, embed_table, heads, state, page_table,
-                     active, temperature=1.0, sample=False, top_k=0):
+                     active, temperature=1.0, sample=False, top_k=0,
+                     use_kernel=None):
     """One decode step across all slots — the dense ``_slot_step``
     with the slab slice replaced by a page-table gather and the append
     target routed through the table. ``page_table`` (S, PB) int32 lists
@@ -340,11 +341,12 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
     ``ops/paged_attention.use_paged_kernel()`` says so — the fused
     Pallas kernel that walks the table directly and attends only each
     slot's LIVE pages (span/page overshoot deleted at the kernel
-    level). The probe is read at TRACE time, so the ``paged.step`` /
-    ``paged.dispatch`` instrument names, the AOT facade and the
-    sharded-fns surface are identical either way; flipping the probe
-    does not invalidate already-traced programs (tests
-    ``jax.clear_caches()`` around it)."""
+    level). ``use_kernel=None`` reads the probe at TRACE time, so the
+    ``paged.step`` / ``paged.dispatch`` instrument names and the AOT
+    facade are identical either way; flipping the probe does not
+    invalidate already-traced programs (tests ``jax.clear_caches()``
+    around it). :func:`sharded_paged_fns` pins it False — the mesh
+    tier takes the gather by rule (``use_paged_kernel(mesh)``)."""
     from veles_tpu.ops import paged_attention as pgatt
     from veles_tpu.parallel.decode import _cache_attend, _pick_token
 
@@ -353,7 +355,8 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
     ps = _page_size_of(state)
     pb = page_table.shape[1]
     span = pb * ps
-    use_kernel = pgatt.use_paged_kernel()
+    if use_kernel is None:
+        use_kernel = pgatt.use_paged_kernel()
     lengths = state["lengths"]
     if sample:
         step_keys = jax.vmap(jax.random.fold_in)(state["req_key"],
@@ -374,10 +377,6 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
         inv_sqrt = (embed // heads) ** -0.5
     else:
         mask = visible[:, None, None, :]
-    if use_kernel:
-        # the kernel resolves visibility from the prefetched lengths
-        # itself — no gathered span, no span-wide mask materialized
-        block_h = pgatt._tuned_block_h(ps, embed // heads, heads)
     new_k, new_v = state["k"], state["v"]
     new_ks = state.get("k_scale")
     new_vs = state.get("v_scale")
@@ -414,7 +413,7 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
                 att = pgatt.paged_attend_int8(
                     (q * inv_sqrt)[:, 0], new_k[i], new_ks[i],
                     new_v[i], new_vs[i], page_table, lengths,
-                    page_size=ps, block_h=block_h)[:, None]
+                    page_size=ps)[:, None]
             else:
                 pool = dict(state, k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
@@ -436,7 +435,7 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
             if use_kernel:
                 att = pgatt.paged_attend(
                     q[:, 0], new_k[i], new_v[i], page_table, lengths,
-                    page_size=ps, block_h=block_h)[:, None]
+                    page_size=ps)[:, None]
             else:
                 pool = dict(state, k=new_k, v=new_v)
                 k_g, v_g = _gather_block_float(pool, i, page_table)
@@ -460,7 +459,7 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
 
 def _paged_slot_step_many(params, embed_table, heads, state, page_table,
                           active, n, temperature=1.0, sample=False,
-                          top_k=0):
+                          top_k=0, use_kernel=None):
     """``n`` lockstep paged steps as ONE ``lax.scan`` dispatch. The
     page table is constant across the chunk — the host pre-maps every
     page the chunk's appends can touch (``PB * page_size`` covers the
@@ -469,7 +468,7 @@ def _paged_slot_step_many(params, embed_table, heads, state, page_table,
     def body(state, _):
         state, emitted = _paged_slot_step(
             params, embed_table, heads, state, page_table, active,
-            temperature, sample, top_k)
+            temperature, sample, top_k, use_kernel)
         return state, emitted
 
     with jax.named_scope("paged.dispatch"):
@@ -548,13 +547,16 @@ def sharded_paged_fns(mesh, mesh_axis="model", quantized=False):
     admit_hit = instrument("paged.admit_hit", jax.jit(
         _paged_admit_hit, donate_argnames=("state",),
         out_shardings=state_sh))
+    # the mesh tier takes the gather by rule: GSPMD cannot partition a
+    # bare pallas_call over the head-sharded pool
+    # (ops/paged_attention.use_paged_kernel)
     step = instrument("paged.step", jax.jit(
-        _paged_slot_step,
+        functools.partial(_paged_slot_step, use_kernel=False),
         static_argnames=("heads", "sample", "top_k"),
         donate_argnames=("state",),
         out_shardings=(state_sh, replicated)))
     step_many = instrument("paged.dispatch", jax.jit(
-        _paged_slot_step_many,
+        functools.partial(_paged_slot_step_many, use_kernel=False),
         static_argnames=("heads", "n", "sample", "top_k"),
         donate_argnames=("state",),
         out_shardings=(state_sh, replicated)))

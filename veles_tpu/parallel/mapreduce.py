@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from veles_tpu.parallel.mesh import axis_size, shard_map
+from veles_tpu.parallel.mesh import shard_map
 
 #: valid in-program gradient-reduce precisions
 REDUCE_PRECISIONS = ("f32", "bf16", "int8")
@@ -105,7 +105,7 @@ def _int8_allreduce_leaf(x, axis):
     docstring). Exact int32 accumulation between the two rounding
     stages; both scales are global (``pmax``), so every device computes
     identical bytes and the result is replicated by construction."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     orig_shape, orig_dtype = x.shape, x.dtype
     flat = x.astype(jnp.float32).reshape(-1)
     size = flat.size
@@ -174,7 +174,7 @@ def reduce_mean(tree, axis="data", precision="f32"):
     def leaf(x):
         nonlocal n
         if n is None:
-            n = axis_size(axis)
+            n = lax.axis_size(axis)
         return x / n if _is_float(x) else x // n
 
     return jax.tree.map(leaf, summed)

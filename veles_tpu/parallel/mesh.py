@@ -24,51 +24,12 @@ from veles_tpu.core.config import root
 AXIS_ORDER = ("pipe", "data", "expert", "seq", "model")
 
 
-#: resolved once: (implementation, name of its replication-check
-#: kwarg). Feature-detected by SIGNATURE, not try/except — a genuine
-#: TypeError from a caller's bad mesh/specs must surface as itself,
-#: never as a bogus "unexpected keyword" retry artifact.
-_SHARD_MAP_IMPL = None
-
-
-def _shard_map_impl():
-    global _SHARD_MAP_IMPL
-    if _SHARD_MAP_IMPL is None:
-        import inspect
-        impl = getattr(jax, "shard_map", None)
-        if impl is None:
-            from jax.experimental.shard_map import shard_map as impl
-        try:
-            params = inspect.signature(impl).parameters
-        except (TypeError, ValueError):
-            params = {}
-        kwarg = "check_vma" if "check_vma" in params else (
-            "check_rep" if "check_rep" in params else None)
-        _SHARD_MAP_IMPL = (impl, kwarg)
-    return _SHARD_MAP_IMPL
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at
-    the top level (replication checking via ``check_vma``), older jax
-    under ``jax.experimental.shard_map`` (``check_rep``). Every
-    shard_map in the tree routes through here so a jax upgrade is one
-    edit, not eight."""
-    impl, kwarg = _shard_map_impl()
-    kwargs = {kwarg: False} if kwarg else {}
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
-
-
-def axis_size(axis_name):
-    """Static mesh-axis size from inside a shard_map body, across jax
-    versions (``lax.axis_size`` is newer jax; older jax reads the axis
-    environment)."""
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
+    """``jax.shard_map`` with replication checking off. Every shard_map
+    in the tree routes through here so the one setting they share is
+    one edit, not eight."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_axes():

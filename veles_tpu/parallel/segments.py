@@ -3,8 +3,8 @@
 SURVEY §7's hard part "tick fusion vs dynamic gates", second tier. The
 full :mod:`veles_tpu.parallel.fused` engine recognizes the standard
 forward/GD topology and compiles whole class sweeps; everything it
-declines used to fall all the way to per-unit graph dispatch (the
-VERDICT r2 "170x cliff"). This module closes the gap for ANY workflow
+declines used to fall all the way to per-unit graph dispatch (a
+"170x cliff"). This module closes the gap for ANY workflow
 whose compute units are :class:`~veles_tpu.nn.jit_unit.JitUnit`\\ s:
 
 - the repeater cycle is extracted as a linear unit chain;

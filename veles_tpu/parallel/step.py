@@ -175,8 +175,7 @@ def _model_shard(err, model_ax):
     """Slice this device's column block out of a full-width error."""
     if model_ax == 1:
         return err
-    from veles_tpu.parallel.mesh import axis_size
-    cols = err.shape[1] // axis_size("model")
+    cols = err.shape[1] // jax.lax.axis_size("model")
     idx = jax.lax.axis_index("model")
     return jax.lax.dynamic_slice_in_dim(err, idx * cols, cols, axis=1)
 

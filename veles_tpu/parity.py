@@ -1,5 +1,5 @@
 """Accuracy-parity harness: a pass/fail artifact against the reference
-anchors (VERDICT r2 #3).
+anchors.
 
 The reference publishes three MNIST validation-error anchors
 (``docs/source/manualrst_veles_example.rst:55-66``):
@@ -17,8 +17,8 @@ topology FAMILIES on the sklearn ``load_digits`` set — **real scanned
 handwriting** (the UCI Optical Recognition of Handwritten Digits test
 fold: 1797 8x8 scans from 43 writers; earlier rounds mislabeled this
 tier "synthetic"), 1500 train / 297 validation — with ABSOLUTE bounds
-chosen at the reference anchors' tightness class (VERDICT r4 #2/#8:
-the 6% bounds were loose; these are sub-1% for both convnets):
+chosen at the reference anchors' tightness class (the
+earlier 6% bounds were loose; these are sub-1% for both convnets):
 
     digits784 MLP                         measured 2.36%  → bound 3.0%
     digits "caffe" (relu convnet)         measured 0.00%  → bound 0.7%
