@@ -447,12 +447,14 @@ class Main(Logger):
             # web timeline role, done the TPU way): a jax profiler trace
             # viewable in TensorBoard / Perfetto; profile_window also
             # turns on span-named TraceAnnotations so the host span
-            # timeline lines up with the XLA device trace
+            # timeline shares the XLA device trace's clock
             # (docs/observability.md)
             from veles_tpu.observe.profile import profile_window
             self.info("profiling to %s (open with tensorboard or "
                       "ui.perfetto.dev)", self.profile_dir)
-            with profile_window(self.profile_dir):
+            # strict: the capture was asked for by name, so a profiler
+            # that cannot start fails the run rather than the request
+            with profile_window(self.profile_dir, strict=True):
                 self.launcher.run()
         else:
             self.launcher.run()

@@ -328,6 +328,11 @@ class EventRecorder:
             self._buffer.clear()
 
     def record(self, **attrs):
+        if self._fd is None and not self.enabled and not self._sinks:
+            # no file, no pre-open buffer, no sink: nothing would read
+            # the line, so it is not serialised (a traced window of
+            # the profiler calls this twice per span)
+            return
         attrs.setdefault("time", time.time())
         # monotonic stamp: what the Chrome trace exporter orders and
         # measures by (wall time can step; span durations must not)

@@ -22,6 +22,7 @@ successor (reference ``units.py:485-505``). Re-entrant notifications while a
 (reference ``units.py:782-803``).
 """
 
+import re
 import threading
 import time
 import uuid as uuid_module
@@ -34,6 +35,18 @@ from veles_tpu.core.mutable import Bool, link as link_attr
 from veles_tpu.core.registry import UnitCommandLineArgumentsRegistry
 from veles_tpu.core.timing import Timer
 from veles_tpu.observe.tracing import get_tracer
+
+
+_WORD_START = re.compile(r"(?<=[a-z0-9])(?=[A-Z])|(?<=[A-Z])(?=[A-Z][a-z])")
+
+
+def run_span_label(unit_name):
+    """``unit.run.<unit name in snake case>``: what a unit's run span
+    is called in a profiler capture, so that a device-idle gap goes to
+    the unit the host was running and not to ``unit.run`` at large."""
+    snake = re.sub(r"[^a-z0-9]+", "_",
+                   _WORD_START.sub("_", unit_name).lower()).strip("_")
+    return "unit.run." + (snake or "unit")
 
 
 class Unit(Distributable, metaclass=UnitCommandLineArgumentsRegistry):
@@ -284,7 +297,9 @@ class Unit(Distributable, metaclass=UnitCommandLineArgumentsRegistry):
                     # span-per-tick only while tracing is ON (the
                     # enabled check is the whole disabled-path cost):
                     # unit runs are THE hot path of the training loop
-                    with tracer.span("unit.run", unit=self.name,
+                    with tracer.span("unit.run",
+                                     label=run_span_label(self.name),
+                                     unit=self.name,
                                      cls=type(self).__name__), timer:
                         self.run()
                 else:

@@ -34,6 +34,7 @@ from veles_tpu.core.errors import NoMoreJobsError
 from veles_tpu.core.mutable import Bool
 from veles_tpu.core.units import Unit
 from veles_tpu.memory import Array
+from veles_tpu.observe.tracing import get_tracer
 
 TEST, VALID, TRAIN = 0, 1, 2
 CLASS_NAMES = ("test", "validation", "train")
@@ -399,26 +400,32 @@ class Loader(Unit):
         if self.is_slave:
             return
         if getattr(self, "sweep_serving", False):
-            (klass, matrix, valid_sizes, total, last_of_epoch,
-             epoch) = self.serve_next_class_sweep()
-            self._publish_flags(klass, matrix.reshape(-1), total, True,
-                                last_of_epoch, epoch)
-            self.minibatch_indices.data = matrix
-            self.sweep_valid_sizes = valid_sizes
-            # per-minibatch augmentation seeds for the fused tick, drawn
-            # in the same stream order graph mode would (one per TRAIN
-            # minibatch at fill time)
-            if klass == TRAIN and getattr(self, "jit_transform", None):
-                self.sweep_transform_seeds = self.draw_transform_seeds(
-                    len(matrix))
-            else:
-                self.sweep_transform_seeds = None
-            self._account_served(total, last_of_epoch)
+            with get_tracer().span("loader.serve_sweep"):
+                self._serve_sweep()
             return
         (klass, indices, valid, last_of_class,
          last_of_epoch, epoch) = self.serve_next_minibatch()
         self._apply_minibatch(klass, indices, valid, last_of_class,
                               last_of_epoch, epoch)
+
+    def _serve_sweep(self):
+        """One whole class sweep served at once: the index matrix and
+        the valid sizes the fused tick scans over."""
+        (klass, matrix, valid_sizes, total, last_of_epoch,
+         epoch) = self.serve_next_class_sweep()
+        self._publish_flags(klass, matrix.reshape(-1), total, True,
+                            last_of_epoch, epoch)
+        self.minibatch_indices.data = matrix
+        self.sweep_valid_sizes = valid_sizes
+        # per-minibatch augmentation seeds for the fused tick, drawn
+        # in the same stream order graph mode would (one per TRAIN
+        # minibatch at fill time)
+        if klass == TRAIN and getattr(self, "jit_transform", None):
+            self.sweep_transform_seeds = self.draw_transform_seeds(
+                len(matrix))
+        else:
+            self.sweep_transform_seeds = None
+        self._account_served(total, last_of_epoch)
 
     def _publish_flags(self, klass, indices, valid, last_of_class,
                        last_of_epoch, epoch):

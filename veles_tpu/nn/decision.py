@@ -15,6 +15,7 @@ Snapshotter and the Repeater loop exactly as in the reference workflows.
 from veles_tpu.core.mutable import Bool
 from veles_tpu.core.units import Unit
 from veles_tpu.loader.base import CLASS_NAMES, TEST, TRAIN, VALID
+from veles_tpu.observe.tracing import get_tracer
 
 
 class DecisionGD(Unit):
@@ -178,14 +179,13 @@ class DecisionGD(Unit):
             return
         # one sample class finished: settle the device accumulators in
         # ONE batched transfer
-        import jax
         if self._dev_acc_[klass] is not None:
-            n_err, loss = jax.device_get(self._dev_acc_[klass])
+            n_err, loss = self._settle(self._dev_acc_[klass])
             self._dev_acc_[klass] = None
             self.epoch_n_err[klass] += int(n_err)
             self.epoch_loss[klass] += float(loss)
         if klass == VALID and self._dev_confusion_ is not None:
-            total = jax.device_get(self._dev_confusion_)
+            total = self._settle(self._dev_confusion_)
             self._dev_confusion_ = None
             self._epoch_confusion = (
                 total if self._epoch_confusion is None
@@ -193,6 +193,14 @@ class DecisionGD(Unit):
         self._on_class_ended(klass)
         if self.loader.epoch_ended:
             self._on_epoch_ended()
+
+    @staticmethod
+    def _settle(values):
+        """Device scalars as host numbers: the host's one wait on the
+        device in steady state, under the span ``decision.settle``."""
+        import jax
+        with get_tracer().span("decision.settle"):
+            return jax.device_get(values)
 
     def _queue_epoch(self):
         """Park the finished epoch's (still-lazy) accumulators and reset
@@ -262,8 +270,7 @@ class DecisionGD(Unit):
         """One batched device->host transfer for one epoch's
         accumulators (error counts, loss sums, confusion), then the
         class summaries in serving order and the epoch summary."""
-        import jax
-        n_errs, losses, cm = jax.device_get(
+        n_errs, losses, cm = self._settle(
             (entry["n_err"], entry["loss"], entry["confusion"]))
         self.epoch_n_err = [int(v) for v in n_errs]
         self.epoch_loss = [float(v) for v in losses]
@@ -283,8 +290,7 @@ class DecisionGD(Unit):
     def _peek_metric(self, entry):
         """The VALID metric of a still-lazy epoch entry (the pipelined
         drain's advance-peek)."""
-        import jax
-        return int(jax.device_get(entry["n_err"][VALID]))
+        return int(self._settle(entry["n_err"][VALID]))
 
     def _improvement_suffix(self, metric, n_err, samples):
         return "validation_%.2fpt" % (100.0 * n_err / max(samples, 1))
@@ -474,8 +480,7 @@ class DecisionMSE(DecisionGD):
         return loss_sum / max(samples, 1)
 
     def _peek_metric(self, entry):
-        import jax
-        loss_sum = float(jax.device_get(entry["loss"][VALID]))
+        loss_sum = float(self._settle(entry["loss"][VALID]))
         return loss_sum / max(entry["samples"][VALID], 1)
 
     def _improvement_suffix(self, metric, n_err, samples):

@@ -4,12 +4,21 @@
 window in ``jax.profiler.trace``; while a capture is active the tracer
 also enters a ``jax.profiler.TraceAnnotation`` named after each span
 (``Span.__enter__``), so the host-side span timeline and the XLA device
-timeline line up by NAME in TensorBoard/Perfetto — "decode.dispatch" on
-the host lane sits over the slot_step_many program on the device lane.
+timeline share one clock in TensorBoard/Perfetto — "decode.dispatch"
+on a host lane sits over the slot_step_many program on the device
+lane. What the capture holds: the span names on the ``/host:CPU``
+plane; on the device plane one ``XLA Modules`` event per program run
+(``jit_<function>(<hash>)``) and one ``XLA Ops`` event per instruction,
+named by the instruction's text. It holds NO ``op_name`` metadata: the
+program's ``jax.named_scope``s reach a traced op only through the
+scope table (``observe/xla_stats.scope_table``), not through the
+capture.
 
-Everything here degrades to a no-op when jax is unavailable or the
-profiler cannot start (a serving box must never crash because a
+By default everything here degrades to a no-op when jax is unavailable
+or the profiler cannot start (a serving box must never crash because a
 capture was requested) — the failure is logged, the run continues.
+``strict=True`` re-raises instead: a caller that was ASKED for a
+capture (the CLI's ``--profile``) must not end without one in silence.
 """
 
 import contextlib
@@ -17,7 +26,7 @@ import logging
 
 
 @contextlib.contextmanager
-def profile_window(profile_dir, annotate=True):
+def profile_window(profile_dir, annotate=True, strict=False):
     """Capture a jax profiler trace of the enclosed window into
     ``profile_dir`` (viewable in TensorBoard or ui.perfetto.dev).
     ``annotate=True`` additionally turns on span-named
@@ -29,7 +38,9 @@ def profile_window(profile_dir, annotate=True):
     whatever EventRecorder is configured; none configured means they
     are simply dropped while the annotations still fire.
     ``profile_dir`` of None/"" makes this a no-op — callers wrap
-    unconditionally and the flag decides."""
+    unconditionally and the flag decides. ``strict`` re-raises a
+    profiler that cannot start or cannot write its capture, where the
+    default logs it and lets the run go on without one."""
     if not profile_dir:
         yield None
         return
@@ -47,6 +58,8 @@ def profile_window(profile_dir, annotate=True):
         # checks for a concurrent capture
         profiler_cm.__enter__()
     except Exception:
+        if strict:
+            raise
         log.exception(
             "jax profiler unavailable; continuing without a capture")
         yield None
@@ -62,5 +75,7 @@ def profile_window(profile_dir, annotate=True):
         try:
             profiler_cm.__exit__(None, None, None)
         except Exception:
+            if strict:
+                raise
             log.exception("jax profiler capture failed to finalize; "
                           "the run itself is unaffected")

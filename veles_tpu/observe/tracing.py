@@ -27,9 +27,9 @@ the continuation.
 
 import contextvars
 import os
+import random
 import threading
 import time
-import uuid
 
 from veles_tpu.core.logger import get_event_recorder
 from veles_tpu.observe.fleetscope import get_span_ring
@@ -41,8 +41,26 @@ TRACE_HEADER = "X-Veles-Trace"
 _current = contextvars.ContextVar("veles_trace_span", default=None)
 
 
+#: ids and the process id without a system call per span: on the
+#: chip's host one costs 5-8 us (measured, PERF.md PR 27) and a root
+#: span made four. The generator is this module's own (a program that
+#: seeds ``random`` does not repeat ids), seeded from the OS once and
+#: again in a forked child
+_ids = random.Random(os.urandom(16))
+_pid = os.getpid()
+
+
+def _after_fork():
+    global _pid
+    _ids.seed(os.urandom(16))
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
+
+
 def _new_id():
-    return uuid.uuid4().hex[:16]
+    return "%016x" % _ids.getrandbits(64)
 
 
 class NullSpan:
@@ -81,13 +99,18 @@ class Span:
     monotonic stamp (``mono`` — what the Chrome exporter orders by),
     and the recording thread (``tid``)."""
 
-    __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
-                 "attrs", "_token", "_finished", "_annotation",
-                 "_t0_mono")
+    __slots__ = ("tracer", "name", "label", "trace_id", "span_id",
+                 "parent_id", "attrs", "_token", "_finished",
+                 "_annotation", "_t0_mono")
 
-    def __init__(self, tracer, name, trace_id, parent_id, **attrs):
+    def __init__(self, tracer, name, trace_id, parent_id, label=None,
+                 **attrs):
         self.tracer = tracer
         self.name = name
+        #: what the TraceAnnotation is called in a profiler capture
+        #: (the recorded events keep ``name``): ``unit.run`` is one
+        #: event name for every unit, ``unit.run.<unit>`` in a capture
+        self.label = label or name
         self.trace_id = trace_id
         self.span_id = _new_id()
         self.parent_id = parent_id
@@ -114,7 +137,7 @@ class Span:
             name=self.name, etype=etype, trace_id=self.trace_id,
             span_id=self.span_id, parent_id=self.parent_id,
             mono=mono, tid=threading.get_ident(),
-            pid=os.getpid(), **self.attrs)
+            pid=_pid, **self.attrs)
         get_event_recorder().record(**payload)
         # the black box holds the last spans regardless of which
         # EventRecorder instance is active (flight.py; bounded append)
@@ -139,13 +162,13 @@ class Span:
     def __enter__(self):
         self._token = _current.set(self)
         if self.tracer.annotate_device:
-            # align host spans with the XLA device trace: a
-            # TraceAnnotation of the SAME name shows up in the
-            # jax.profiler capture (--profile-dir)
+            # put the span on the device trace's clock: a
+            # TraceAnnotation named after it (``label``) is an event
+            # of the capture's /host:CPU plane (--profile-dir)
             try:
                 import jax
                 self._annotation = jax.profiler.TraceAnnotation(
-                    self.name)
+                    self.label)
                 self._annotation.__enter__()
             except Exception:
                 self._annotation = None
@@ -200,7 +223,10 @@ class Tracer:
     def span(self, name, parent=None, **attrs):
         """Open a span. ``parent`` overrides the ambient context: a
         ``(trace_id, span_id)`` pair (from a header/frame/holder), a
-        Span, or None to inherit from this thread's current span."""
+        Span, or None to inherit from this thread's current span.
+        ``label=`` names the span's TraceAnnotation in a profiler
+        capture where that should say more than ``name``; it is no
+        attribute of the recorded events."""
         if not self.enabled:
             return NULL_SPAN
         if parent is None:

@@ -33,6 +33,7 @@ check until a ``/metrics`` surface is mounted
 """
 
 import logging
+import re
 import threading
 import time
 from collections import deque
@@ -113,11 +114,17 @@ def abstractify(args, kwargs):
                              None)
             if single is not None and isinstance(sharding, single):
                 sharding = None
+            # weak_type rides along: a weakly typed operand lowers to
+            # another program than its strong twin, and the scope
+            # table (below) must find the one that ran
+            weak = bool(getattr(x, "weak_type", False))
             try:
                 return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                            sharding=sharding)
+                                            sharding=sharding,
+                                            weak_type=weak)
             except (TypeError, ValueError):
-                return jax.ShapeDtypeStruct(x.shape, x.dtype)
+                return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                            weak_type=weak)
         return x
 
     return (jax.tree.map(conv, args), jax.tree.map(conv, kwargs))
@@ -171,6 +178,9 @@ class CompileTracker:
         #: request ledger intersects these with a request's lifetime
         #: to attribute a latency spike to the compile that caused it
         self._windows = deque(maxlen=256)
+        #: programs dispatched while the tracer was on (note_program):
+        #: signature -> [jitted fn, abstract operands, scope table]
+        self._programs = {}
 
     def enable(self):
         self.enabled = True
@@ -189,6 +199,7 @@ class CompileTracker:
                           self._step_count):
                 store.clear()
             self._storm_warned.clear()
+            self._programs.clear()
 
     # -- recording --------------------------------------------------------
     def record_compile(self, name, seconds, flops=None):
@@ -357,13 +368,21 @@ def instrument(name, fn):
     hook (non-jit objects, older jax) are returned unwrapped."""
     import functools
 
+    from veles_tpu.observe.tracing import get_tracer
+
     tracker = get_compile_tracker()
+    tracer = get_tracer()
     cache_size = getattr(fn, "_cache_size", None)
     if cache_size is None:
         return fn
 
     @functools.wraps(fn, assigned=("__doc__",), updated=())
     def wrapper(*args, **kwargs):
+        if tracer.enabled:
+            # a traced window is open: remember WHICH program this
+            # dispatch runs, so that scope_table() can name its
+            # instructions once the window has closed
+            note_program(fn, args, kwargs)
         if not tracker.enabled:
             return fn(*args, **kwargs)
         before = cache_size()
@@ -381,6 +400,248 @@ def instrument(name, fn):
     wrapper.__wrapped__ = fn
     wrapper.program_name = name
     return wrapper
+
+
+# -- the scope table ---------------------------------------------------------
+#
+# A jax.profiler capture names a device op by its instruction's text
+# and carries no ``op_name``, so the program says itself which
+# instruction belongs to which ``jax.named_scope``: ``instrument``
+# notes the programs dispatched while the tracer is on (shapes only),
+# and :func:`scope_table` reads the metadata out of their compiled
+# text once the window has closed.
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(?P<name>[^\s(]+) .*\{$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?P<root>ROOT )?%?(?P<name>\S+) = (?P<shape>.+?) "
+    r"(?P<opcode>[a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="(?P<op_name>[^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?(?P<callee>[^\s,{}]+)")
+_HLO_OPERAND = re.compile(r"%?([A-Za-z_][\w.\-]*)\s*(?:,|$)")
+#: instructions that compute nothing: a constant and its broadcast
+#: carry the enclosing call's ``op_name``, not a scope's, and would
+#: outvote the instructions that do a fusion's work
+_NO_VOTE = ("parameter", "constant", "broadcast", "iota", "bitcast",
+            "tuple", "get-tuple-element")
+_TRANSFORMED = re.compile(r"^(?:[A-Za-z_]\w*\()+(?P<inner>[^()]*)\)+$")
+
+
+def note_program(fn, args, kwargs):
+    """Remember the program that ``fn(*args, **kwargs)`` dispatches:
+    the jitted callable and its operands' shape/dtype skeletons
+    (:func:`abstractify`; static operands as they are). A set: one
+    entry per distinct program, whatever the number of dispatches."""
+    import jax
+
+    leaves, treedef = jax.tree.flatten((args, kwargs))
+    try:
+        key = (getattr(fn, "__name__", None) or repr(fn), treedef, tuple(
+            (x.shape, x.dtype, bool(getattr(x, "weak_type", False)))
+            if hasattr(x, "shape") and hasattr(x, "dtype") else x
+            for x in leaves))
+        programs = get_compile_tracker()._programs
+        if key not in programs:
+            programs[key] = [fn, abstractify(args, kwargs), None]
+    except TypeError:
+        # an unhashable static operand: not a program this can name
+        pass
+
+
+def _operands(rest):
+    """The operand names of an instruction, from the text after its
+    opening bracket up to the bracket that closes it."""
+    depth = 1
+    for i, char in enumerate(rest):
+        depth += (char == "(") - (char == ")")
+        if not depth:
+            return _HLO_OPERAND.findall(rest[:i])
+    return []
+
+
+def scope_names(path):
+    """The names along an ``op_name`` (or a name stack) as a list,
+    each freed of the transformations JAX wraps round it:
+    ``"jit(f)/transpose(jvp(fwd))/l0_conv/mul"`` -> ``["f", "fwd",
+    "l0_conv", "mul"]``."""
+    out = []
+    for part in path.split("/"):
+        found = _TRANSFORMED.match(part)
+        out.append(found.group("inner") if found else part)
+    return out
+
+
+def parse_hlo_scopes(text):
+    """``{instruction name: (output shape text, op_name)}`` of every
+    instruction of a compiled module's text that can run as an op of
+    its own (those of fused computations are folded into their
+    fusion). An instruction that calls a computation (a fusion, an
+    asynchronous wrapper) is what that computation produces: it gets
+    the ``op_name`` whose scope (the ``op_name`` less its last part)
+    most of the root's instructions carry, the root being one
+    instruction or, for several outputs, the operands of its tuple.
+    Where the root carries none (a bitcast, a copy XLA put there) the
+    vote goes to all of the computation's instructions that compute
+    (not its parameters, constants and broadcasts: they carry the
+    enclosing call's ``op_name``), and where none of those does
+    either the caller keeps its own. An instruction without metadata
+    has ``op_name`` ""."""
+    computations, current = {}, None
+    for line in text.splitlines():
+        if current is None:
+            head = _HLO_COMPUTATION.match(line)
+            if head and " -> " in line:
+                current = computations.setdefault(head.group("name"), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if not found:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        calls = _HLO_CALLS.search(line)
+        current.append((found.group("name"), found.group("shape"),
+                        op_name.group("op_name") if op_name else "",
+                        calls.group("callee") if calls else None,
+                        found.group("opcode"),
+                        _operands(line[found.end():])
+                        if found.group("root") else None))
+    called = {row[3] for rows in computations.values() for row in rows
+              if row[3]}
+
+    def folded(callee, own, depth=0):
+        rows = computations.get(callee, ())
+        if not rows or depth > 8:
+            return own
+        root = next((row for row in rows if row[5] is not None),
+                    rows[-1])
+        produced = [row for row in rows if row[0] in (root[5] or ())] \
+            if root[4] == "tuple" else [root]
+        for voters in (produced, rows):
+            votes = {}
+            for _, _, op_name, inner, opcode, _ in voters:
+                if inner:
+                    op_name = folded(inner, op_name, depth + 1)
+                if op_name and opcode not in _NO_VOTE:
+                    votes.setdefault(op_name.rpartition("/")[0],
+                                     []).append(op_name)
+            if votes:
+                # the most votes; the first seen on a tie
+                return max(votes.values(), key=len)[0]
+        return own
+
+    table = {}
+    for name, rows in computations.items():
+        if name in called:
+            continue
+        for instruction, shape, op_name, callee, _, _ in rows:
+            if callee:
+                op_name = folded(callee, op_name)
+            table[instruction] = (shape, op_name)
+    return table
+
+
+def _written_scopes(jaxpr, out=None):
+    """The names the program's source puts on its name stack (the
+    ``jax.named_scope``s, freed of ``jvp(...)`` and the like), read
+    off the traced jaxpr and every jaxpr inside it."""
+    out = set() if out is None else out
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        if stack:
+            out.update(scope_names(stack))
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else (value,)):
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    _written_scopes(inner, out)
+    return out
+
+
+def _compiled_outside_cache(lowered):
+    """Compile ``lowered`` anew, past both of JAX's caches: the
+    persistent one is switched off for the call, and the in-process
+    one (keyed by the module and its options) is missed by naming a
+    debug option at the value it has anyway, which leaves XLA's flags,
+    and so its instruction names, as they were."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile(
+            compiler_options={"xla_dump_hlo_as_text": False})
+    finally:
+        jax.config.update("jax_enable_compilation_cache", saved)
+        compilation_cache.reset_cache()
+
+
+def _program_scopes(fn, operands):
+    """(``parse_hlo_scopes`` of the program's compiled text, whether
+    it had to be compiled outside the persistent cache)."""
+    traced = fn.trace(*operands[0], **operands[1])
+    written = _written_scopes(traced.jaxpr)
+    lowered = traced.lower()
+    text = lowered.compile().as_text()
+    held = {name for op_name in _HLO_OP_NAME.findall(text)
+            for name in scope_names(op_name)}
+    # "fewer than half", not "none": a binary cached when the program
+    # had some of today's scopes is as stale, and XLA may rightly lose
+    # a scope or two (folded to a constant)
+    stale = 2 * len(written & held) < len(written)
+    if stale:
+        text = _compiled_outside_cache(lowered).as_text()
+    return parse_hlo_scopes(text), stale
+
+
+def scope_table(function_name):
+    """The scope of every instruction of the programs noted while the
+    tracer was on whose function's name holds ``function_name``: a
+    list of ``{"function", "instructions": {instruction name: (output
+    shape text, op_name)}, "outside_cache", "seconds"}``, one per
+    distinct program (several share a name: one ``slot_step_many``
+    per attended span; a reader matches a traced module to the entry
+    whose instruction names and output shapes cover the ops that
+    ran). Each program is traced, lowered and compiled here, once,
+    and never while the tracer is on. The compile is a hit in JAX's
+    persistent cache, so the text read is that of the binary that
+    ran, with one exception: JAX leaves metadata out of the cache's
+    key, so a binary cached from the same HLO before its scopes
+    existed (or under other names) is a hit and carries the old
+    metadata. Where the compiled text holds fewer than half of the
+    names the source writes, the program is compiled once more with
+    the cache switched off (XLA names instructions the same way for
+    one input and one set of flags) and ``outside_cache`` says so."""
+    from veles_tpu.observe.tracing import get_tracer
+
+    if get_tracer().enabled:
+        raise RuntimeError("scope_table() compiles; call it once the "
+                           "traced window has closed")
+    out = []
+    programs = get_compile_tracker()._programs
+    for key, entry in list(programs.items()):
+        if function_name not in key[0]:
+            continue
+        if entry[2] is None:
+            t0 = time.perf_counter()
+            try:
+                instructions, stale = _program_scopes(*entry[:2])
+            except Exception:
+                # diagnostics must not fail the run they describe: a
+                # program that cannot be compiled again has no table,
+                # and a reader counts its modules as unscoped
+                logging.getLogger("xla_stats").exception(
+                    "no scope table for %s", key[0])
+                instructions, stale = {}, False
+            entry[2] = {"function": key[0],
+                        "instructions": instructions,
+                        "outside_cache": stale,
+                        "seconds": time.perf_counter() - t0}
+        out.append(entry[2])
+    return out
 
 
 # -- device gauges ----------------------------------------------------------

@@ -96,9 +96,11 @@ def _prefill_forward(params, x, heads, length=None):
         # With a quantized cache the prompt attention still runs on the
         # exact K/V; only the CACHED copies are rounded (decode steps
         # then attend against what was stored, like every later token).
-        att = attention(q, k, v, causal=True)
-        x = x + matmul_any(att.reshape(batch, t, embed),
-                           blk["wout"]) + blk["bout"]
+        with jax.named_scope("attn.attend"):
+            att = attention(q, k, v, causal=True)
+        with jax.named_scope("attn.out"):
+            x = x + matmul_any(att.reshape(batch, t, embed),
+                               blk["wout"]) + blk["bout"]
         x = _mlp(blk, x)
     if length is None:
         last = x[:, -1]
@@ -482,28 +484,34 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
     with jax.named_scope("decode.admit"):
         logits, k_all, v_all, lengths = _prefill_forward(
             params, prompt_x, heads, lengths)
-    new = dict(
-        state,
-        lengths=state["lengths"].at[slots].set(lengths),
-        logits=state["logits"].at[slots].set(
-            logits.astype(jnp.float32)),
-        req_key=state["req_key"].at[slots].set(req_keys),
-        step=state["step"].at[slots].set(jnp.zeros_like(lengths)),
-    )
-    if "k_scale" in state:
-        for name, val in (("k", k_all), ("v", v_all)):
-            q8, scale = _quantize_kv(val)    # (L,B,T,H,D), (L,B,T,H)
-            # head-major, positions-minor slot layout (init_slot_state)
-            new[name] = state[name].at[:, slots, :, :, :t].set(
-                jnp.transpose(q8, (0, 1, 3, 4, 2)))
-            new[name + "_scale"] = \
-                state[name + "_scale"].at[:, slots, :, :t].set(
-                    jnp.transpose(scale, (0, 1, 3, 2)))
-    else:
-        new["k"] = state["k"].at[:, slots, :t].set(
-            k_all.astype(state["k"].dtype))
-        new["v"] = state["v"].at[:, slots, :t].set(
-            v_all.astype(state["v"].dtype))
+        # the sampling stream's books, then positions [0, t) of each
+        # admitted slot's K/V lane
+        with jax.named_scope("sample"):
+            new = dict(
+                state,
+                lengths=state["lengths"].at[slots].set(lengths),
+                logits=state["logits"].at[slots].set(
+                    logits.astype(jnp.float32)),
+                req_key=state["req_key"].at[slots].set(req_keys),
+                step=state["step"].at[slots].set(
+                    jnp.zeros_like(lengths)),
+            )
+        with jax.named_scope("cache.append"):
+            if "k_scale" in state:
+                for name, val in (("k", k_all), ("v", v_all)):
+                    q8, scale = _quantize_kv(val)  # (L,B,T,H,D),(L,B,T,H)
+                    # head-major, positions-minor slot layout
+                    # (init_slot_state)
+                    new[name] = state[name].at[:, slots, :, :, :t].set(
+                        jnp.transpose(q8, (0, 1, 3, 4, 2)))
+                    new[name + "_scale"] = \
+                        state[name + "_scale"].at[:, slots, :, :t].set(
+                            jnp.transpose(scale, (0, 1, 3, 2)))
+            else:
+                new["k"] = state["k"].at[:, slots, :t].set(
+                    k_all.astype(state["k"].dtype))
+                new["v"] = state["v"].at[:, slots, :t].set(
+                    v_all.astype(state["v"].dtype))
     return new
 
 
@@ -559,30 +567,38 @@ def _slot_step(params, embed_table, heads, state, active,
     if span is None or span > max_len:
         span = max_len
     lengths = state["lengths"]
-    if sample:
-        step_keys = jax.vmap(jax.random.fold_in)(state["req_key"],
-                                                 state["step"])
-        # inner shape (1, V): the SAME categorical shape generate's
-        # batch-1 path draws, so the random bits match exactly
-        tok_in = jax.vmap(
-            lambda l, k: _pick_token(l[None], k, temperature, True,
-                                     top_k)[0])(state["logits"],
-                                                step_keys)
-    else:
-        tok_in = jnp.argmax(state["logits"], axis=-1)
-    x = embed_table[tok_in][:, None, :]
+    # the named scopes of a step (HLO metadata; the scope table,
+    # observe/xla_stats.scope_table, carries them to a traced op):
+    # sample, embed, then per block attn.qkv (_block_qkv), cache.append,
+    # cache.read, attn.attend, attn.out, mlp (_mlp), then head (_head)
+    with jax.named_scope("sample"):
+        if sample:
+            step_keys = jax.vmap(jax.random.fold_in)(state["req_key"],
+                                                     state["step"])
+            # inner shape (1, V): the SAME categorical shape generate's
+            # batch-1 path draws, so the random bits match exactly
+            tok_in = jax.vmap(
+                lambda l, k: _pick_token(l[None], k, temperature, True,
+                                         top_k)[0])(state["logits"],
+                                                    step_keys)
+        else:
+            tok_in = jnp.argmax(state["logits"], axis=-1)
+    with jax.named_scope("embed"):
+        x = embed_table[tok_in][:, None, :]
     embed = x.shape[-1]
     # per-slot mask over the span: position p of slot s is visible iff
     # p <= length[s] (the new token attends to itself at index
     # length[s])
-    visible = jnp.arange(span)[None, :] <= lengths[:, None]
-    if quantized:
-        mask_addend = jnp.where(visible, 0.0, -1e30).astype(jnp.float32)
-        # python float (weak type): `q * inv_sqrt` must NOT promote a
-        # bf16 q to f32 (see decode_step)
-        inv_sqrt = (embed // heads) ** -0.5
-    else:
-        mask = visible[:, None, None, :]
+    with jax.named_scope("attn.attend"):
+        visible = jnp.arange(span)[None, :] <= lengths[:, None]
+        if quantized:
+            mask_addend = jnp.where(visible, 0.0,
+                                    -1e30).astype(jnp.float32)
+            # python float (weak type): `q * inv_sqrt` must NOT promote
+            # a bf16 q to f32 (see decode_step)
+            inv_sqrt = (embed // heads) ** -0.5
+        else:
+            mask = visible[:, None, None, :]
     new_k, new_v = state["k"], state["v"]
     new_ks = state.get("k_scale")
     new_vs = state.get("v_scale")
@@ -593,49 +609,59 @@ def _slot_step(params, embed_table, heads, state, active,
         # multi-row scatter on TPU far worse than S in-place dus ops
         # (the single biggest cost of the pre-tiled slot step).
         if quantized:
-            kq, ks = _quantize_kv(k)         # (S,1,H,D), (S,1,H)
-            vq, vs = _quantize_kv(v)
-            for s in range(slots):
-                pos = lengths[s]
-                new_k = lax.dynamic_update_slice(
-                    new_k, jnp.transpose(kq[s:s + 1], (0, 2, 3, 1))[None],
-                    (i, s, 0, 0, pos))
-                new_v = lax.dynamic_update_slice(
-                    new_v, jnp.transpose(vq[s:s + 1], (0, 2, 3, 1))[None],
-                    (i, s, 0, 0, pos))
-                new_ks = lax.dynamic_update_slice(
-                    new_ks, jnp.transpose(ks[s:s + 1], (0, 2, 1))[None],
-                    (i, s, 0, pos))
-                new_vs = lax.dynamic_update_slice(
-                    new_vs, jnp.transpose(vs[s:s + 1], (0, 2, 1))[None],
-                    (i, s, 0, pos))
-            att = int8_cache_attend(
-                q * inv_sqrt,
-                new_k[i, :, :, :, :span], new_ks[i, :, :, :span],
-                new_v[i, :, :, :, :span], new_vs[i, :, :, :span],
-                mask_addend)
+            with jax.named_scope("cache.append"):
+                kq, ks = _quantize_kv(k)         # (S,1,H,D), (S,1,H)
+                vq, vs = _quantize_kv(v)
+                for s in range(slots):
+                    pos = lengths[s]
+                    new_k = lax.dynamic_update_slice(
+                        new_k,
+                        jnp.transpose(kq[s:s + 1], (0, 2, 3, 1))[None],
+                        (i, s, 0, 0, pos))
+                    new_v = lax.dynamic_update_slice(
+                        new_v,
+                        jnp.transpose(vq[s:s + 1], (0, 2, 3, 1))[None],
+                        (i, s, 0, 0, pos))
+                    new_ks = lax.dynamic_update_slice(
+                        new_ks,
+                        jnp.transpose(ks[s:s + 1], (0, 2, 1))[None],
+                        (i, s, 0, pos))
+                    new_vs = lax.dynamic_update_slice(
+                        new_vs,
+                        jnp.transpose(vs[s:s + 1], (0, 2, 1))[None],
+                        (i, s, 0, pos))
+            with jax.named_scope("cache.read"):
+                read = (new_k[i, :, :, :, :span], new_ks[i, :, :, :span],
+                        new_v[i, :, :, :, :span], new_vs[i, :, :, :span])
+            with jax.named_scope("attn.attend"):
+                att = int8_cache_attend(q * inv_sqrt, *read, mask_addend)
         else:
-            for s in range(slots):
-                pos = lengths[s]
-                new_k = lax.dynamic_update_slice(
-                    new_k, k[s:s + 1][None].astype(new_k.dtype),
-                    (i, s, pos, 0, 0))
-                new_v = lax.dynamic_update_slice(
-                    new_v, v[s:s + 1][None].astype(new_v.dtype),
-                    (i, s, pos, 0, 0))
-            att = _cache_attend(q, new_k[i][:, :span],
-                                new_v[i][:, :span], mask)
-        att = att.astype(x.dtype)
-        x = x + matmul_any(att.reshape(slots, 1, embed),
-                           blk["wout"]) + blk["bout"]
+            with jax.named_scope("cache.append"):
+                for s in range(slots):
+                    pos = lengths[s]
+                    new_k = lax.dynamic_update_slice(
+                        new_k, k[s:s + 1][None].astype(new_k.dtype),
+                        (i, s, pos, 0, 0))
+                    new_v = lax.dynamic_update_slice(
+                        new_v, v[s:s + 1][None].astype(new_v.dtype),
+                        (i, s, pos, 0, 0))
+            with jax.named_scope("cache.read"):
+                k_span, v_span = new_k[i][:, :span], new_v[i][:, :span]
+            with jax.named_scope("attn.attend"):
+                att = _cache_attend(q, k_span, v_span, mask)
+        with jax.named_scope("attn.out"):
+            att = att.astype(x.dtype)
+            x = x + matmul_any(att.reshape(slots, 1, embed),
+                               blk["wout"]) + blk["bout"]
         x = _mlp(blk, x)
     logits = _head(params, x[:, 0]).astype(jnp.float32)
-    new_state = dict(
-        state, k=new_k, v=new_v,
-        lengths=jnp.where(active, lengths + 1, lengths),
-        logits=jnp.where(active[:, None], logits, state["logits"]),
-        step=jnp.where(active, state["step"] + 1, state["step"]),
-    )
+    with jax.named_scope("sample"):
+        new_state = dict(
+            state, k=new_k, v=new_v,
+            lengths=jnp.where(active, lengths + 1, lengths),
+            logits=jnp.where(active[:, None], logits, state["logits"]),
+            step=jnp.where(active, state["step"] + 1, state["step"]),
+        )
     if quantized:
         new_state["k_scale"] = new_ks
         new_state["v_scale"] = new_vs
@@ -680,10 +706,14 @@ slot_step_many = functools.partial(
     donate_argnames=("state",))(_slot_step_many)
 
 # compile/cache-hit/FLOPs telemetry per slot program
-# (observe/xla_stats.py): each name matches its host span and
-# named_scope, so the veles_xla_* counters, the profiler timeline and
-# the trace vocabulary line up. The wrappers delegate after one
-# attribute check while device telemetry is off.
+# (observe/xla_stats.py): each name is that of the program's host span
+# and outermost named_scope, so the veles_xla_* counters and the span
+# vocabulary agree. A profiler capture holds the span names on its
+# host plane and, on its device plane, each instruction's text and no
+# scope: the scopes reach a traced op only through the scope table
+# (xla_stats.scope_table), for which the wrappers note every program
+# they dispatch while the tracer is on. With telemetry and tracing
+# off a wrapper delegates after two attribute checks.
 _generate_jit = instrument("decode.generate", _generate_jit)
 slot_admit_many = instrument("decode.admit", slot_admit_many)
 slot_step = instrument("decode.step", slot_step)

@@ -44,6 +44,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from veles_tpu.core.units import Unit
+from veles_tpu.observe.tracing import get_tracer
+from veles_tpu.observe.xla_stats import instrument
 from veles_tpu.parallel import mapreduce
 from veles_tpu.parallel.mesh import shard_map
 from veles_tpu.loader.base import TRAIN, VALID
@@ -388,9 +390,19 @@ def build_tick(specs, norm_type="none", mesh=None,
             return batch
         return transform(batch, seed)
 
+    # the named scopes below are HLO metadata only: they cost nothing
+    # at run time and reach a number through the scope table
+    # (observe/xla_stats.scope_table), which tells a traced op's
+    # instruction back to ``data`` / ``fwd`` / ``update`` / ``reduce``
+    # and the layer inside. Backward needs none of its own: JAX writes
+    # ``transpose(jvp(fwd))`` where an op is ``fwd``'s gradient
+    layer_scopes = ["l%d_%s" % (i, spec["kind"])
+                    for i, spec in enumerate(specs)]
+
     def model_forward(wb, x):
-        for fwd, p in zip(layer_fwds, wb):
-            x = fwd(p, x)
+        for fwd, p, scope in zip(layer_fwds, wb, layer_scopes):
+            with jax.named_scope(scope):
+                x = fwd(p, x)
         return x
 
     def local_mask(n_local, valid):
@@ -402,21 +414,24 @@ def build_tick(specs, norm_type="none", mesh=None,
     def metrics_of(wb, batch, lab, mask, valid):
         """``lab`` is int labels (softmax) or float targets (mse) — both
         gathered from the device-resident originals by the same indices."""
-        logits = model_forward(wb, batch)
-        if loss_kind == "mse":
-            _, loss_sum, _ = losses.masked_mse(logits, lab, mask, valid)
-            return loss_sum, jnp.int32(0), logits
-        _, loss_sum, n_err, _ = losses.masked_softmax_xent(
-            logits, lab, mask, valid)
-        return loss_sum, n_err, logits
+        with jax.named_scope("fwd"):
+            logits = model_forward(wb, batch)
+            if loss_kind == "mse":
+                _, loss_sum, _ = losses.masked_mse(logits, lab, mask,
+                                                   valid)
+                return loss_sum, jnp.int32(0), logits
+            _, loss_sum, n_err, _ = losses.masked_softmax_xent(
+                logits, lab, mask, valid)
+            return loss_sum, n_err, logits
 
     # cores return the UNNORMALIZED loss_sum; wrappers divide by the
     # relevant valid count (per minibatch or per sweep)
     def core_train(params, hypers, norm, data, labels, indices, valid,
                    seed):
-        batch, lab = gather_norm(data, labels, indices, norm)
-        batch = apply_augment(batch, seed)
-        mask = local_mask(indices.shape[0], valid)
+        with jax.named_scope("data"):
+            batch, lab = gather_norm(data, labels, indices, norm)
+            batch = apply_augment(batch, seed)
+            mask = local_mask(indices.shape[0], valid)
         wb = [p["p"] if p else {} for p in params]
 
         def loss_fn(wb):
@@ -430,38 +445,42 @@ def build_tick(specs, norm_type="none", mesh=None,
             # gradients merge at the configured wire tier (f32 IS the
             # plain psum, bit-identical to the pre-tier programs);
             # metric scalars always reduce exact
-            grads = mapreduce.reduce_sum(grads, "data",
-                                         precision=grad_reduce)
-            loss_sum = mapreduce.reduce_sum(loss_sum, "data")
-            n_err = mapreduce.reduce_sum(n_err, "data")
+            with jax.named_scope("reduce"):
+                grads = mapreduce.reduce_sum(grads, "data",
+                                             precision=grad_reduce)
+                loss_sum = mapreduce.reduce_sum(loss_sum, "data")
+                n_err = mapreduce.reduce_sum(n_err, "data")
         new = []
-        for p, g, hyper, spec in zip(params, grads, hypers, specs):
+        for p, g, hyper, spec, scope in zip(params, grads, hypers,
+                                            specs, layer_scopes):
             if not p:
                 new.append({})
                 continue
             from veles_tpu.nn.gd import make_updater
-            lr, lr_b, l2, l1 = hyper[0], hyper[1], hyper[2], hyper[3]
-            solver = spec.get("solver", "momentum")
-            step = p["t"] + 1.0 if solver != "momentum" else None
-            upd = make_updater(solver, hyper, step)
-            entry = {"p": {}, "v": {}}
-            if solver != "momentum":
-                entry["s"], entry["t"] = {}, step
-            # per-leaf policy from the spec table: which rate applies
-            # and whether l2/l1 decay does — matching each graph-mode GD
-            # unit's exact update math (same make_updater)
-            for leaf, _, _, use_lr_b, decay in spec["leaves"]:
-                w, gw, vel = p["p"][leaf], g[leaf], p["v"][leaf]
-                if decay:
-                    gw = gw + l2 * w + l1 * jnp.sign(w)
-                w2, v2, s2 = upd(w, gw, vel,
-                                 p["s"][leaf] if solver != "momentum"
-                                 else None,
-                                 lr_b if use_lr_b else lr)
-                entry["p"][leaf] = w2
-                entry["v"][leaf] = v2
+            with jax.named_scope("update"), jax.named_scope(scope):
+                lr, lr_b, l2, l1 = hyper[0], hyper[1], hyper[2], hyper[3]
+                solver = spec.get("solver", "momentum")
+                step = p["t"] + 1.0 if solver != "momentum" else None
+                upd = make_updater(solver, hyper, step)
+                entry = {"p": {}, "v": {}}
                 if solver != "momentum":
-                    entry["s"][leaf] = s2
+                    entry["s"], entry["t"] = {}, step
+                # per-leaf policy from the spec table: which rate
+                # applies and whether l2/l1 decay does — matching each
+                # graph-mode GD unit's exact update math (same
+                # make_updater)
+                for leaf, _, _, use_lr_b, decay in spec["leaves"]:
+                    w, gw, vel = p["p"][leaf], g[leaf], p["v"][leaf]
+                    if decay:
+                        gw = gw + l2 * w + l1 * jnp.sign(w)
+                    w2, v2, s2 = upd(w, gw, vel,
+                                     p["s"][leaf] if solver != "momentum"
+                                     else None,
+                                     lr_b if use_lr_b else lr)
+                    entry["p"][leaf] = w2
+                    entry["v"][leaf] = v2
+                    if solver != "momentum":
+                        entry["s"][leaf] = s2
             new.append(entry)
         return new, (loss_sum, n_err)
 
@@ -469,16 +488,20 @@ def build_tick(specs, norm_type="none", mesh=None,
         """Eval additionally emits the confusion-matrix increment (when
         the evaluator asked for it), so the MatrixPlotter / Decision
         accumulation work in fused mode too."""
-        batch, lab = gather_norm(data, labels, indices, norm)
-        mask = local_mask(indices.shape[0], valid)
+        with jax.named_scope("data"):
+            batch, lab = gather_norm(data, labels, indices, norm)
+            mask = local_mask(indices.shape[0], valid)
         wb = [p["p"] if p else {} for p in params]
         loss_sum, n_err, logits = metrics_of(wb, batch, lab, mask, valid)
-        cm = (losses.confusion_matrix(logits, lab, logits.shape[-1], mask)
-              if with_confusion else jnp.zeros((1, 1), jnp.int32))
+        with jax.named_scope("fwd"):
+            cm = (losses.confusion_matrix(logits, lab, logits.shape[-1],
+                                          mask)
+                  if with_confusion else jnp.zeros((1, 1), jnp.int32))
         if data_ax > 1:
-            loss_sum = mapreduce.reduce_sum(loss_sum, "data")
-            n_err = mapreduce.reduce_sum(n_err, "data")
-            cm = mapreduce.reduce_sum(cm, "data")
+            with jax.named_scope("reduce"):
+                loss_sum = mapreduce.reduce_sum(loss_sum, "data")
+                n_err = mapreduce.reduce_sum(n_err, "data")
+                cm = mapreduce.reduce_sum(cm, "data")
         return loss_sum, n_err, cm
 
     def local_train(params, hypers, norm, data, labels, indices, valid,
@@ -520,10 +543,18 @@ def build_tick(specs, norm_type="none", mesh=None,
                 jnp.sum(cms, axis=0))
 
     if data_ax == 1:
-        steps = (jax.jit(local_train, donate_argnums=(0,)),
-                 jax.jit(local_eval),
-                 jax.jit(local_train_sweep, donate_argnums=(0,)),
-                 jax.jit(local_eval_sweep))
+        # instrumented like every other hot program: compiles and hits
+        # on /metrics, and noted for the scope table while a traced
+        # window is open (observe/xla_stats.py). The meshed tick gets
+        # the same through mapreduce.fleet_train_step
+        steps = (instrument("fused.train_step",
+                            jax.jit(local_train, donate_argnums=(0,))),
+                 instrument("fused.eval_step", jax.jit(local_eval)),
+                 instrument("fused.train_sweep",
+                            jax.jit(local_train_sweep,
+                                    donate_argnums=(0,))),
+                 instrument("fused.eval_sweep",
+                            jax.jit(local_eval_sweep)))
         _TICK_CACHE[key] = steps
         return steps
     eval_specs = (P(), P(), P(), P(), P("data"), P())
@@ -747,28 +778,37 @@ class FusedTick(Unit):
             # mutate nothing — no slot, rollback_job is then a no-op
             self._rollback_ = (jax.tree.map(jnp.copy, self._params_)
                                if training else None)
+        # one span per dispatch, round the call alone (argument
+        # preparation to the jitted call's return): what the host pays
+        # to put a program on the device's queue
+        tracer = get_tracer()
         if getattr(loader, "sweep_serving", False):
             sizes = loader.sweep_valid_sizes
             if training:
-                seeds = getattr(loader, "sweep_transform_seeds", None)
-                if seeds is None:
-                    seeds = numpy.zeros(len(sizes), numpy.int64)
-                self._params_, (loss, n_err) = train_sweep(
-                    self._params_, get_hypers(wf), norm, data, labels,
-                    indices, sizes, valid, seeds)
+                with tracer.span("engine.train_sweep"):
+                    seeds = getattr(loader, "sweep_transform_seeds",
+                                    None)
+                    if seeds is None:
+                        seeds = numpy.zeros(len(sizes), numpy.int64)
+                    self._params_, (loss, n_err) = train_sweep(
+                        self._params_, get_hypers(wf), norm, data,
+                        labels, indices, sizes, valid, seeds)
             else:
-                loss, n_err, cm = eval_sweep(self._params_, norm, data,
-                                             labels, indices, sizes,
-                                             valid)
+                with tracer.span("engine.eval_sweep"):
+                    loss, n_err, cm = eval_sweep(
+                        self._params_, norm, data, labels, indices,
+                        sizes, valid)
         elif training:
-            seed = numpy.int64(getattr(loader, "minibatch_transform_seed",
-                                       0))
-            self._params_, (loss, n_err) = train_step(
-                self._params_, get_hypers(wf), norm, data, labels,
-                indices, valid, seed)
+            with tracer.span("engine.train_step"):
+                seed = numpy.int64(getattr(
+                    loader, "minibatch_transform_seed", 0))
+                self._params_, (loss, n_err) = train_step(
+                    self._params_, get_hypers(wf), norm, data, labels,
+                    indices, valid, seed)
         else:
-            loss, n_err, cm = eval_step(self._params_, norm, data,
-                                        labels, indices, valid)
+            with tracer.span("engine.eval_step"):
+                loss, n_err, cm = eval_step(self._params_, norm, data,
+                                            labels, indices, valid)
         evaluator = wf.evaluator
         evaluator.loss.data = loss
         if getattr(evaluator, "n_err", None) is not None:
@@ -787,12 +827,12 @@ class FusedTick(Unit):
                 # the client ships (docs/compiler_fleet.md); per-job
                 # updates carry scalars only
                 if bool(loader.epoch_ended):
-                    set_params(wf, self._params_, self._specs_)
+                    self._write_back(self._params_)
             elif training:
                 # data plane (one tick per job): write the trained
                 # weights straight back so generate_data_for_master
                 # ships them; epoch accounting lives on the master
-                set_params(wf, self._params_, self._specs_)
+                self._write_back(self._params_)
             return
         if not training and loader.epoch_ended_for_class:
             # write the EVALUATED weights into the unit Arrays now —
@@ -808,13 +848,14 @@ class FusedTick(Unit):
                 # the params the PREVIOUS epoch evaluated, stash the
                 # ones this epoch's eval sweep is scoring right now.
                 if not self._stashed_this_epoch_:
-                    current = jax.tree.map(jnp.copy, self._params_)
+                    with tracer.span("engine.write_back"):
+                        current = jax.tree.map(jnp.copy, self._params_)
                     if self._eval_stash_ is not None:
-                        set_params(wf, self._eval_stash_, self._specs_)
+                        self._write_back(self._eval_stash_)
                     self._eval_stash_ = current
                     self._stashed_this_epoch_ = True
             else:
-                set_params(wf, self._params_, self._specs_)
+                self._write_back(self._params_)
             self._wrote_eval_params_ = True
         if loader.epoch_ended:
             # the eval-tick write stands in for the epoch-end one ONLY
@@ -826,9 +867,16 @@ class FusedTick(Unit):
             eval_covers = (getattr(self, "_wrote_eval_params_", False)
                            and loader.effective_class_lengths[VALID] > 0)
             if training and not eval_covers:
-                set_params(wf, self._params_, self._specs_)
+                self._write_back(self._params_)
             self._wrote_eval_params_ = False
             self._stashed_this_epoch_ = False
+
+    def _write_back(self, params):
+        """Copy ``params`` into the unit Arrays (an eval or epoch
+        fence), under a span of its own: one device copy per leaf,
+        dispatched from the host between two sweeps."""
+        with get_tracer().span("engine.write_back"):
+            set_params(self.workflow, params, self._specs_)
 
     @staticmethod
     def _control_plane():

@@ -60,15 +60,19 @@ def _ln(x, w, b, eps=1e-5):
 # The sublayer helpers are shared with the KV-cache decode path
 # (parallel/decode.py) — ONE copy of the block math keeps the cached
 # and full-recompute forwards numerically equivalent by construction.
+# Each writes its ``jax.named_scope`` (``attn.qkv``, ``mlp``, ``head``:
+# HLO metadata, nothing at run time), which the scope table
+# (observe/xla_stats.scope_table) carries to a traced op.
 
 def _block_qkv(blk, x, heads):
     """Pre-LN qkv projection: (B, T, E) -> three (B, T, H, D)."""
     batch, t, embed = x.shape
-    h = _ln(x, blk["ln1_w"], blk["ln1_b"])
-    qkv = matmul_any(h, blk["wqkv"]) + blk["bqkv"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (batch, t, heads, embed // heads)
-    return q.reshape(shape), k.reshape(shape), v.reshape(shape)
+    with jax.named_scope("attn.qkv"):
+        h = _ln(x, blk["ln1_w"], blk["ln1_b"])
+        qkv = matmul_any(h, blk["wqkv"]) + blk["bqkv"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        shape = (batch, t, heads, embed // heads)
+        return q.reshape(shape), k.reshape(shape), v.reshape(shape)
 
 
 def _mlp(blk, x, reduce=None):
@@ -78,18 +82,20 @@ def _mlp(blk, x, reduce=None):
     single-device and TP paths alike. The products route through
     ``matmul_any`` so the int8 serving tier (``ops/quant.py``) shares
     this exact sublayer math."""
-    h = _ln(x, blk["ln2_w"], blk["ln2_b"])
-    y = matmul_any(jax.nn.gelu(matmul_any(h, blk["w1"]) + blk["b1"]),
-                   blk["w2"])
-    if reduce is not None:
-        y = reduce(y)
-    return x + y + blk["b2"]
+    with jax.named_scope("mlp"):
+        h = _ln(x, blk["ln2_w"], blk["ln2_b"])
+        y = matmul_any(jax.nn.gelu(matmul_any(h, blk["w1"]) + blk["b1"]),
+                       blk["w2"])
+        if reduce is not None:
+            y = reduce(y)
+        return x + y + blk["b2"]
 
 
 def _head(params, x):
     """Final layer norm + vocab projection."""
-    return matmul_any(_ln(x, params["lnf_w"], params["lnf_b"]),
-                      params["head"])
+    with jax.named_scope("head"):
+        return matmul_any(_ln(x, params["lnf_w"], params["lnf_b"]),
+                          params["head"])
 
 
 def _forward(params, x, heads, seq_ax, sp_strategy):

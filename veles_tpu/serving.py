@@ -3116,6 +3116,7 @@ class GenerateAPI:
         (``collect_chunk`` consults the live budget map)."""
         waiting = {}
         backoff = self.rebuild_backoff
+        tracer = get_tracer()
         try:
             while not self._stop.is_set():
                 if self._tripped is not None:
@@ -3138,52 +3139,58 @@ class GenerateAPI:
                         backoff = min(backoff * 2,
                                       self.rebuild_backoff_max)
                     continue
-                if self.governor is not None:
-                    # the closed loop rides the driver thread — one
-                    # rate-limited pass, and a broken governor must
-                    # never take the driver down with it
-                    try:
-                        self.governor.tick(self)
-                    except Exception:
-                        import traceback
-                        traceback.print_exc()
-                if self._trip_request is not None:
-                    # proactive breaker guard: treat the predicted
-                    # stall exactly like a real one — shed retryably,
-                    # rebuild behind the probe
-                    reason = self._trip_request
-                    self._trip_request = None
-                    self._pending = None
-                    self._trip(RuntimeError(reason), waiting)
-                    continue
-                if self._rollout_request is not None:
-                    holder = self._rollout_request
-                    self._rollout_request = None
-                    self._start_green(holder)
-                if self._tier_request is None \
-                        and self._swap_request is None:
-                    waiting.update(self._drain_staged())
-                # while a tier swap OR weight swap is pending the
-                # staged queue HOLDS: in-flight requests drain on the
-                # admitted tier/weights (the bit-identity contract),
-                # then the idle branch swaps and the next pass admits
-                # into the new decoder/weights
-                self._expire_deadlines(waiting)
-                green = self._green
-                if green is not None:
-                    self._expire_deadlines(green["waiting"],
-                                           decoder=green["decoder"])
-                    # the rollout's control loop rides the driver
-                    # thread like the governor's; a broken rollout
-                    # must never take the driver down
-                    if self._rollout is not None:
+                # the pass's books, from a collect's end to the next
+                # dispatch's start (with the block after the collect,
+                # below): one span name, so that a device idle gap
+                # under them reads as the scheduler's and not as
+                # nobody's
+                with tracer.span("serve.drive_books"):
+                    if self.governor is not None:
+                        # the closed loop rides the driver thread — one
+                        # rate-limited pass, and a broken governor must
+                        # never take the driver down with it
                         try:
-                            self._rollout.tick(self)
+                            self.governor.tick(self)
                         except Exception:
                             import traceback
                             traceback.print_exc()
-                    self._rollout_step(waiting)
-                    green = self._green  # _rollout_step may clear it
+                    if self._trip_request is not None:
+                        # proactive breaker guard: treat the predicted
+                        # stall exactly like a real one — shed retryably,
+                        # rebuild behind the probe
+                        reason = self._trip_request
+                        self._trip_request = None
+                        self._pending = None
+                        self._trip(RuntimeError(reason), waiting)
+                        continue
+                    if self._rollout_request is not None:
+                        holder = self._rollout_request
+                        self._rollout_request = None
+                        self._start_green(holder)
+                    if self._tier_request is None \
+                            and self._swap_request is None:
+                        waiting.update(self._drain_staged())
+                    # while a tier swap OR weight swap is pending the
+                    # staged queue HOLDS: in-flight requests drain on the
+                    # admitted tier/weights (the bit-identity contract),
+                    # then the idle branch swaps and the next pass admits
+                    # into the new decoder/weights
+                    self._expire_deadlines(waiting)
+                    green = self._green
+                    if green is not None:
+                        self._expire_deadlines(green["waiting"],
+                                               decoder=green["decoder"])
+                        # the rollout's control loop rides the driver
+                        # thread like the governor's; a broken rollout
+                        # must never take the driver down
+                        if self._rollout is not None:
+                            try:
+                                self._rollout.tick(self)
+                            except Exception:
+                                import traceback
+                                traceback.print_exc()
+                        self._rollout_step(waiting)
+                        green = self._green  # _rollout_step may clear it
                 blue_idle = not self.decoder.busy \
                     and self._pending is None
                 green_idle = green is None \
@@ -3208,7 +3215,8 @@ class GenerateAPI:
                     # the whole idle wall time into the step-time EMA
                     self.decoder._last_chunk_done = None
                     idle_from = time.monotonic()
-                    woke = self._wake.wait(timeout=0.05)
+                    with tracer.span("serve.drive_idle"):
+                        woke = self._wake.wait(timeout=0.05)
                     # queue-empty wall lands in the goodput
                     # decomposition as idle, not host
                     self.scope.note_idle(time.monotonic() - idle_from)
@@ -3223,17 +3231,19 @@ class GenerateAPI:
                         if self._pending is not None:
                             self.decoder.collect_chunk(self._pending)
                         self._pending = current
-                        self._note_progress(waiting)
-                    # the waste/occupancy autopsy (OFF the record
-                    # path): trend series + detector-owned anomaly
-                    # rules + a cooldown-limited incident naming the
-                    # dominant waste cause; a broken autopsy must
-                    # never take the driver down
-                    try:
-                        self.scope.autopsy_tick(get_metric_history())
-                    except Exception:
-                        import traceback
-                        traceback.print_exc()
+                    with tracer.span("serve.drive_books"):
+                        if not blue_idle:
+                            self._note_progress(waiting)
+                        # the waste/occupancy autopsy (OFF the record
+                        # path): trend series + detector-owned anomaly
+                        # rules + a cooldown-limited incident naming
+                        # the dominant waste cause; a broken autopsy
+                        # must never take the driver down
+                        try:
+                            self.scope.autopsy_tick(get_metric_history())
+                        except Exception:
+                            import traceback
+                            traceback.print_exc()
                 except Exception as exc:  # device/runtime failure
                     import traceback
                     traceback.print_exc()
