@@ -353,7 +353,7 @@ def paged_kernel_agreement(heads=16, head_dims=(64, 128), page_size=128,
     from veles_tpu.ops import paged_attention as pgatt
     from veles_tpu.ops.quant import int8_cache_attend
     from veles_tpu.parallel import kv_pool
-    from veles_tpu.parallel.decode import _cache_attend
+    from veles_tpu.parallel.decode import _cache_attend, _positions_last
 
     rng = numpy.random.RandomState(seed)
     pool_pages = slots * pages_per_slot + 1
@@ -375,7 +375,8 @@ def paged_kernel_agreement(heads=16, head_dims=(64, 128), page_size=128,
             pool = {"k": jnp.asarray(rng.randn(*kv_shape), dtype),
                     "v": jnp.asarray(rng.randn(*kv_shape), dtype)}
             k_g, v_g = kv_pool._gather_block_float(pool, 0, page_table)
-            want = _cache_attend(q[:, None], k_g, v_g,
+            want = _cache_attend(q[:, None], _positions_last(k_g),
+                                 _positions_last(v_g),
                                  visible[:, None, None, :])[:, 0]
             got = pgatt.paged_attend(q, pool["k"][0], pool["v"][0],
                                      page_table, lengths,

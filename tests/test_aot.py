@@ -189,6 +189,37 @@ class TestCompatGate:
         assert dec.results == ref.results
 
 
+    def test_stacked_slab_bundle_is_a_miss_not_an_execute(
+            self, model, dense_bundle):
+        """A bundle built when the slab was one stacked ``(L, ...)``
+        array per name has the serving geometry of today and other
+        operand avals: every program of it is refused at its first
+        dispatch by aval mismatch and served live, bit-identical, and
+        the manifest's operand rows say shape, dtype, spec and
+        layout."""
+        params, table = model
+        aot = load_bundle(dense_bundle, prefetch=False)
+        leaf = [DENSE_KW["slots"], EMBED, DENSE_KW["max_len"]]
+        for entry in aot._entries.values():
+            rows = entry.row["in_avals"]
+            assert all(len(row) == 4 for row in rows)
+            assert sum(row[0] == leaf for row in rows) == 2 * BLOCKS \
+                or not entry.row["name"].startswith("decode.")
+            # the bundle of before: K and V stacked over the blocks
+            kept = [row for row in rows if row[0] != leaf]
+            stacked = [[BLOCKS] + leaf, rows[0][1], "", ""]
+            entry.row["in_avals"] = kept + [stacked, stacked]
+        dec = ContinuousDecoder(params, table, HEADS, aot=aot,
+                                **DENSE_KW)
+        assert dec.aot_active           # the geometry is today's
+        ref = ContinuousDecoder(params, table, HEADS, **DENSE_KW)
+        for d in (dec, ref):
+            _drain(d, _prompts(3))
+        assert dec.results == ref.results
+        stats = aot.stats()
+        assert not stats["hits"] and sum(stats["misses"].values()) > 0
+
+
 class TestBitIdentity:
     """AOT-loaded programs must stream EXACTLY what live-compiled ones
     do — the wire-format conversion is a bit-level reinterpretation."""

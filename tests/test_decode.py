@@ -139,34 +139,196 @@ def test_generate_sampling_reproducible_and_topk_bounded(model):
                                      numpy.asarray(top1))
 
 
-def test_slot_step_span_tiling_is_inert(model):
+def _tier_model(model, tier):
+    """(params, table, dtype, quantize, state kwargs) of a serving
+    tier: float32, bfloat16, or int8-KV (int8 weights and cache)."""
+    from veles_tpu.parallel.decode import quantize_params
+
+    params, table = model
+    if tier == "bfloat16":
+        cast = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+        return jax.tree.map(cast, params), cast(table), None
+    if tier == "int8-kv":
+        return quantize_params(params), table, "int8-kv"
+    return params, table, None
+
+
+def _admitted(params, table, quantize, lens, max_len, seed=7):
+    """A slot state with one prompt of each of ``lens`` admitted,
+    and the prompts."""
+    from veles_tpu.parallel.decode import init_slot_state, slot_admit
+
+    rng = numpy.random.RandomState(seed)
+    state = init_slot_state(BLOCKS, len(lens), max_len, HEADS,
+                            EMBED // HEADS, VOCAB, dtype=table.dtype,
+                            quantized=quantize == "int8-kv")
+    prompts = []
+    for slot, n in enumerate(lens):
+        prompts.append(jnp.asarray(rng.randint(0, VOCAB, (1, n))))
+        state = slot_admit(params, table, HEADS, state, jnp.int32(slot),
+                           table[prompts[-1]])
+    return state, prompts
+
+
+TIERS = ["float32", "bfloat16", "int8-kv"]
+# staggered lengths; the last case holds a slot at max_len - 1, whose
+# append lands on the lane's last position
+SPAN_CASES = [((5, 3), 24), ((1, 9, 4), 24), ((6, 23), 24)]
+
+
+@pytest.mark.parametrize("lens, max_len", SPAN_CASES,
+                         ids=["short", "staggered", "lane_end"])
+@pytest.mark.parametrize("tier", TIERS)
+def test_slot_step_span_tiling_is_inert(model, tier, lens, max_len):
     """The tiled slot attention contract: any span covering the
     longest live sequence (+1 for the appended token) produces
     bit-identical state updates and emitted tokens vs attending the
     whole max_len lane — masked positions contribute exact zeros."""
-    from veles_tpu.parallel.decode import (init_slot_state, slot_admit,
-                                           slot_step)
+    from veles_tpu.parallel.decode import slot_step
 
-    params, table = model
-    rng = numpy.random.RandomState(7)
-    state = init_slot_state(BLOCKS, 2, 24, HEADS, EMBED // HEADS, VOCAB)
-    for slot, n in enumerate((5, 3)):
-        prompt = jnp.asarray(rng.randint(0, VOCAB, (1, n)))
-        state = slot_admit(params, table, HEADS, state,
-                           jnp.int32(slot), table[prompt])
-    active = jnp.asarray([True, True])
-    full_state = jax.tree.map(jnp.copy, state)
-    for span in (8, 16, 24):
+    params, table, quantize = _tier_model(model, tier)
+    state, _ = _admitted(params, table, quantize, lens, max_len)
+    active = jnp.ones((len(lens),), bool)
+    full, tok_full = slot_step(params, table, HEADS,
+                               jax.tree.map(jnp.copy, state), active)
+    spans = [n for n in (8, 16, 24) if n > max(lens)]
+    for span in spans:
         tiled, tok_tiled = slot_step(params, table, HEADS,
                                      jax.tree.map(jnp.copy, state),
                                      active, span=span)
-        full, tok_full = slot_step(params, table, HEADS,
-                                   jax.tree.map(jnp.copy, full_state),
-                                   active)
         numpy.testing.assert_array_equal(numpy.asarray(tok_tiled),
                                          numpy.asarray(tok_full))
         numpy.testing.assert_array_equal(
             numpy.asarray(tiled["logits"]), numpy.asarray(full["logits"]))
+        for name in ("k", "v"):
+            for one, other in zip(tiled[name], full[name]):
+                numpy.testing.assert_array_equal(numpy.asarray(one),
+                                                 numpy.asarray(other))
+
+
+@pytest.mark.parametrize("tier", TIERS)
+def test_slot_step_many_tokens_match_generate(model, tier):
+    """Chunks of 4 lockstep steps over staggered slots emit, slot by
+    slot, the tokens ``generate`` emits for that prompt alone."""
+    from veles_tpu.parallel.decode import slot_step_many
+
+    params, table, quantize = _tier_model(model, tier)
+    lens, n_tokens = (5, 3, 9), 8
+    # int8-KV generate rounds its cache up to whole lane tiles
+    max_len = 128 if quantize else 24
+    state, prompts = _admitted(params, table, quantize, lens, max_len)
+    active = jnp.ones((len(lens),), bool)
+    emitted = []
+    for chunk in range(n_tokens // 4):
+        state, toks = slot_step_many(
+            params, table, HEADS, state, active, 4,
+            span=16 if chunk == 0 else max_len)
+        emitted.append(numpy.asarray(toks))
+    emitted = numpy.concatenate(emitted)                  # (n, S)
+    for slot, prompt in enumerate(prompts):
+        want, _ = generate(params, table, prompt, HEADS,
+                           n_tokens=n_tokens, max_len=max_len,
+                           quantize=quantize)
+        numpy.testing.assert_array_equal(emitted[:, slot],
+                                         numpy.asarray(want)[0])
+
+
+def _eqns(jaxpr, scope=()):
+    """Every equation of a jaxpr and of the jaxprs inside it, with the
+    scope names on its name stack."""
+    from veles_tpu.observe.xla_stats import scope_names
+
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        names = tuple(scope) + tuple(scope_names(stack) if stack else ())
+        yield eqn, names
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else (value,)):
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    yield from _eqns(inner, names)
+
+
+@pytest.mark.parametrize("tier", ["float32", "int8-kv"])
+def test_chunk_reads_one_window_per_leaf(model, tier):
+    """The structure the chunk program's speed rests on: under
+    ``cache.read`` every block produces exactly one value per K/V
+    leaf, of the span's size, and no value of a leaf's ``max_len``
+    size exists but what each leaf's one write a chunk returns, so
+    nothing copies a layer at full length."""
+    from veles_tpu.parallel.decode import KV_LEAVES, _slot_step_many
+
+    params, table, quantize = _tier_model(model, tier)
+    slots, max_len, span = 3, 32, 16
+    state, _ = _admitted(params, table, quantize, (5, 3, 9), max_len)
+    names = [name for name in KV_LEAVES if name in state]
+    assert all(isinstance(state[name], tuple)
+               and len(state[name]) == BLOCKS for name in names)
+    jaxpr = jax.make_jaxpr(
+        lambda st: _slot_step_many(params, table, HEADS, st,
+                                   jnp.ones((slots,), bool), 4,
+                                   span=span))(state)
+    leaf_shapes = {state[name][0].shape for name in names}
+    window_shapes = sorted(shape[:-1] + (span,) for shape in leaf_shapes)
+    reads, full = [], []
+    for eqn, scope in _eqns(jaxpr):
+        shapes = [tuple(var.aval.shape) for var in eqn.outvars]
+        if "cache.read" in scope:
+            reads.extend(shapes)
+        if any(shape in leaf_shapes for shape in shapes):
+            full.append(eqn.primitive.name)
+    # one read per leaf per block; the scan's body is traced once
+    assert len(reads) == len(names) * BLOCKS, reads
+    assert sorted(set(reads)) == window_shapes
+    # full-length values: each leaf's one write a chunk (a loop over
+    # the slots round one append), nothing else. The loop of steps
+    # itself carries no leaf: it reads them where they lie
+    assert set(full) <= {"dynamic_update_slice", "scan"}, set(full)
+    assert full.count("dynamic_update_slice") == len(names) * BLOCKS
+    assert full.count("scan") == len(names) * BLOCKS
+
+
+def test_layout_is_decided_once_and_pinned_in_and_out(model):
+    """The layout mechanism end to end where it can run off the chip:
+    the compiler is asked once per state skeleton, the zeros are built
+    in its answer, the programs are found by what the leaves carry,
+    every program's K/V come back as they went in, and a program is
+    lowered once whether its state is fresh or another program's
+    output."""
+    import functools
+
+    from veles_tpu.parallel import decode
+
+    params, table = model
+    build = functools.partial(
+        decode.init_slot_state, BLOCKS, 2, 32, HEADS, EMBED // HEADS,
+        VOCAB)
+    formats = decode.decide_slot_formats(
+        params, table, HEADS, jax.eval_shape(build), 4, 16)
+    assert sorted(formats) == ["k", "v"]
+    assert decode.decide_slot_formats(
+        params, table, HEADS, jax.eval_shape(build), 4, 16) is formats
+    state = build(formats=formats)
+    fns = decode.slot_fns(state)
+    for name in ("k", "v"):
+        assert all(leaf.format == formats[name] for leaf in state[name])
+    state = decode.slot_admit(params, table, HEADS, state, jnp.int32(0),
+                              table[jnp.asarray([[1, 2, 3]])])
+    active = jnp.asarray([True, False])
+    chunk = fns[2].__wrapped__
+    lowered = chunk._cache_size()
+    for _ in range(3):
+        state, _ = decode.slot_step_many(params, table, HEADS, state,
+                                         active, 4, span=16)
+        assert decode.slot_fns(state) is fns
+        for name in ("k", "v"):
+            assert all(leaf.format == formats[name]
+                       for leaf in state[name])
+    assert chunk._cache_size() == lowered + 1
+    # a state that an outer trace holds has nothing to read a place
+    # off: the unpinned programs serve it
+    assert decode.slot_fns(jax.eval_shape(build)) is not fns
 
 
 def test_slot_admit_many_matches_single_admits(model):
@@ -202,13 +364,24 @@ def test_slot_admit_many_matches_single_admits(model):
         jnp.asarray(list(lens) + [lens[-1]], jnp.int32))
     numpy.testing.assert_array_equal(numpy.asarray(ref["lengths"]),
                                      numpy.asarray(batched["lengths"]))
-    numpy.testing.assert_array_equal(numpy.asarray(ref["logits"]),
-                                     numpy.asarray(batched["logits"]))
-    # the written K/V slabs agree wherever a real prompt lives
-    for slot, n in enumerate(lens):
-        numpy.testing.assert_array_equal(
-            numpy.asarray(ref["k"][:, slot, :n]),
-            numpy.asarray(batched["k"][:, slot, :n]))
+    # a batched and a single-row prefill reassociate their matmuls:
+    # the logits agree to rounding, everything stored agrees exactly
+    numpy.testing.assert_allclose(numpy.asarray(ref["logits"]),
+                                  numpy.asarray(batched["logits"]),
+                                  rtol=1e-5, atol=1e-6)
+    numpy.testing.assert_array_equal(
+        numpy.asarray(jax.random.key_data(ref["req_key"])),
+        numpy.asarray(jax.random.key_data(batched["req_key"])))
+    # the written K/V rows agree wherever a real prompt lives, in
+    # every block's leaf (S, H·D, T)
+    for name in ("k", "v"):
+        assert len(ref[name]) == len(batched[name]) == BLOCKS
+        for one, many in zip(ref[name], batched[name]):
+            assert one.shape == (4, EMBED, 24)
+            for slot, n in enumerate(lens):
+                numpy.testing.assert_array_equal(
+                    numpy.asarray(one[slot, :, :n]),
+                    numpy.asarray(many[slot, :, :n]))
 
 
 def test_tensor_parallel_decode_smoke_2dev():

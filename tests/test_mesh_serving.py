@@ -81,13 +81,31 @@ class TestShardedSlotEngine:
         silently replicated KV slab would pass the token test while
         storing H x the memory per device."""
         params, table = model
+        from jax.sharding import NamedSharding
+
+        from veles_tpu.parallel.decode import slot_state_specs
+
         _, got = _drain_pair(params, table, mesh)
-        assert not got.state["k"].sharding.is_fully_replicated
         assert not got.params["blocks"][0]["wqkv"] \
             .sharding.is_fully_replicated
         _, got8 = _drain_pair(params, table, mesh, quantize="int8-kv")
-        assert not got8.state["k"].sharding.is_fully_replicated
-        assert not got8.state["k_scale"].sharding.is_fully_replicated
+        for dec, quantized in ((got, False), (got8, True)):
+            # one K and one V leaf per block (and the int8 tier's
+            # scales), each where slot_state_specs, which mirrors the
+            # state leaf for leaf, says: split over heads
+            n_blocks = len(dec.params["blocks"])
+            specs = slot_state_specs(n_blocks, quantized)
+            assert jax.tree.structure(specs) \
+                == jax.tree.structure(dec.state)
+            names = ("k", "v") + (("k_scale", "v_scale") if quantized
+                                  else ())
+            for name in names:
+                assert isinstance(dec.state[name], tuple)
+                assert len(dec.state[name]) == n_blocks
+                for leaf, spec in zip(dec.state[name], specs[name]):
+                    assert not leaf.sharding.is_fully_replicated
+                    assert leaf.sharding.is_equivalent_to(
+                        NamedSharding(mesh, spec), leaf.ndim)
 
     def test_dispatch_counts_one_admit_per_bucket_group(self, model,
                                                         mesh):

@@ -1052,6 +1052,41 @@ class TestServingObservability:
         for span_id in by_name["serve.request"]:
             assert tree[span_id] == client_trace[1]
 
+    def test_run_record_says_the_slab_layout(self, model, observability,
+                                             tmp_path):
+        """Which device layout the K/V leaves lie in is said once per
+        run: in ``/healthz`` and on the first traced ``decode.dispatch``
+        span, as the state's own arrays report it."""
+        from veles_tpu.parallel.decode import slot_layout_facts
+        from veles_tpu.serving import GenerateAPI
+
+        params, table, heads, vocab = model
+        api = GenerateAPI(params, table, heads, slots=2, max_len=32,
+                          n_tokens=6, chunk=2, port=0)
+        api.start()
+        try:
+            url = "http://127.0.0.1:%d" % api.port
+            post(url + "/generate", {"tokens": [1, 2, 3]})
+            health = json.loads(get(url + "/healthz"))
+            facts = slot_layout_facts(api.decoder.state)
+        finally:
+            api.stop()
+        assert health["kv_layout"] == facts
+        for name in ("k", "v"):
+            # (S, H·D, T): three dimensions, positions minor-most
+            assert sorted(facts[name]["major_to_minor"]) == [0, 1, 2]
+            assert facts[name]["major_to_minor"][-1] == 2
+        slab = 2 * 2 * (2 * 16 * 32) * 4       # K, V; 2 blocks; f32
+        assert facts["state_device_bytes"] >= slab
+        out = str(tmp_path / "trace.json")
+        export_chrome_trace(observability, out)
+        dispatches = [e for e in json.loads(open(out).read())[
+            "traceEvents"] if e["name"] == "decode.dispatch"]
+        assert len(dispatches) >= 2
+        said = [json.loads(e["args"]["kv_layout"]) for e in dispatches
+                if "kv_layout" in e["args"]]
+        assert said == [facts]                  # the first one only
+
     def test_metrics_expose_device_truth(self, observability):
         """The ISSUE acceptance: /metrics on GenerateAPI exposes
         compile-count, device-memory and MFU gauges — fed by real

@@ -157,7 +157,7 @@ def test_cache_attend_scale_folding_matches_explicit_dequant():
     k_scale into the score row and v_scale into the softmax weights;
     it must equal attending against explicitly dequantized fp K/V
     through the plain _cache_attend (pure reassociation + layout)."""
-    from veles_tpu.parallel.decode import _cache_attend
+    from veles_tpu.parallel.decode import _cache_attend, _positions_last
     from veles_tpu.ops.quant import int8_cache_attend
 
     (q, khm, kshm, vhm, vshm, _, _, kq, ks, vq, vs) = _attend_fixture()
@@ -168,7 +168,10 @@ def test_cache_attend_scale_folding_matches_explicit_dequant():
     deq_k = kq.astype(jnp.float32) * ks[..., None]
     deq_v = vq.astype(jnp.float32) * vs[..., None]
     mask = jnp.ones((1, 1, 1, length), bool)
-    want = _cache_attend(q, deq_k, deq_v, mask)
+    # positions-major rows handed over in the slab's order, as every
+    # caller with such a cache does
+    want = _cache_attend(q, _positions_last(deq_k),
+                         _positions_last(deq_v), mask)
     numpy.testing.assert_allclose(numpy.asarray(got),
                                   numpy.asarray(want), rtol=1e-5,
                                   atol=1e-6)

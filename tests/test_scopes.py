@@ -38,9 +38,11 @@ SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
 LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 TRAIN_SCOPES = ("data", "fwd", "update", "l0_dense", "l1_dense")
+# "cache.read" is written (tests/test_decode.py holds it to one window
+# per leaf) and owns no instruction: the window is one slice inside
+# the attend's fusion, which is what that fusion produces
 SERVE_SCOPES = ("decode.dispatch", "sample", "embed", "attn.qkv",
-                "cache.append", "cache.read", "attn.attend", "attn.out",
-                "mlp", "head")
+                "cache.append", "attn.attend", "attn.out", "mlp", "head")
 ADMIT_SCOPES = ("decode.admit", "attn.qkv", "attn.attend", "attn.out",
                 "mlp", "head", "sample", "cache.append")
 
@@ -165,6 +167,37 @@ def test_decode_tables_name_every_scope(traced, function, scopes):
         assert function in table["function"]
         assert set(scopes) <= scopes_held(table), \
             set(scopes) - scopes_held(table)
+        if function == "slot_step_many":
+            # no instruction of its own copies a layer's window out
+            assert "cache.read" not in scopes_held(table)
+
+
+def test_decode_table_is_of_the_program_that_ran(traced):
+    """The slot state's leaves are committed to their place and the
+    table is compiled from shapes alone: both must lower the same
+    module, or a traced op finds no row (on the chip that read every
+    decode part as unscoped). The state's place is pinned on the
+    programs, so what the arrays are committed to changes nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from veles_tpu.parallel import decode
+
+    decoder = toy_decoder()
+    decoder.submit([1, 2, 3])
+    traced.enabled = True
+    handle = decoder.dispatch_chunk(2)
+    traced.enabled = False
+    decoder.collect_chunk(handle)
+    (table,) = xla_stats.scope_table("slot_step_many")
+    span = decoder._attended_span(2)
+    ran = decode.slot_fns(decoder.state)[2].__wrapped__.lower(
+        decoder.params, decoder.embed_table, decoder.heads,
+        decoder.state, jnp.asarray(decoder._active()), 2,
+        jnp.float32(1.0), False, 0, span).compile().as_text()
+    assert all(leaf.committed for leaf in jax.tree.leaves(decoder.state))
+    assert set(xla_stats.parse_hlo_scopes(ran)) \
+        == set(table["instructions"])
 
 
 def test_one_table_entry_per_distinct_program(traced):

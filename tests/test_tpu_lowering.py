@@ -145,3 +145,100 @@ def test_kernels_compile_for_v5e_without_a_chip():
     compiled = [line for line in lines if line.startswith("COMPILED")]
     assert not refused, "\n".join(refused)
     assert len(compiled) == len(kernel_cases())
+
+
+# -- the decode chunk program uses the K/V slab in place ----------------------
+
+_CHUNK_CHILD = """
+import functools, json, re, sys
+sys.path.insert(0, %(repo)r)
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as exc:
+    print("NO-TOPOLOGY %%s" %% exc)
+    sys.exit(0)
+jax.config.update("jax_enable_compilation_cache", False)
+from veles_tpu.parallel import decode
+chip = SingleDeviceSharding(topo.devices[0])
+E, HEADS, LAYERS, HIDDEN, VOCAB, MAX_LEN, SLOTS = %(sizes)r
+bf = jnp.bfloat16
+def spec(shape, dtype=bf):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+block = {"ln1_w": spec((E,)), "ln1_b": spec((E,)),
+         "wqkv": spec((E, 3 * E)), "bqkv": spec((3 * E,)),
+         "wout": spec((E, E)), "bout": spec((E,)),
+         "ln2_w": spec((E,)), "ln2_b": spec((E,)),
+         "w1": spec((E, HIDDEN)), "b1": spec((HIDDEN,)),
+         "w2": spec((HIDDEN, E)), "b2": spec((E,))}
+params = {"blocks": [block] * LAYERS, "lnf_w": spec((E,)),
+          "lnf_b": spec((E,)), "head": spec((E, VOCAB))}
+table = spec((VOCAB, E))
+state = jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+    jax.eval_shape(functools.partial(
+        decode.init_slot_state, LAYERS, SLOTS, MAX_LEN, HEADS,
+        E // HEADS, VOCAB, dtype=bf)))
+formats = decode.decide_slot_formats(params, table, HEADS, state, 8, 512)
+place = dict(formats)
+place.update(dict.fromkeys(("lengths", "logits", "req_key", "step")))
+chunk = decode._build_slot_fns(place)[2].__wrapped__
+compiled = chunk.lower(
+    params, table, HEADS, state, spec((SLOTS,), jnp.bool_), 8,
+    spec((), jnp.float32), False, 0, 512).compile()
+text = compiled.as_text()
+leaf = state["k"][0]
+shape = ",".join(str(n) for n in leaf.shape)
+# an op of its own whose result is a whole K/V leaf, other than the
+# in-place appends: a copy of a layer
+whole = [line.strip()[:200] for line in text.splitlines()
+         if re.search(r"= bf16\\[%%s\\]\\S* (copy|fusion)\\(" %% shape, line)
+         and "dynamic-update-slice" not in line.split(" = ")[0]]
+print("RESULT " + json.dumps({
+    "layout": [str(formats[name].layout) for name in ("k", "v")],
+    "padded_bytes": compiled.memory_analysis().argument_size_in_bytes,
+    "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+    "slab_bytes": 2 * LAYERS * leaf.size * 2,
+    "weights_bytes": sum(a.size * 2 for a in jax.tree.leaves(params))
+                     + table.size * 2,
+    "whole_leaf_ops": whole,
+    "remat_uncompressed": text.count("remat_uncompressed = ")}))
+"""
+
+
+def test_chunk_program_uses_the_slab_in_place_on_v5e():
+    """Compiled for a described v5e at gpt2-medium's sizes and 16 slots
+    (the benchmark's serving cell), the chunk program of
+    ``slot_step_many`` with the layout the decoder would pin: its
+    temporaries stay under 5% of the slab, no op of its own produces a
+    whole K/V leaf (a copy of a layer) and the slab's arguments are
+    its bytes, unpadded. Skips where no TPU compiler is installed."""
+    import json
+
+    sizes = (1024, 16, 24, 4096, 50257, 1024, 16)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c",
+             _CHUNK_CHILD % {"repo": REPO, "sizes": sizes}],
+            env=env, capture_output=True, text=True, timeout=600)
+    except subprocess.TimeoutExpired:
+        pytest.skip("the compile-only TPU client did not answer here")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    if any(line.startswith("NO-TOPOLOGY") for line in lines):
+        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
+    (result,) = [json.loads(line[len("RESULT "):]) for line in lines
+                 if line.startswith("RESULT ")]
+    slab = result["slab_bytes"]
+    assert result["temp_bytes"] < 0.05 * slab, result
+    assert not result["whole_leaf_ops"], result["whole_leaf_ops"][:3]
+    assert not result["remat_uncompressed"]
+    # nothing pads the slab: the arguments are the weights, the slab
+    # and the small control leaves (logits: 16 x 50257 f32)
+    assert result["padded_bytes"] < slab + result["weights_bytes"] \
+        + 0.01 * slab, result

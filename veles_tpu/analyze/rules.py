@@ -297,7 +297,7 @@ class UnpinnedOutShardingsRule(Rule):
                     "jax.jit call pins in_shardings but not "
                     "out_shardings — the output layout floats and "
                     "donated state drifts into retrace storms "
-                    "(pin it like decode.sharded_slot_fns)")
+                    "(pin it like decode.slot_fns)")
 
 
 def _walk_scope(node):

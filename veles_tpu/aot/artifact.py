@@ -198,16 +198,22 @@ def _strip_debug_info(exported):
 
 
 def _aval_rows(avals):
-    """Human-readable manifest record of a program's operand avals."""
+    """Human-readable manifest record of a program's operand avals:
+    shape, dtype, partition spec and device layout. The layout is the
+    platform's default (""): ``jax.export`` carries none, so a decoder
+    bound to a bundle keeps its slab in it (serving.py)."""
     import jax
 
     rows = []
     for leaf in jax.tree.leaves(avals):
         if hasattr(leaf, "shape"):
             sharding = getattr(leaf, "sharding", None)
+            layout = getattr(getattr(leaf, "format", None), "layout",
+                             None)
             rows.append([list(leaf.shape), str(leaf.dtype),
                          str(getattr(sharding, "spec", ""))
-                         if sharding is not None else ""])
+                         if sharding is not None else "",
+                         "" if layout is None else str(layout)])
     return rows
 
 
@@ -431,10 +437,11 @@ def build_serving_bundle(params, embed_table, heads, path, *, slots=4,
             from veles_tpu.parallel.kv_pool import paged_state_specs
             specs = paged_state_specs(quantized, axis=dec.mesh_axis)
         else:
-            specs = decode.slot_state_specs(quantized,
-                                            axis=dec.mesh_axis)
-        out_state_sh = {name: NamedSharding(dec.mesh, spec)
-                        for name, spec in specs.items()}
+            specs = decode.slot_state_specs(
+                len(dec.params["blocks"]), quantized,
+                axis=dec.mesh_axis)
+        out_state_sh = jax.tree.map(
+            lambda spec: NamedSharding(dec.mesh, spec), specs)
         replicated = NamedSharding(dec.mesh, P())
         out_pair_sh = (out_state_sh, replicated)
     table = dec.embed_table

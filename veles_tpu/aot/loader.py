@@ -453,14 +453,12 @@ class _BoundAot:
         #: aot/live attribution seam (read by the decoder right after
         #: the call returns, single driver thread)
         self.last_dispatch = None
+        self._fits = {}     # (name, key) -> avals matched this decoder
 
     # live fallback resolvers (the decoder's own late-binding rules)
-    def _live_dense(self, index, module_name):
+    def _live_dense(self, module_name):
         from veles_tpu.parallel import decode
 
-        dec = self._decoder()
-        if dec is not None and dec._sharded_fns:
-            return dec._sharded_fns[index]
         return getattr(decode, module_name)
 
     def _live_paged(self, index, module_name):
@@ -476,7 +474,16 @@ class _BoundAot:
         or fall back to the live jit surface."""
         from veles_tpu.parallel.decode import unwire_slot_state
 
-        compiled = self._programs.program(name, key)
+        entry = self._programs._entries.get((name, tuple(key)))
+        # a program whose recorded avals are not this call's (a bundle
+        # built when the slab was one stacked array) is a miss, not an
+        # execute into donated buffers of another shape. A decoder's
+        # operands keep their shapes: one verdict per program
+        fits = self._fits.get((name, tuple(key)))
+        if fits is None and entry is not None:
+            fits = self._fits[name, tuple(key)] = _avals_match(
+                entry.row, wire_args)
+        compiled = entry.get() if fits else None
         if compiled is None:
             self._programs._book_miss(name)
             self.last_dispatch = (name, False)
@@ -500,7 +507,7 @@ class _BoundAot:
             "decode.admit", key,
             (params, embed_table, wire_slot_state(state), slots, x,
              jax.random.key_data(req_keys), lengths), True,
-            lambda: self._live_dense(0, "slot_admit_many")(
+            lambda: self._live_dense("slot_admit_many")(
                 params, embed_table, heads, state, slots, x, req_keys,
                 lengths))
 
@@ -513,7 +520,7 @@ class _BoundAot:
             "decode.step", key,
             (params, embed_table, wire_slot_state(state), active,
              temperature), False,
-            lambda: self._live_dense(1, "slot_step")(
+            lambda: self._live_dense("slot_step")(
                 params, embed_table, heads, state, active, temperature,
                 sample=sample, top_k=top_k, span=span))
 
@@ -526,7 +533,7 @@ class _BoundAot:
             "decode.dispatch", key,
             (params, embed_table, wire_slot_state(state), active,
              temperature), False,
-            lambda: self._live_dense(2, "slot_step_many")(
+            lambda: self._live_dense("slot_step_many")(
                 params, embed_table, heads, state, active, n,
                 temperature, sample=sample, top_k=top_k, span=span))
 
@@ -611,9 +618,9 @@ def _avals_match(row, args):
               if hasattr(leaf, "shape")]
     if len(want) != len(leaves):
         return False
-    for (shape, dtype, _), leaf in zip(want, leaves):
-        if list(leaf.shape) != list(shape) \
-                or str(leaf.dtype) != dtype:
+    for row, leaf in zip(want, leaves):
+        if list(leaf.shape) != list(row[0]) \
+                or str(leaf.dtype) != row[1]:
             return False
     return True
 

@@ -161,14 +161,18 @@ def int8_matmul(x, q, scale, use_pallas=None, interpret=False):
     return (out * scale).astype(x.dtype)
 
 
-def int8_cache_attend(q, k_q, k_scale, v_q, v_scale, mask_addend):
+def int8_cache_attend(q, k_q, k_scale, v_q, v_scale, mask_addend,
+                      tail=None):
     """Decode attention of one query token against an int8 KV cache in
     the head-major (B, H, D, T) layout, dequantization fused into the
     dots. ``q`` (B, 1, H, D) float (already 1/sqrt(D)-scaled by the
     caller); per-(position, head) ``k_scale``/``v_scale`` (B, H, T)
     f32; ``mask_addend`` f32 (0 = visible, -1e30 = masked) — shape
     (T,) for one shared mask, or (B, T) for per-row masks (the slot
-    engine's per-slot lengths). Returns (B, 1, H, D) f32.
+    engine's per-slot lengths). ``tail`` is ``(k_q, k_scale, v_q,
+    v_scale, mask_addend)`` of more positions that lie in another
+    buffer (the slot chunk's staged columns): one softmax over both,
+    no copy that joins them. Returns (B, 1, H, D) f32.
 
     One formulation, XLA's: on THIS head-major layout XLA keeps the
     int8 payloads narrow all the way into the dots (the positions-major
@@ -180,15 +184,23 @@ def int8_cache_attend(q, k_q, k_scale, v_q, v_scale, mask_addend):
     kernel is ``ops/paged_attention.paged_attend_int8``."""
     compute = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
     qh = q[:, 0].astype(compute)                        # (B,H,D)
-    s = jnp.einsum("bhd,bhdt->bht", qh, k_q.astype(compute),
-                   preferred_element_type=jnp.float32)
-    addend = (mask_addend if mask_addend.ndim == 1
-              else mask_addend[:, None, :])             # (B,1,T)
-    s = s * k_scale + addend
-    p = jax.nn.softmax(s, axis=-1)
-    pv = (p * v_scale).astype(compute)
-    out = jnp.einsum("bhdt,bht->bhd", v_q.astype(compute), pv,
-                     preferred_element_type=jnp.float32)
+    parts = [(k_q, k_scale, v_q, v_scale, mask_addend)] \
+        + ([tail] if tail is not None else [])
+    scores = []
+    for kq, ks, _, _, addend in parts:
+        s = jnp.einsum("bhd,bhdt->bht", qh, kq.astype(compute),
+                       preferred_element_type=jnp.float32)
+        scores.append(s * ks + (addend if addend.ndim == 1
+                                else addend[:, None, :]))  # (B,1,T)
+    p = jax.nn.softmax(jnp.concatenate(scores, axis=-1)
+                       if tail is not None else scores[0], axis=-1)
+    out, at = None, 0
+    for (_, _, vq, vs, _), s in zip(parts, scores):
+        pv = (p[..., at:at + s.shape[-1]] * vs).astype(compute)
+        part = jnp.einsum("bhdt,bht->bhd", vq.astype(compute), pv,
+                          preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+        at += s.shape[-1]
     return out[:, None]
 
 

@@ -150,21 +150,42 @@ def prefill(params, x, heads, cache, length=None):
     return logits, new
 
 
-def _cache_attend(q, k_all, v_all, mask):
+def _cache_attend(q, k_all, v_all, mask, tail=None):
     """Attention of query tokens against the cache prefix, f32 softmax:
     ONE copy of the math for the single-device and tensor-parallel
     decode paths (the TP guarantee of token-identity depends on it).
-    The int8-cache variant lives in ``ops/quant.int8_cache_attend``
-    (head-major layout, dequantization fused into the dots)."""
+    K/V come head-major with positions minor, ``(B, H, D, T)``: the
+    order the slot slab holds them in (:func:`init_slot_state`), so the
+    slot step hands over its window as it lies. A caller whose cache
+    is positions-major hands a transposed view (:func:`_positions_last`),
+    which XLA folds into the dots. ``tail`` is ``(k, v, mask)`` of more
+    positions that lie in another buffer (the slot chunk's staged
+    columns): one softmax over both, no copy that joins them. The
+    int8-cache variant lives in ``ops/quant.int8_cache_attend`` (same
+    order, dequantization fused into the dots)."""
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-    # q (B,1,H,D) x cache K (B,L,H,D) -> (B,H,1,L)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_all.astype(q.dtype),
-                   preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype),
-                      v_all.astype(q.dtype),
-                      preferred_element_type=jnp.float32)
+    parts = [(k_all, v_all, mask)] + ([tail] if tail is not None else [])
+    # q (B,1,H,D) x cache K (B,H,D,T) -> (B,H,1,T)
+    scores = [jnp.where(m, jnp.einsum(
+        "bqhd,bhdk->bhqk", q, k.astype(q.dtype),
+        preferred_element_type=jnp.float32) * scale, -1e30)
+        for k, _, m in parts]
+    p = jax.nn.softmax(jnp.concatenate(scores, axis=-1)
+                       if tail is not None else scores[0], axis=-1)
+    out, at = None, 0
+    for (_, v, _), s in zip(parts, scores):
+        part = jnp.einsum(
+            "bhqk,bhdk->bqhd", p[..., at:at + s.shape[-1]].astype(q.dtype),
+            v.astype(q.dtype), preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+        at += s.shape[-1]
+    return out
+
+
+def _positions_last(x):
+    """``(..., T, H, D)`` -> ``(..., H, D, T)``: rows of K/V as
+    ``_block_qkv`` makes them, in the slab's order."""
+    return jnp.moveaxis(x, -3, -1)
 
 
 def decode_step(params, x_tok, heads, cache):
@@ -214,7 +235,8 @@ def decode_step(params, x_tok, heads, cache):
                 new_k, k[None].astype(new_k.dtype), (i, 0, length, 0, 0))
             new_v = lax.dynamic_update_slice(
                 new_v, v[None].astype(new_v.dtype), (i, 0, length, 0, 0))
-            att = _cache_attend(q, new_k[i], new_v[i], mask)
+            att = _cache_attend(q, _positions_last(new_k[i]),
+                                _positions_last(new_v[i]), mask)
         att = att.astype(x.dtype)
         x = x + matmul_any(att.reshape(batch, 1, embed),
                            blk["wout"]) + blk["bout"]
@@ -379,20 +401,47 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
 SLOT_SPAN_TILE = 128
 
 
+#: the state's K/V leaves (the int8-KV tier adds the scales): a tuple
+#: of one array per block under each name, everything else is control
+KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+CONTROL_LEAVES = ("lengths", "logits", "req_key", "step")
+
+
+def _kv_names(state):
+    return [name for name in KV_LEAVES if name in state]
+
+
 def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
                     dtype=jnp.float32, quantized=False, mesh=None,
                     mesh_axis="model", paged=False, pages=None,
-                    page_size=None):
+                    page_size=None, formats=None):
     """Cache + control state for ``slots`` concurrent sequences.
 
-    ``quantized=True`` stores the slot K/V as int8 with per-(slot,
-    position, head) f32 scales in the head-major (L, S, H, D, T)
-    layout — ``init_kv_cache``'s int8-KV recipe generalized to the
-    slot pool, so continuous serving gets the same halved cache
-    traffic as raw ``generate(quantize="int8-kv")``.
+    The slab is ONE K and ONE V leaf per block, ``state["k"]`` and
+    ``state["v"]`` tuples of ``n_blocks`` arrays ``(S, H·D, T)``:
+    heads and ``head_dim`` folded, head-major, positions minor. Both
+    minor dimensions are whole tiles (``H·D`` and a ``max_len`` that
+    are multiples of 128), so no device layout pads a leaf: a
+    ``(…, H, 64)`` minor dimension costs twice its bytes on the chip,
+    in HBM and in every read of the attend. A leaf per block is what
+    lets the chunk program update each in place: one stacked ``(L, …)``
+    array is copied whole round the scan
+    (docs/serving_performance.md).
+
+    ``quantized=True`` stores the leaves as int8 ``(S, H, D, T)`` with
+    per-(slot, head, position) f32 scales ``(S, H, T)`` in
+    ``k_scale``/``v_scale`` — ``init_kv_cache``'s int8-KV recipe
+    generalized to the slot pool (``int8_cache_attend``'s order), so
+    continuous serving gets the same halved cache traffic as raw
+    ``generate(quantize="int8-kv")``.
+
+    ``formats`` (:func:`decide_slot_formats`) builds the K/V leaves in
+    the device layout the decode programs were found to work in; the
+    slot programs then pin what the leaves carry (:func:`slot_fns`).
+    Without it the leaves have the platform's default layout.
 
     ``mesh`` creates the state already in the serving layout: the KV
-    slab (and the int8 tier's scales) sharded over their heads dim on
+    leaves (and the int8 tier's scales) sharded over their heads dim on
     ``mesh_axis``, control leaves replicated — per-device slot-cache
     HBM then scales with H/n (:func:`slot_state_specs`).
 
@@ -416,7 +465,7 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
             n_blocks, pages, page_size, heads, head_dim, vocab, slots,
             dtype=dtype, quantized=quantized, mesh=mesh,
             mesh_axis=mesh_axis)
-    base = {
+    state = {
         "lengths": jnp.zeros((slots,), jnp.int32),
         "logits": jnp.zeros((slots, vocab), jnp.float32),
         # per-slot sampling stream: the request's key + how many tokens
@@ -425,21 +474,40 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
         "req_key": jax.random.split(jax.random.key(0), slots),
         "step": jnp.zeros((slots,), jnp.int32),
     }
+    leaves = dict.fromkeys(
+        ("k", "v"), ((slots, heads * head_dim, max_len), dtype))
     if quantized:
-        qshape = (n_blocks, slots, heads, head_dim, max_len)
-        sshape = (n_blocks, slots, heads, max_len)
-        state = dict(base,
-                     k=jnp.zeros(qshape, jnp.int8),
-                     v=jnp.zeros(qshape, jnp.int8),
-                     k_scale=jnp.zeros(sshape, jnp.float32),
-                     v_scale=jnp.zeros(sshape, jnp.float32))
-    else:
-        shape = (n_blocks, slots, max_len, heads, head_dim)
-        state = dict(base, k=jnp.zeros(shape, dtype),
-                     v=jnp.zeros(shape, dtype))
+        leaves = dict.fromkeys(
+            ("k", "v"), ((slots, heads, head_dim, max_len), jnp.int8))
+        leaves.update(dict.fromkeys(
+            ("k_scale", "v_scale"),
+            ((slots, heads, max_len), jnp.float32)))
+    # where each leaf lives: every leaf is committed to its place, so
+    # the first dispatch and every later one (whose state is a
+    # program's output) are the same call to the same program
     if mesh is not None:
-        state = shard_slot_tree(
-            state, mesh, slot_state_specs(quantized, axis=mesh_axis))
+        from jax.sharding import NamedSharding
+
+        place = {name: NamedSharding(mesh, spec[0] if name in leaves
+                                     else spec)
+                 for name, spec in slot_state_specs(
+                     n_blocks, quantized, axis=mesh_axis).items()}
+    else:
+        from jax.sharding import SingleDeviceSharding
+
+        here = SingleDeviceSharding(jax.devices()[0])
+        place = dict.fromkeys(list(state) + list(leaves), here)
+    if formats is not None:
+        place.update(formats)
+    state = {name: jax.device_put(leaf, place[name])
+             for name, leaf in state.items()}
+    # the slab is made where and how it will lie (a program's outputs
+    # in their pinned place): no second copy of it exists meanwhile
+    state.update(jax.jit(
+        lambda: {name: tuple(jnp.zeros(shape, leaf_dtype)
+                             for _ in range(n_blocks))
+                 for name, (shape, leaf_dtype) in leaves.items()},
+        out_shardings={name: place[name] for name in leaves})())
     return state
 
 
@@ -460,11 +528,31 @@ def param_tree_bytes(params, embed_table=None):
     return pytree_nbytes(params) + pytree_nbytes(embed_table)
 
 
+def _kv_columns(state, k, v):
+    """New K/V rows ``(..., T, H, D)`` as the state's leaves hold them:
+    ``{leaf name: (..., H·D, T)}`` in the leaves' dtype, and for the
+    int8-KV tier the quantized rows ``(..., H, D, T)`` with their
+    scales ``(..., H, T)``. One copy for the admission scatter and the
+    per-step appends."""
+    if "k_scale" not in state:
+        dtype = state["k"][0].dtype
+        folded = k.shape[:-3] + (-1, k.shape[-3])   # (..., H·D, T)
+        return {"k": _positions_last(k).astype(dtype).reshape(folded),
+                "v": _positions_last(v).astype(dtype).reshape(folded)}
+    out = {}
+    for name, val in (("k", k), ("v", v)):
+        q8, scale = _quantize_kv(val)           # (..,T,H,D), (..,T,H)
+        out[name] = _positions_last(q8)
+        out[name + "_scale"] = jnp.swapaxes(scale, -2, -1)
+    return out
+
+
 def _slot_admit_many(params, embed_table, heads, state, slots,
                      prompt_x, req_keys, lengths):
     """Admit a whole same-bucket group in ONE dispatch: prefill
     ``prompt_x`` (B, T, E) — each row right-padded to the bucket T —
-    and scatter the K/V slabs into slots ``slots`` (B,) int32.
+    and scatter the K/V rows into slots ``slots`` (B,) int32 of every
+    block's leaf.
 
     The prefill cost scales with the BUCKET (T), not ``max_len``: only
     positions [0, T) of each slot lane are written. Stale positions
@@ -497,21 +585,18 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
                     jnp.zeros_like(lengths)),
             )
         with jax.named_scope("cache.append"):
-            if "k_scale" in state:
-                for name, val in (("k", k_all), ("v", v_all)):
-                    q8, scale = _quantize_kv(val)  # (L,B,T,H,D),(L,B,T,H)
-                    # head-major, positions-minor slot layout
-                    # (init_slot_state)
-                    new[name] = state[name].at[:, slots, :, :, :t].set(
-                        jnp.transpose(q8, (0, 1, 3, 4, 2)))
-                    new[name + "_scale"] = \
-                        state[name + "_scale"].at[:, slots, :, :t].set(
-                            jnp.transpose(scale, (0, 1, 3, 2)))
-            else:
-                new["k"] = state["k"].at[:, slots, :t].set(
-                    k_all.astype(state["k"].dtype))
-                new["v"] = state["v"].at[:, slots, :t].set(
-                    v_all.astype(state["v"].dtype))
+            # positions [0, t) of each admitted slot's lane, block by
+            # block: a block's rows (B, ..., T) are turned and written
+            # on their own, so no second copy of all blocks' K/V
+            # stands beside the stack the prefill returns
+            fresh = {name: [] for name in _kv_names(state)}
+            for i in range(len(state["k"])):
+                rows = _kv_columns(state, k_all[i], v_all[i])
+                for name, leaf in fresh.items():
+                    leaf.append(
+                        state[name][i].at[slots, ..., :t].set(rows[name]))
+            new.update({name: tuple(leaves)
+                        for name, leaves in fresh.items()})
     return new
 
 
@@ -532,6 +617,150 @@ def slot_admit(params, embed_table, heads, state, slot, prompt_x,
         jnp.reshape(jnp.asarray(slot, jnp.int32), (1,)), prompt_x,
         jnp.stack([req_key]),
         jnp.reshape(jnp.asarray(length, jnp.int32), (1,)))
+
+
+def _slot_steps(params, embed_table, heads, state, active, n,
+                temperature, sample, top_k, span):
+    """``n`` lockstep decode steps across ALL slots, the K/V leaves
+    used in place: ``(state, emitted (n, S))``.
+
+    Positions are the leaves' minor dimension, so one slot's new
+    column is a strided write that costs an op of its own (3.5 us
+    each on a v5e, 2.7 ms a step for 16 slots x 48 leaves: PERF.md),
+    and each slot appends at its own length. The chunk therefore
+    STAGES its columns: step ``j`` writes every slot's column at once
+    into column ``j`` of a small ``(..., n)`` buffer per leaf (one
+    uniform write a leaf), attends over the leaf's window, which holds
+    what was cached before the chunk, and the staged columns up to its
+    own, and only when the steps are done does each slot's block of
+    ``n`` columns go to the leaf at the length the slot had when the
+    chunk began: one write per slot and leaf a chunk, from a loop over
+    the slots, so the program holds one such write a leaf."""
+    slots = state["lengths"].shape[0]
+    quantized = "k_scale" in state
+    names = _kv_names(state)
+    max_len = state["k"][0].shape[-1]       # positions are minor
+    if span is None or span > max_len:
+        span = max_len
+    before = state["lengths"]
+    # per-slot mask over the window: position p of slot s is cached iff
+    # p < the slot's length when the chunk began; a staged column is
+    # visible from its own step on (the new token attends to itself)
+    with jax.named_scope("attn.attend"):
+        cached = jnp.arange(span)[None, :] < before[:, None]
+        # python float (weak type): `q * inv_sqrt` must NOT promote
+        # a bf16 q to f32 (see decode_step)
+        inv_sqrt = (embed_table.shape[-1] // heads) ** -0.5
+
+    def masks(visible):
+        if quantized:
+            return jnp.where(visible, 0.0, -1e30).astype(jnp.float32)
+        return visible[:, None, None, :]
+
+    def step(carry, j):
+        control, staged = carry
+        lengths = control["lengths"]
+        # the named scopes of a step (HLO metadata; the scope table,
+        # observe/xla_stats.scope_table, carries them to a traced op):
+        # sample, embed, then per block attn.qkv (_block_qkv),
+        # cache.append, cache.read, attn.attend, attn.out, mlp (_mlp),
+        # then head (_head)
+        with jax.named_scope("sample"):
+            if sample:
+                step_keys = jax.vmap(jax.random.fold_in)(
+                    control["req_key"], control["step"])
+                # inner shape (1, V): the SAME categorical shape
+                # generate's batch-1 path draws, so the random bits
+                # match exactly
+                tok_in = jax.vmap(
+                    lambda l, k: _pick_token(l[None], k, temperature,
+                                             True, top_k)[0])(
+                    control["logits"], step_keys)
+            else:
+                tok_in = jnp.argmax(control["logits"], axis=-1)
+        with jax.named_scope("embed"):
+            x = embed_table[tok_in][:, None, :]
+        embed = x.shape[-1]
+        with jax.named_scope("attn.attend"):
+            mask = masks(cached)
+            mask_staged = masks(jnp.broadcast_to(
+                jnp.arange(n)[None, :] <= j, (slots, n)))
+        staged = {name: list(staged[name]) for name in names}
+        for i, blk in enumerate(params["blocks"]):
+            q, k, v = _block_qkv(blk, x, heads)
+            # every slot's new column at once, into column j of this
+            # block's staging buffers: (S, H·D, 1); the int8 tier's
+            # (S, H, D, 1) and (S, H, 1)
+            with jax.named_scope("cache.append"):
+                for name, cols in _kv_columns(state, k, v).items():
+                    at = (0,) * (cols.ndim - 1) + (j,)
+                    staged[name][i] = lax.dynamic_update_slice(
+                        staged[name][i], cols, at)
+            # ONE read per leaf: the attended window, consumed by the
+            # attend from the leaf where it lies; never the leaf at
+            # max_len
+            with jax.named_scope("cache.read"):
+                read = {name: state[name][i][..., :span]
+                        for name in names}
+            with jax.named_scope("attn.attend"):
+                if quantized:
+                    att = int8_cache_attend(
+                        q * inv_sqrt, read["k"], read["k_scale"],
+                        read["v"], read["v_scale"], mask,
+                        tail=(staged["k"][i], staged["k_scale"][i],
+                              staged["v"][i], staged["v_scale"][i],
+                              mask_staged))
+                else:
+                    # heads unfolded: no byte moves, D is whole tiles
+                    apart = (slots, heads, -1)
+                    att = _cache_attend(
+                        q, read["k"].reshape(apart + (span,)),
+                        read["v"].reshape(apart + (span,)), mask,
+                        tail=(staged["k"][i].reshape(apart + (n,)),
+                              staged["v"][i].reshape(apart + (n,)),
+                              mask_staged))
+            with jax.named_scope("attn.out"):
+                att = att.astype(x.dtype)
+                x = x + matmul_any(att.reshape(slots, 1, embed),
+                                   blk["wout"]) + blk["bout"]
+            x = _mlp(blk, x)
+        logits = _head(params, x[:, 0]).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            control = dict(
+                control,
+                lengths=jnp.where(active, lengths + 1, lengths),
+                logits=jnp.where(active[:, None], logits,
+                                 control["logits"]),
+                step=jnp.where(active, control["step"] + 1,
+                               control["step"]))
+        return (control, {name: tuple(staged[name])
+                          for name in names}), tok_in
+
+    control = {name: state[name] for name in CONTROL_LEAVES}
+    staged = {name: tuple(jnp.zeros(leaf.shape[:-1] + (n,), leaf.dtype)
+                          for leaf in state[name]) for name in names}
+    (control, staged), emitted = lax.scan(step, (control, staged),
+                                          jnp.arange(n))
+    # each slot's block of n columns, to where the slot's sequence
+    # stood. An inactive lane's block is what its frozen logits made:
+    # it lands past the lane's length, where nothing reads before the
+    # lane's own appends or a new occupant's prefill have rewritten it
+    # (a block that would pass max_len is clamped back onto the lane's
+    # end, as the single append was: only a sequence that has overrun
+    # its budget, whose tokens the host discards, stands there).
+    new_state = dict(control)
+    with jax.named_scope("cache.append"):
+        for name in names:
+            def put(s, leaf, block):
+                at = (s,) + (0,) * (leaf.ndim - 2) + (before[s],)
+                return lax.dynamic_update_slice(
+                    leaf, lax.dynamic_slice_in_dim(block, s, 1, 0), at)
+
+            new_state[name] = tuple(
+                lax.fori_loop(0, slots,
+                              functools.partial(put, block=block), leaf)
+                for leaf, block in zip(state[name], staged[name]))
+    return new_state, emitted
 
 
 def _slot_step(params, embed_table, heads, state, active,
@@ -555,169 +784,241 @@ def _slot_step(params, embed_table, heads, state, active,
     must pass ``span > max(lengths[active])`` — masked positions
     beyond a sequence's length contribute exact zeros, so any
     sufficient span produces identical tokens. Appends still write
-    into the full-length cache. An inactive lane whose length reaches
+    into the full-length leaves (one K and one V per block, positions
+    minor: :func:`init_slot_state`). An inactive lane whose length reaches
     ``max_len`` keeps (harmlessly) rewriting the last position — its
     output is discarded and a re-admitted slot rewrites every position
-    before attending to it."""
-    slots = state["lengths"].shape[0]
-    quantized = "k_scale" in state
-    # head-major int8 layout keeps T minor; float layout keeps it at
-    # axis 2 (see init_slot_state)
-    max_len = state["k"].shape[-1] if quantized else state["k"].shape[2]
-    if span is None or span > max_len:
-        span = max_len
-    lengths = state["lengths"]
-    # the named scopes of a step (HLO metadata; the scope table,
-    # observe/xla_stats.scope_table, carries them to a traced op):
-    # sample, embed, then per block attn.qkv (_block_qkv), cache.append,
-    # cache.read, attn.attend, attn.out, mlp (_mlp), then head (_head)
-    with jax.named_scope("sample"):
-        if sample:
-            step_keys = jax.vmap(jax.random.fold_in)(state["req_key"],
-                                                     state["step"])
-            # inner shape (1, V): the SAME categorical shape generate's
-            # batch-1 path draws, so the random bits match exactly
-            tok_in = jax.vmap(
-                lambda l, k: _pick_token(l[None], k, temperature, True,
-                                         top_k)[0])(state["logits"],
-                                                    step_keys)
-        else:
-            tok_in = jnp.argmax(state["logits"], axis=-1)
-    with jax.named_scope("embed"):
-        x = embed_table[tok_in][:, None, :]
-    embed = x.shape[-1]
-    # per-slot mask over the span: position p of slot s is visible iff
-    # p <= length[s] (the new token attends to itself at index
-    # length[s])
-    with jax.named_scope("attn.attend"):
-        visible = jnp.arange(span)[None, :] <= lengths[:, None]
-        if quantized:
-            mask_addend = jnp.where(visible, 0.0,
-                                    -1e30).astype(jnp.float32)
-            # python float (weak type): `q * inv_sqrt` must NOT promote
-            # a bf16 q to f32 (see decode_step)
-            inv_sqrt = (embed // heads) ** -0.5
-        else:
-            mask = visible[:, None, None, :]
-    new_k, new_v = state["k"], state["v"]
-    new_ks = state.get("k_scale")
-    new_vs = state.get("v_scale")
-    for i, blk in enumerate(params["blocks"]):
-        q, k, v = _block_qkv(blk, x, heads)
-        # per-slot append at each slot's own length. Unrolled
-        # dynamic_update_slice per slot, NOT one scatter: XLA lowers a
-        # multi-row scatter on TPU far worse than S in-place dus ops
-        # (the single biggest cost of the pre-tiled slot step).
-        if quantized:
-            with jax.named_scope("cache.append"):
-                kq, ks = _quantize_kv(k)         # (S,1,H,D), (S,1,H)
-                vq, vs = _quantize_kv(v)
-                for s in range(slots):
-                    pos = lengths[s]
-                    new_k = lax.dynamic_update_slice(
-                        new_k,
-                        jnp.transpose(kq[s:s + 1], (0, 2, 3, 1))[None],
-                        (i, s, 0, 0, pos))
-                    new_v = lax.dynamic_update_slice(
-                        new_v,
-                        jnp.transpose(vq[s:s + 1], (0, 2, 3, 1))[None],
-                        (i, s, 0, 0, pos))
-                    new_ks = lax.dynamic_update_slice(
-                        new_ks,
-                        jnp.transpose(ks[s:s + 1], (0, 2, 1))[None],
-                        (i, s, 0, pos))
-                    new_vs = lax.dynamic_update_slice(
-                        new_vs,
-                        jnp.transpose(vs[s:s + 1], (0, 2, 1))[None],
-                        (i, s, 0, pos))
-            with jax.named_scope("cache.read"):
-                read = (new_k[i, :, :, :, :span], new_ks[i, :, :, :span],
-                        new_v[i, :, :, :, :span], new_vs[i, :, :, :span])
-            with jax.named_scope("attn.attend"):
-                att = int8_cache_attend(q * inv_sqrt, *read, mask_addend)
-        else:
-            with jax.named_scope("cache.append"):
-                for s in range(slots):
-                    pos = lengths[s]
-                    new_k = lax.dynamic_update_slice(
-                        new_k, k[s:s + 1][None].astype(new_k.dtype),
-                        (i, s, pos, 0, 0))
-                    new_v = lax.dynamic_update_slice(
-                        new_v, v[s:s + 1][None].astype(new_v.dtype),
-                        (i, s, pos, 0, 0))
-            with jax.named_scope("cache.read"):
-                k_span, v_span = new_k[i][:, :span], new_v[i][:, :span]
-            with jax.named_scope("attn.attend"):
-                att = _cache_attend(q, k_span, v_span, mask)
-        with jax.named_scope("attn.out"):
-            att = att.astype(x.dtype)
-            x = x + matmul_any(att.reshape(slots, 1, embed),
-                               blk["wout"]) + blk["bout"]
-        x = _mlp(blk, x)
-    logits = _head(params, x[:, 0]).astype(jnp.float32)
-    with jax.named_scope("sample"):
-        new_state = dict(
-            state, k=new_k, v=new_v,
-            lengths=jnp.where(active, lengths + 1, lengths),
-            logits=jnp.where(active[:, None], logits, state["logits"]),
-            step=jnp.where(active, state["step"] + 1, state["step"]),
-        )
-    if quantized:
-        new_state["k_scale"] = new_ks
-        new_state["v_scale"] = new_vs
-    return new_state, tok_in
+    before attending to it. The one-step case of :func:`_slot_steps`."""
+    state, emitted = _slot_steps(params, embed_table, heads, state,
+                                 active, 1, temperature, sample, top_k,
+                                 span)
+    return state, emitted[0]
 
 
 def _slot_step_many(params, embed_table, heads, state, active, n,
                     temperature=1.0, sample=False, top_k=0, span=None):
-    """``n`` lockstep ``slot_step``s as ONE ``lax.scan`` dispatch —
-    the throughput mode: admission happens between chunks, so a
-    high-RTT host pays one round trip per ``n`` tokens instead of per
-    token. ``span`` (static) must cover the longest live sequence plus
-    the whole chunk (each step appends one position). Returns
-    ``(state, emitted (n, S))``; the host discards a slot's tail
-    tokens past its budget/eos."""
-    def body(state, _):
-        state, emitted = _slot_step(params, embed_table, heads, state,
-                                    active, temperature, sample, top_k,
-                                    span=span)
-        return state, emitted
-
+    """``n`` lockstep ``slot_step``s as ONE dispatch (``lax.scan``
+    inside :func:`_slot_steps`) — the throughput mode: admission
+    happens between chunks, so a high-RTT host pays one round trip per
+    ``n`` tokens instead of per token. ``span`` (static) must cover
+    the longest live sequence plus the whole chunk (each step appends
+    one position). Returns ``(state, emitted (n, S))``; the host
+    discards a slot's tail tokens past its budget/eos."""
     # named after the host-side "decode.dispatch" span (the profiler
-    # alignment contract — observe/profile.py): the whole chunk scan
-    # shows up as one labeled region in the XLA device trace
+    # alignment contract — observe/profile.py): the whole chunk shows
+    # up as one labeled region in the XLA device trace
     with jax.named_scope("decode.dispatch"):
-        return lax.scan(body, state, None, length=n)
+        return _slot_steps(params, embed_table, heads, state, active, n,
+                           temperature, sample, top_k, span)
 
 
-# the single-chip jitted surface. One compiled program per (bucket,
-# group) via the jit cache; the sharded layouts get their own jit
-# objects with PINNED output shardings (sharded_slot_fns below), so a
-# donated state can never drift off the canonical layout and defeat
-# the cache.
-slot_admit_many = functools.partial(
-    jax.jit, static_argnames=("heads",),
-    donate_argnames=("state",))(_slot_admit_many)
-slot_step = functools.partial(
-    jax.jit, static_argnames=("heads", "sample", "top_k", "span"),
-    donate_argnames=("state",))(_slot_step)
-slot_step_many = functools.partial(
-    jax.jit, static_argnames=("heads", "n", "sample", "top_k", "span"),
-    donate_argnames=("state",))(_slot_step_many)
+# -- the jitted surface --------------------------------------------------------
+#
+# The slot programs exist once per PLACE of the K/V leaves: where they
+# are sharded and in which device layout they lie. Every program that
+# takes or returns the state is compiled with that place pinned on the
+# K/V leaves, in and out, and the control leaves' shardings with them:
+# the donated buffers then alias, no program converts the slab at its
+# boundary, and a donated state can never drift off the canonical
+# layout and defeat the jit cache (one compiled program per (bucket,
+# group) or span, per place). The place is not configured: it is read
+# off the state's own leaves (:func:`slot_fns`), which
+# :func:`init_slot_state` built where :func:`decide_slot_formats` or
+# the platform's default put them.
 
-# compile/cache-hit/FLOPs telemetry per slot program
-# (observe/xla_stats.py): each name is that of the program's host span
-# and outermost named_scope, so the veles_xla_* counters and the span
-# vocabulary agree. A profiler capture holds the span names on its
-# host plane and, on its device plane, each instruction's text and no
-# scope: the scopes reach a traced op only through the scope table
-# (xla_stats.scope_table), for which the wrappers note every program
-# they dispatch while the tracer is on. With telemetry and tracing
-# off a wrapper delegates after two attribute checks.
+_SLOT_FNS = {}              # place of the K/V leaves -> the three jits
+_DECIDED_FORMATS = {}       # state skeleton and place -> {name: Format}
+_SLOT_FNS_LOCK = threading.Lock()
+
+
+def _pinned_place(kv_place, control):
+    """The state's prefix tree of places: each K/V name's, and
+    ``control`` on every control leaf."""
+    return dict(dict.fromkeys(CONTROL_LEAVES, control), **kv_place)
+
+
+def slot_fns(state):
+    """``(admit_many, step, step_many)`` for ``state``: the raw
+    functions (one copy of the math — the bit-identity contract) jitted
+    with the state donated and its place pinned in and out, the emitted
+    tokens replicated. Instrumented under the program names of the
+    host spans, so the veles_xla_* counters, profiler spans and
+    flight-recorder vocabulary are the same wherever the state lies.
+
+    The place is read off the state's arrays: each K/V name's layout
+    and sharding (one per name: its leaves are built alike), and for
+    the control leaves the sharding that stands for "replicated" where
+    the K/V are (on one device, that device: a place that is pinned
+    lowers the same program whether or not the arrays handed in are
+    committed to it, which is what lets ``xla_stats.scope_table`` find
+    the program that ran from shapes alone). A state that an outer
+    trace holds has no place to read: nothing is pinned for it.
+
+    The check-then-insert is LOCKED: two tiers of the same place built
+    concurrently (a breaker rebuild racing a new API) must share one
+    jit object, not compile twice."""
+    lead = state["k"][0]
+    concrete = isinstance(lead, jax.Array) \
+        and not isinstance(lead, jax.core.Tracer)
+    key = tuple((name, state[name][0].format)
+                for name in _kv_names(state)) if concrete else None
+    with _SLOT_FNS_LOCK:
+        fns = _SLOT_FNS.get(key)
+    if fns is not None:
+        return fns
+    place = None
+    if concrete:
+        control = lead.sharding
+        if getattr(control, "mesh", None) is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            control = NamedSharding(control.mesh, P())
+        place = _pinned_place(dict(key), control)
+    fns = _build_slot_fns(place)
+    with _SLOT_FNS_LOCK:
+        # a racing builder may have won; keep ITS jit objects (their
+        # compiled programs are already cached)
+        return _SLOT_FNS.setdefault(key, fns)
+
+
+def _build_slot_fns(place):
+    """The three jit objects with ``place`` pinned (``None``: nothing
+    pinned, for a state that an outer trace holds). Statics are
+    positional: jit takes no keyword beside pinned operand places."""
+    pins = ({}, {}, {})
+    if place is not None:
+        emitted = place["lengths"]
+        pins = (
+            dict(in_shardings=(None, None, place, None, None, None,
+                               None),
+                 out_shardings=place),
+            dict(in_shardings=(None, None, place, None, None),
+                 out_shardings=(place, emitted)),
+            dict(in_shardings=(None, None, place, None, None),
+                 out_shardings=(place, emitted)))
+    # compile/cache-hit/FLOPs telemetry per slot program
+    # (observe/xla_stats.py): each name is that of the program's host
+    # span and outermost named_scope, so the veles_xla_* counters and
+    # the span vocabulary agree. A profiler capture holds the span
+    # names on its host plane and, on its device plane, each
+    # instruction's text and no scope: the scopes reach a traced op
+    # only through the scope table (xla_stats.scope_table), for which
+    # the wrappers note every program they dispatch while the tracer is
+    # on. With telemetry and tracing off a wrapper delegates after two
+    # attribute checks.
+    return (
+        instrument("decode.admit", jax.jit(
+            _slot_admit_many, static_argnums=(2,), donate_argnums=(3,),
+            **pins[0])),
+        instrument("decode.step", jax.jit(
+            _slot_step, static_argnums=(2, 6, 7, 8),
+            donate_argnums=(3,), **pins[1])),
+        instrument("decode.dispatch", jax.jit(
+            _slot_step_many, static_argnums=(2, 5, 7, 8, 9),
+            donate_argnums=(3,), **pins[2])))
+
+
+def slot_admit_many(params, embed_table, heads, state, slots, prompt_x,
+                    req_keys, lengths):
+    """:func:`_slot_admit_many` as one dispatch, the state donated."""
+    return slot_fns(state)[0](params, embed_table, heads, state, slots,
+                              prompt_x, req_keys, lengths)
+
+
+def slot_step(params, embed_table, heads, state, active,
+              temperature=1.0, sample=False, top_k=0, span=None):
+    """:func:`_slot_step` as one dispatch, the state donated."""
+    return slot_fns(state)[1](params, embed_table, heads, state, active,
+                              temperature, sample, top_k, span)
+
+
+def slot_step_many(params, embed_table, heads, state, active, n,
+                   temperature=1.0, sample=False, top_k=0, span=None):
+    """:func:`_slot_step_many` as one dispatch, the state donated."""
+    return slot_fns(state)[2](params, embed_table, heads, state, active,
+                              n, temperature, sample, top_k, span)
+
+
+# the ledger's per-dispatch attribution key (dispatch_program below)
+slot_admit_many.program_name = "decode.admit"
+slot_step.program_name = "decode.step"
+slot_step_many.program_name = "decode.dispatch"
 _generate_jit = instrument("decode.generate", _generate_jit)
-slot_admit_many = instrument("decode.admit", slot_admit_many)
-slot_step = instrument("decode.step", slot_step)
-slot_step_many = instrument("decode.dispatch", slot_step_many)
+
+
+def decide_slot_formats(params, embed_table, heads, state, n, span,
+                        mesh=None, mesh_axis="model"):
+    """The device layout of the K/V leaves, taken from the compiler:
+    ``{leaf name: Format}`` for :func:`init_slot_state`'s ``formats``.
+
+    One representative chunk program (``n`` steps over ``span``
+    positions, through the first two blocks: every block uses its
+    leaves alike, and two compile in a second where all of them take
+    a quarter of a minute of every set-up) is compiled with the layout
+    of every K/V leaf left to the compiler, in and out
+    (``Layout.AUTO``), and the layout it chose for the leaves it takes
+    is the layout the loop works in: pinned on every program from then
+    on, nothing is converted at a program's boundary. ``state`` is the state's skeleton
+    (``jax.eval_shape`` of :func:`init_slot_state`); under ``mesh`` its
+    leaves are sharded as :func:`slot_state_specs` says, else they lie
+    where the skeleton's leaves say (``sharding``) or on the first
+    device. One layout per name: where the compiler's choice differs
+    between the two, the first block's stands. Decided once per leaf
+    skeleton and place in a process (a breaker's rebuild asks again)."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    names = _kv_names(state)
+    if mesh is not None:
+        specs = slot_state_specs(len(state["k"]), "k_scale" in state,
+                                 axis=mesh_axis)
+        where = {name: NamedSharding(mesh, specs[name][0])
+                 for name in names}
+        control = NamedSharding(mesh, P())
+    else:
+        here = SingleDeviceSharding(jax.devices()[0])
+        where = {name: getattr(state[name][0], "sharding", None) or here
+                 for name in names}
+        control = where["k"]
+    memo = (tuple((name, state[name][0].shape, state[name][0].dtype,
+                   where[name]) for name in names), heads, n, span)
+    with _SLOT_FNS_LOCK:
+        formats = _DECIDED_FORMATS.get(memo)
+    if formats is not None:
+        return formats
+    place = _pinned_place(
+        {name: Format(Layout.AUTO, where[name]) for name in names},
+        control)
+    slots = state["lengths"].shape[0]
+    params = dict(params, blocks=params["blocks"][:2])
+    state = dict(state, **{name: state[name][:2] for name in names})
+    chosen = _build_slot_fns(place)[2].__wrapped__.lower(
+        params, embed_table, heads, state,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_), n,
+        jax.ShapeDtypeStruct((), jnp.float32), False, 0,
+        span).compile().input_formats[0][2]
+    formats = {name: chosen[name][0] for name in names}
+    with _SLOT_FNS_LOCK:
+        return _DECIDED_FORMATS.setdefault(memo, formats)
+
+
+def slot_layout_facts(state):
+    """What a run's record says of the layout it ran in: per K/V name
+    the leaves' ``major_to_minor`` and tiling as the arrays report
+    them, and the state's device bytes in that layout (a tiled layout
+    pads; ``nbytes`` does not know)."""
+    facts = {}
+    for name in _kv_names(state):
+        layout = state[name][0].format.layout
+        facts[name] = {
+            "major_to_minor": list(layout.major_to_minor),
+            "tiling": [list(tile) for tile in layout.tiling or ()]}
+    facts["state_device_bytes"] = sum(
+        shard.data.on_device_size_in_bytes()
+        for leaf in jax.tree.leaves(state)
+        for shard in leaf.addressable_shards)
+    return facts
 
 
 def dispatch_program(fn, default):
@@ -878,7 +1179,8 @@ def make_tp_generate(mesh, heads, n_tokens, axis="model"):
             new_v = lax.dynamic_update_slice(
                 new_v, v[None].astype(new_v.dtype), (i, 0, length, 0, 0))
             # the SAME cache-attend the single-device decode_step runs
-            att = _cache_attend(q, new_k[i], new_v[i], mask)
+            att = _cache_attend(q, _positions_last(new_k[i]),
+                                _positions_last(new_v[i]), mask)
             # row-sharded out-projection: psum completes the contraction
             out = lax.psum(
                 jnp.einsum("bqhd,hde->bqe", att.astype(x.dtype),
@@ -1064,21 +1366,23 @@ def slot_param_specs(params, axis="model"):
             "head": mat(params["head"], P(None, axis), P(axis))}
 
 
-def slot_state_specs(quantized=False, axis="model"):
-    """PartitionSpec dict for the slot state: the KV slab (and the
-    int8 tier's scales) shard over their HEADS dim, control leaves
-    (lengths/logits/req_key/step) replicate."""
+def slot_state_specs(n_blocks, quantized=False, axis="model"):
+    """PartitionSpec pytree mirroring the slot state: each block's KV
+    leaves (and the int8 tier's scales) shard over their HEADS dim,
+    control leaves (lengths/logits/req_key/step) replicate."""
     from jax.sharding import PartitionSpec as P
 
+    specs = {"lengths": P(), "logits": P(), "req_key": P(),
+             "step": P()}
+    # (S, H·D, T), split on head boundaries (validate_slot_mesh:
+    # heads divide by the axis); the int8 tier's (S, H, D, T)
+    kv = P(None, axis, None, None) if quantized else P(None, axis, None)
+    specs.update(k=(kv,) * n_blocks, v=(kv,) * n_blocks)
     if quantized:
-        kv = P(None, None, axis, None, None)   # (L, S, H, D, T)
-        scale = P(None, None, axis, None)      # (L, S, H, T)
-        extra = {"k_scale": scale, "v_scale": scale}
-    else:
-        kv = P(None, None, None, axis, None)   # (L, S, T, H, D)
-        extra = {}
-    return dict({"k": kv, "v": kv, "lengths": P(), "logits": P(),
-                 "req_key": P(), "step": P()}, **extra)
+        scale = P(None, axis, None)             # (S, H, T)
+        specs.update(k_scale=(scale,) * n_blocks,
+                     v_scale=(scale,) * n_blocks)
+    return specs
 
 
 def shard_slot_tree(tree, mesh, specs):
@@ -1103,21 +1407,6 @@ def shard_slot_params(params, embed_table, heads, mesh, axis="model"):
     validate_slot_mesh(mesh, heads, params, embed_table, axis=axis)
     params = shard_slot_tree(params, mesh, slot_param_specs(params, axis))
     return params, jax.device_put(embed_table, NamedSharding(mesh, P()))
-
-
-#: (mesh, axis, quantized) -> (admit, step, step_many) jit objects with
-#: the state's output shardings PINNED to the canonical serving layout.
-#: Without the pin, the compiler is free to hand a donated state back
-#: in whatever layout the last program preferred — the next call then
-#: misses the jit cache and every admit recompiles (a recompile storm
-#: by construction). One entry per layout keeps the compile count at
-#: one program per (bucket, group, mesh), which is what the
-#: dispatch-count and storm regression tests assert — so the
-#: check-then-insert is LOCKED: two tiers of the same layout built
-#: concurrently (a bf16 and an int8 GenerateAPI, a breaker rebuild
-#: racing a new API) must share one jit object, not compile twice.
-_SHARDED_SLOT_FNS = {}
-_SHARDED_SLOT_LOCK = threading.Lock()
 
 
 # -- AOT wire format (veles_tpu/aot/) -----------------------------------------
@@ -1147,45 +1436,3 @@ def unwire_slot_state(state):
 
     return dict(state,
                 req_key=jax.random.wrap_key_data(state["req_key"]))
-
-
-def sharded_slot_fns(mesh, mesh_axis="model", quantized=False):
-    """The sharded slot engine's jitted call surface: the SAME raw
-    functions as the single-chip ``slot_admit_many``/``slot_step``/
-    ``slot_step_many`` (one copy of the math — the bit-identity
-    contract), jitted per layout with the state outputs pinned to
-    :func:`slot_state_specs` and the emitted tokens replicated.
-    Instrumented under the same program names, so the veles_xla_*
-    counters, profiler spans and flight-recorder vocabulary are
-    layout-blind."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    key = (mesh, mesh_axis, bool(quantized))
-    with _SHARDED_SLOT_LOCK:
-        fns = _SHARDED_SLOT_FNS.get(key)
-    if fns is not None:
-        return fns
-    state_sh = {
-        name: NamedSharding(mesh, spec)
-        for name, spec in slot_state_specs(quantized,
-                                           axis=mesh_axis).items()}
-    replicated = NamedSharding(mesh, P())
-    admit = instrument("decode.admit", jax.jit(
-        _slot_admit_many, static_argnames=("heads",),
-        donate_argnames=("state",), out_shardings=state_sh))
-    step = instrument("decode.step", jax.jit(
-        _slot_step,
-        static_argnames=("heads", "sample", "top_k", "span"),
-        donate_argnames=("state",),
-        out_shardings=(state_sh, replicated)))
-    step_many = instrument("decode.dispatch", jax.jit(
-        _slot_step_many,
-        static_argnames=("heads", "n", "sample", "top_k", "span"),
-        donate_argnames=("state",),
-        out_shardings=(state_sh, replicated)))
-    fns = (admit, step, step_many)
-    with _SHARDED_SLOT_LOCK:
-        # a racing builder may have won; keep ITS jit objects (their
-        # compiled programs are already cached)
-        fns = _SHARDED_SLOT_FNS.setdefault(key, fns)
-    return fns

@@ -348,7 +348,8 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
     around it). :func:`sharded_paged_fns` pins it False — the mesh
     tier takes the gather by rule (``use_paged_kernel(mesh)``)."""
     from veles_tpu.ops import paged_attention as pgatt
-    from veles_tpu.parallel.decode import _cache_attend, _pick_token
+    from veles_tpu.parallel.decode import (_cache_attend, _pick_token,
+                                           _positions_last)
 
     slots = state["lengths"].shape[0]
     quantized = "k_scale" in state
@@ -439,7 +440,10 @@ def _paged_slot_step(params, embed_table, heads, state, page_table,
             else:
                 pool = dict(state, k=new_k, v=new_v)
                 k_g, v_g = _gather_block_float(pool, i, page_table)
-                att = _cache_attend(q, k_g, v_g, mask)
+                # the pool stays positions-major: a transposed view,
+                # which XLA folds into the attend's dots
+                att = _cache_attend(q, _positions_last(k_g),
+                                    _positions_last(v_g), mask)
         att = att.astype(x.dtype)
         x = x + matmul_any(att.reshape(slots, 1, embed),
                            blk["wout"]) + blk["bout"]
@@ -512,7 +516,7 @@ paged_restore = instrument("paged.restore", functools.partial(
 
 
 #: (mesh, axis, quantized) -> pinned jit objects, same doctrine as
-#: decode._SHARDED_SLOT_FNS: output shardings pinned to the canonical
+#: decode._SLOT_FNS: output shardings pinned to the canonical
 #: layout so a donated state never drifts and defeats the jit cache;
 #: check-then-insert locked so racing builders share one jit object.
 _SHARDED_PAGED_FNS = {}

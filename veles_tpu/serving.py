@@ -29,6 +29,7 @@ wedging the process until a human restarts it.
 """
 
 import base64
+import functools
 import json
 import math
 import threading
@@ -490,6 +491,12 @@ class ServingHealth:
                 if self._deploy_ref is not None else None
         if deploy is not None:
             snap["version"] = getattr(deploy, "version", None)
+            layout = getattr(getattr(deploy, "decoder", None),
+                             "kv_layout", None)
+            if layout is not None:
+                # the dense slab's device layout (decode.py
+                # slot_layout_facts): which layout this run is in
+                snap["kv_layout"] = layout
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -926,12 +933,44 @@ class ContinuousDecoder:
         n_blocks = len(params["blocks"])
         embed = embed_table.shape[1]
         vocab = embed_table.shape[0]
-        self.state = init_slot_state(
-            n_blocks, slots, self.max_len, heads, embed // heads, vocab,
-            dtype=embed_table.dtype,
+        build_state = functools.partial(
+            init_slot_state, n_blocks, slots, self.max_len, heads,
+            embed // heads, vocab, dtype=embed_table.dtype,
             quantized=self.quantize == "int8-kv",
             mesh=mesh, mesh_axis=mesh_axis, paged=self.paged,
             pages=self.pool_pages, page_size=self.page_size)
+        #: the dense slab's device layout, decided once: the layout
+        #: the chunk program works in, taken from the compiler on one
+        #: representative chunk and pinned on every slot program from
+        #: then on (parallel/decode.py decide_slot_formats). Where the
+        #: state's programs are not this process's to compile (an AOT
+        #: bundle: jax.export carries no layout) and off the TPU (the
+        #: CPU's compiler has one layout to choose from) the leaves
+        #: keep the default, and nothing else differs.
+        formats = None
+        platform = (jax.default_backend() if mesh is None
+                    else mesh.devices.flat[0].platform)
+        if not self.paged and aot is None and platform == "tpu":
+            from veles_tpu.parallel.decode import decide_slot_formats
+            # representative: a chunk of 8 steps over half the lane
+            formats = decide_slot_formats(
+                self.params, self.embed_table, heads,
+                jax.eval_shape(build_state), 8,
+                min(self.max_len,
+                    -(-(self.max_len // 2) // self.tile) * self.tile),
+                mesh=mesh, mesh_axis=mesh_axis)
+            build_state = functools.partial(build_state,
+                                            formats=formats)
+        self.state = build_state()
+        #: which layout this decoder runs in, as its K/V leaves report
+        #: it (major_to_minor, tiling, the state's device bytes): said
+        #: once per run, in /healthz and on the first decode.dispatch
+        #: span. The page pool's is kv_pool's own affair.
+        self.kv_layout = None
+        if not self.paged:
+            from veles_tpu.parallel.decode import slot_layout_facts
+            self.kv_layout = slot_layout_facts(self.state)
+        self._layout_said = False
         self.pool = None
         self._paged_fns = None
         self._slot_pages = {}    # slot -> [page id, ...] logical order
@@ -954,17 +993,12 @@ class ContinuousDecoder:
                            else paged_restore)
                 self.state = self.pool.restore_entries(self.state,
                                                        restore)
-        if mesh is not None and not self.paged:
-            # layout-pinned jit surface: output state shardings stay on
-            # the canonical serving layout so donated state never
-            # drifts and every (bucket, group) compiles exactly once
-            from veles_tpu.parallel.decode import sharded_slot_fns
-            self._sharded_fns = sharded_slot_fns(
-                mesh, mesh_axis, quantized=self.quantize == "int8-kv")
-        else:
-            # single-chip: resolved per call from the module (late
-            # binding — the chaos/fault-injection seam tests patch)
-            self._sharded_fns = None
+        # the dense slot programs are resolved per call from the
+        # module (late binding — the chaos/fault-injection seam tests
+        # patch), which pins them to where and how this state's K/V
+        # leaves lie, single-chip and sharded alike (decode.slot_fns):
+        # a donated state never drifts off its layout and every
+        # (bucket, group) compiles exactly once
         #: AOT compiled-program bundle (docs/aot_artifacts.md): a
         #: loaded ``veles_tpu.aot.loader.AotPrograms`` whose bound
         #: facade serves every covered (bucket, group, span) dispatch
@@ -1324,11 +1358,8 @@ class ContinuousDecoder:
 
         from veles_tpu.parallel.decode import slot_admit_many
 
-        if self._aot is not None:
-            admit = self._aot.admit
-        else:
-            admit = (self._sharded_fns[0] if self._sharded_fns
-                     else slot_admit_many)
+        admit = (self._aot.admit if self._aot is not None
+                 else slot_admit_many)
         if not (self._queue and self._free):
             return
         groups = {}
@@ -1816,7 +1847,6 @@ class ContinuousDecoder:
                 sample=bool(self.temperature), top_k=self.top_k)
         else:
             step = (self._aot.step if self._aot is not None
-                    else self._sharded_fns[1] if self._sharded_fns
                     else slot_step)
             span = self._attended_span(1)
             self.state, emitted = step(
@@ -1995,10 +2025,18 @@ class ContinuousDecoder:
         scope_lens = [self._slot_len[s] for s in snapshot] \
             if self.scope.enabled else None
         span = pb = 0
+        said = {}
+        if self.kv_layout is not None and not self._layout_said \
+                and self._tracer.enabled:
+            # the first dispatch span of a traced run says which
+            # layout the run is in
+            self._layout_said = True
+            said = {"kv_layout": json.dumps(self.kv_layout,
+                                            sort_keys=True)}
         # span writes stay outside the timed window (see decode.admit)
         with self._span("paged.dispatch" if self.paged
                         else "decode.dispatch",
-                        list(snapshot.values()), chunk=chunk):
+                        list(snapshot.values()), chunk=chunk, **said):
             t0 = time.perf_counter()
             if self.paged:
                 from veles_tpu.parallel.kv_pool import \
@@ -2018,8 +2056,6 @@ class ContinuousDecoder:
             else:
                 step_many = (self._aot.step_many
                              if self._aot is not None
-                             else self._sharded_fns[2]
-                             if self._sharded_fns
                              else slot_step_many)
                 span = self._attended_span(chunk)
                 self.state, emitted = step_many(
