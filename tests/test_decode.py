@@ -257,12 +257,12 @@ def test_chunk_reads_one_window_per_leaf(model, tier):
     leaf, of the span's size, and no value of a leaf's ``max_len``
     size exists but what each leaf's one write a chunk returns, so
     nothing copies a layer at full length."""
-    from veles_tpu.parallel.decode import KV_LEAVES, _slot_step_many
+    from veles_tpu.parallel.decode import _kv_names, _slot_step_many
 
     params, table, quantize = _tier_model(model, tier)
     slots, max_len, span = 3, 32, 16
     state, _ = _admitted(params, table, quantize, (5, 3, 9), max_len)
-    names = [name for name in KV_LEAVES if name in state]
+    names = _kv_names(state)
     assert all(isinstance(state[name], tuple)
                and len(state[name]) == BLOCKS for name in names)
     jaxpr = jax.make_jaxpr(

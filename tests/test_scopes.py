@@ -619,3 +619,36 @@ def test_a_recorder_nobody_reads_serialises_nothing(monkeypatch):
     monkeypatch.undo()
     recorder.record(name="y", etype="begin")
     assert [e["name"] for e in seen] == ["y"]
+
+
+REWRITTEN = """
+HloModule jit_g, entry_computation_layout={(bf16[8,4])->bf16[8,4]}
+
+ENTRY %main.5 (x: bf16[8,4]) -> bf16[8,4] {
+  %x = bf16[8,4]{1,0} parameter(0), metadata={op_name="x"}
+  %ragged-dot-metadata = (s32[3]{0}, s32[2]{0}) custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %get-tuple-element = s32[3]{0} get-tuple-element(%ragged-dot-metadata), index=0
+  %ragged-dot-none = bf16[8,4]{1,0} custom-call(%get-tuple-element, %x), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %sort.1 = bf16[8,4]{1,0} sort(%x), dimensions={0}, metadata={op_name="sort"}
+  %copy.7 = bf16[8,4]{1,0} copy(%x)
+  %negate.3 = bf16[8,4]{1,0} negate(%copy.7), metadata={op_name="jit(g)/mlp/neg"}
+  ROOT %multiply.2 = bf16[8,4]{1,0} multiply(%ragged-dot-none, %ragged-dot-none), metadata={op_name="jit(g)/mlp/moe.experts/mul"}
+}
+"""
+
+
+@pytest.mark.parametrize("instruction,op_name", [
+    # the compiler's rewrite left its own name where the scope stood:
+    # the instruction is its user's, through the tuple element too
+    ("ragged-dot-none", "jit(g)/mlp/moe.experts/mul"),
+    ("ragged-dot-metadata", "jit(g)/mlp/moe.experts/mul"),
+    # no user carries a scope: it keeps the rewrite's name
+    ("sort.1", "sort"),
+    # XLA's own instruction without metadata stays unscoped, whoever
+    # uses it; a parameter keeps its name
+    ("copy.7", ""),
+    ("x", "x"),
+])
+def test_a_rewritten_instruction_takes_its_users_scope(instruction,
+                                                       op_name):
+    assert xla_stats.parse_hlo_scopes(REWRITTEN)[instruction][1] == op_name

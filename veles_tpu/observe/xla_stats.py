@@ -483,8 +483,13 @@ def parse_hlo_scopes(text):
     vote goes to all of the computation's instructions that compute
     (not its parameters, constants and broadcasts: they carry the
     enclosing call's ``op_name``), and where none of those does
-    either the caller keeps its own. An instruction without metadata
-    has ``op_name`` ""."""
+    either the caller keeps its own. An instruction that a rewrite of
+    the compiler's made carries the rewrite's name and no scope
+    (``ragged-dot-none``: the grouped products of ``ops/moe.py``;
+    ``gather``, ``sort``): it takes the ``op_name`` of the first
+    instruction that uses what it makes, looked for through other such
+    instructions and through those without metadata. An instruction
+    without metadata has ``op_name`` "", as before."""
     computations, current = {}, None
     for line in text.splitlines():
         if current is None:
@@ -500,12 +505,12 @@ def parse_hlo_scopes(text):
             continue
         op_name = _HLO_OP_NAME.search(line)
         calls = _HLO_CALLS.search(line)
+        operands = _operands(line[found.end():])
         current.append((found.group("name"), found.group("shape"),
                         op_name.group("op_name") if op_name else "",
                         calls.group("callee") if calls else None,
-                        found.group("opcode"),
-                        _operands(line[found.end():])
-                        if found.group("root") else None))
+                        found.group("opcode"), operands,
+                        bool(found.group("root"))))
     called = {row[3] for rows in computations.values() for row in rows
               if row[3]}
 
@@ -513,13 +518,12 @@ def parse_hlo_scopes(text):
         rows = computations.get(callee, ())
         if not rows or depth > 8:
             return own
-        root = next((row for row in rows if row[5] is not None),
-                    rows[-1])
-        produced = [row for row in rows if row[0] in (root[5] or ())] \
+        root = next((row for row in rows if row[6]), rows[-1])
+        produced = [row for row in rows if row[0] in root[5]] \
             if root[4] == "tuple" else [root]
         for voters in (produced, rows):
             votes = {}
-            for _, _, op_name, inner, opcode, _ in voters:
+            for _, _, op_name, inner, opcode, _, _ in voters:
                 if inner:
                     op_name = folded(inner, op_name, depth + 1)
                 if op_name and opcode not in _NO_VOTE:
@@ -534,10 +538,29 @@ def parse_hlo_scopes(text):
     for name, rows in computations.items():
         if name in called:
             continue
-        for instruction, shape, op_name, callee, _, _ in rows:
-            if callee:
-                op_name = folded(callee, op_name)
-            table[instruction] = (shape, op_name)
+        own = {row[0]: folded(row[3], row[2]) if row[3] else row[2]
+               for row in rows}
+        users = {}
+        for row in rows:
+            for operand in row[5]:
+                users.setdefault(operand, []).append(row[0])
+
+        def scoped(instruction, depth=0):
+            if "/" in own[instruction] or depth > 6:
+                return own[instruction]
+            for user in users.get(instruction, ()):
+                found = scoped(user, depth + 1)
+                if "/" in found:
+                    return found
+            return own[instruction]
+
+        for row in rows:
+            # a rewrite's name is there and holds no scope; parameters
+            # carry their own names, and what carries nothing stays so
+            rewritten = own[row[0]] and "/" not in own[row[0]] \
+                and row[4] != "parameter"
+            table[row[0]] = (row[1], scoped(row[0]) if rewritten
+                             else own[row[0]])
     return table
 
 

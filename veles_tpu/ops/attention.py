@@ -26,9 +26,14 @@ from veles_tpu.ops.platform import on_tpu
 
 
 def attention(q, k, v, causal=False, scale=None):
-    """Fused single-device attention. Shapes: (B, T, H, D)."""
+    """Fused single-device attention. Shapes: (B, T, H, D); a ``v``
+    narrower than ``q`` (latent attention: 128 against 192) goes
+    through zero-padded, and the output is cut back to its width."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if v.shape[-1] < q.shape[-1]:
+        wide = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - v.shape[-1])])
+        return attention(q, k, wide, causal, scale)[..., :v.shape[-1]]
     if _use_pallas_flash(q, k):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention)

@@ -1,0 +1,450 @@
+"""The block's sublayers, by architecture: the model seam.
+
+Every language-model path of the repo (the plain full forward of
+``transformer_step._forward``, the prompt forward of
+``decode._prefill_forward`` and the decode chunk of
+``decode._slot_steps``) runs a block as the same five sublayers:
+
+    q, rows = kind.project(arch, blk, x, heads, positions)
+    att     = kind.attend_prompt(...)  |  kind.attend_cached(...)
+    x       = kind.out(blk, x, att)
+    x, load = ffn(arch, blk, x, live)
+    logits  = head(arch, params, x)
+
+``rows`` is what a position leaves in the cache, and the attention
+kind declares the cache's leaves (``kind.leaves``): the slot state is
+built, written and read over whatever leaves it declares.
+
+How an architecture travels: with the parameters. ``params["arch"]``
+is an :class:`Arch`, a static node of the pytree (no array in it; it
+is part of every jitted program's key). A tree without one is GPT-2's
+block (``GPT2``): pre-LN LayerNorm, fused biased qkv, equal Q and K/V
+heads, no position encoding, GELU MLP. ``attention="mla"`` is latent
+attention with RoPE (RMSNorm, no bias): a position leaves ONE row
+``[c | k_rope]`` of ``kv_rank + rope_dim`` values for all heads; the
+prompt attends expanded (``k_nope``, ``v`` made from ``c``), a decode
+step absorbed (the query goes into the latent space and attends the
+rows as they lie), the same numbers. The feed-forward kind is read
+off the block's own leaves: ``w1`` GELU MLP, ``w_gate`` SwiGLU,
+``router`` the routed experts of ``ops/moe.py``.
+
+The named scopes are the ones the per-layer readers know
+(``attn.qkv``, ``attn.attend``, ``attn.out``, ``mlp``, ``head``), with
+the new kinds' own nested under them (``mla.q``, ``mla.kv``,
+``mla.rope``, ``mla.absorb``, ``moe.*``).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from veles_tpu.ops import moe
+from veles_tpu.ops.attention import attention
+from veles_tpu.ops.quant import int8_cache_attend, matmul_any
+
+
+@dataclasses.dataclass(frozen=True)
+class Arch:
+    """What the block's sublayers are. Sizes the leaves' shapes do not
+    give are stated here; ``heads`` travels as every caller passes it."""
+    attention: str = "mha"
+    eps: float = 1e-5
+    # latent attention
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    rope_theta: float = 10000.0
+    # routed experts
+    top_k: int = 0
+    route_scale: float = 1.0
+    #: (first, count) of the experts held here; None: all of them
+    held: tuple = None
+    #: prompt tokens a block takes at once in an admission (rows of a
+    #: group beyond that go through in turn); 0: the whole group
+    prefill_tokens: int = 0
+
+
+jax.tree_util.register_static(Arch)
+GPT2 = Arch()
+
+
+def arch_of(params):
+    return params.get("arch", GPT2)
+
+
+def expert_blocks(params):
+    """Indices of the blocks whose feed-forward is routed experts."""
+    return [i for i, blk in enumerate(params["blocks"]) if "router" in blk]
+
+
+def require_gpt2(params, what):
+    """Refuse by name what only GPT-2's block has yet."""
+    arch = arch_of(params)
+    if arch != GPT2 or expert_blocks(params):
+        raise ValueError(
+            "%s is built for GPT-2's block (fused qkv, k/v leaves, GELU "
+            "MLP) and this model declares attention=%r%s: that tier "
+            "has no such kind yet" % (
+                what, arch.attention,
+                " with routed experts" if expert_blocks(params) else ""))
+
+
+# -- norms ---------------------------------------------------------------------
+
+def _ln(x, w, b, eps=1e-5):
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def rms_norm(x, w, eps):
+    """``x / rms(x) * w``, the mean square in float32."""
+    wide = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(wide * wide, -1, keepdims=True) + eps)
+    return (wide * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
+# -- position encoding ---------------------------------------------------------
+
+def rope(x, positions, theta):
+    """Rotate the pairs ``(2i, 2i+1)`` of ``x`` (..., T, [H,] R) by
+    ``positions (..., T) * theta ** (-2i / R)`` (interleaved pairs)."""
+    r = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = positions.astype(jnp.float32)[..., None] * freq
+    if x.ndim == angle.ndim + 1:            # a heads axis before R
+        angle = angle[..., None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    wide = x.astype(jnp.float32)
+    even, odd = wide[..., 0::2], wide[..., 1::2]
+    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+# -- attention against the cache -----------------------------------------------
+
+def _positions_last(x):
+    """``(..., T, H, D)`` -> ``(..., H, D, T)``: rows of K/V as
+    ``_block_qkv`` makes them, in the slab's order."""
+    return jnp.moveaxis(x, -3, -1)
+
+
+def _quantize_kv(x):
+    """Per-(batch, position, head) symmetric int8: (..., D) ->
+    (int8 (..., D), f32 scale (...,)). The quantization the cache
+    stores; one copy for prefill and decode appends."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
+                   keepdims=True)
+    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
+    return q.astype(jnp.int8), scale[..., 0]
+
+
+def _cache_attend(q, k_all, v_all, mask, tail=None, scale=None):
+    """Attention of query tokens against the cache prefix, f32 softmax:
+    ONE copy of the math for the single-device and tensor-parallel
+    decode paths (the TP guarantee of token-identity depends on it).
+    K/V come head-major with positions minor, ``(B, H, D, T)``: the
+    order the slot slab holds them in (:func:`init_slot_state`), so the
+    slot step hands over its window as it lies. A caller whose cache
+    is positions-major hands a transposed view (:func:`_positions_last`),
+    which XLA folds into the dots. ``tail`` is ``(k, v, mask)`` of more
+    positions that lie in another buffer (the slot chunk's staged
+    columns): one softmax over both, no copy that joins them. The
+    int8-cache variant lives in ``ops/quant.int8_cache_attend`` (same
+    order, dequantization fused into the dots). ``scale`` defaults to
+    ``1 / sqrt(D)``."""
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
+    parts = [(k_all, v_all, mask)] + ([tail] if tail is not None else [])
+    # q (B,1,H,D) x cache K (B,H,D,T) -> (B,H,1,T)
+    scores = [jnp.where(m, jnp.einsum(
+        "bqhd,bhdk->bhqk", q, k.astype(q.dtype),
+        preferred_element_type=jnp.float32) * scale, -1e30)
+        for k, _, m in parts]
+    p = jax.nn.softmax(jnp.concatenate(scores, axis=-1)
+                       if tail is not None else scores[0], axis=-1)
+    out, at = None, 0
+    for (_, v, _), s in zip(parts, scores):
+        part = jnp.einsum(
+            "bhqk,bhdk->bqhd", p[..., at:at + s.shape[-1]].astype(q.dtype),
+            v.astype(q.dtype), preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+        at += s.shape[-1]
+    return out
+
+
+# -- GPT-2's sublayers (the first instance) ------------------------------------
+#
+# The helpers keep their names: the paged pool, the tensor-parallel
+# decode and ``generate`` run GPT-2's block through them directly.
+
+def _block_qkv(blk, x, heads):
+    """Pre-LN qkv projection: (B, T, E) -> three (B, T, H, D)."""
+    batch, t, embed = x.shape
+    with jax.named_scope("attn.qkv"):
+        h = _ln(x, blk["ln1_w"], blk["ln1_b"])
+        qkv = matmul_any(h, blk["wqkv"]) + blk["bqkv"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        shape = (batch, t, heads, embed // heads)
+        return q.reshape(shape), k.reshape(shape), v.reshape(shape)
+
+
+def _mlp(blk, x, reduce=None):
+    """Pre-LN residual gelu MLP. ``reduce`` completes a sharded
+    contraction (tensor-parallel decode passes a psum; ``b2`` is added
+    AFTER it, so it stays replicated) — one copy of the math for the
+    single-device and TP paths alike. The products route through
+    ``matmul_any`` so the int8 serving tier (``ops/quant.py``) shares
+    this exact sublayer math."""
+    with jax.named_scope("mlp"):
+        h = _ln(x, blk["ln2_w"], blk["ln2_b"])
+        y = matmul_any(jax.nn.gelu(matmul_any(h, blk["w1"]) + blk["b1"]),
+                       blk["w2"])
+        if reduce is not None:
+            y = reduce(y)
+        return x + y + blk["b2"]
+
+
+def _head(params, x):
+    """Final layer norm + vocab projection."""
+    with jax.named_scope("head"):
+        return matmul_any(_ln(x, params["lnf_w"], params["lnf_b"]),
+                          params["head"])
+
+
+class FusedQKV:
+    """``attention="mha"``: K and V rows of ``heads * head_dim`` a
+    position (the int8-KV tier: int8 rows and a scale a head)."""
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        """``{leaf name: (a position's row shape, dtype)}``."""
+        if not quantized:
+            return dict.fromkeys(("k", "v"), ((heads * head_dim,), dtype))
+        leaves = dict.fromkeys(("k", "v"), ((heads, head_dim), jnp.int8))
+        leaves.update(dict.fromkeys(("k_scale", "v_scale"),
+                                    ((heads,), jnp.float32)))
+        return leaves
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
+        q, k, v = _block_qkv(blk, x, heads)
+        return q, {"k": k, "v": v}
+
+    @staticmethod
+    def columns(state, rows):
+        """New rows ``(..., T, H, D)`` as the state's leaves hold them:
+        ``{leaf name: (..., H·D, T)}`` in the leaves' dtype, and for
+        the int8-KV tier the quantized rows ``(..., H, D, T)`` with
+        their scales ``(..., H, T)``. One copy for the admission
+        scatter and the per-step appends."""
+        k, v = rows["k"], rows["v"]
+        if "k_scale" not in state:
+            dtype = state["k"][0].dtype
+            folded = k.shape[:-3] + (-1, k.shape[-3])   # (..., H·D, T)
+            return {"k": _positions_last(k).astype(dtype).reshape(folded),
+                    "v": _positions_last(v).astype(dtype).reshape(folded)}
+        out = {}
+        for name, val in (("k", k), ("v", v)):
+            q8, scale = _quantize_kv(val)           # (..,T,H,D), (..,T,H)
+            out[name] = _positions_last(q8)
+            out[name + "_scale"] = jnp.swapaxes(scale, -2, -1)
+        return out
+
+    @staticmethod
+    def attend_prompt(arch, blk, q, rows):
+        """Full causal attention over the prompt — the SAME gated op
+        the training forward uses (flash kernel for prompts >= 4096).
+        With a quantized cache the prompt attention still runs on the
+        exact K/V; only the CACHED copies are rounded (decode steps
+        then attend against what was stored, like every later token)."""
+        with jax.named_scope("attn.attend"):
+            att = attention(q, rows["k"], rows["v"], causal=True)
+            return att.reshape(att.shape[:2] + (-1,))
+
+    @staticmethod
+    def attend_cached(arch, blk, q, read, staged, mask, mask_staged):
+        """One query a slot against the window ``read`` and the chunk's
+        staged columns, both as the leaves hold them; the masks are
+        bool ``(S, 1, 1, T)``, for the int8-KV tier f32 addends
+        ``(S, T)``. Returns ``(S, 1, H·D)``."""
+        slots, _, heads, head_dim = q.shape
+        with jax.named_scope("attn.attend"):
+            if "k_scale" in read:
+                # python float (weak type): `q * inv_sqrt` must NOT
+                # promote a bf16 q to f32 — that would kill the
+                # fallback path's bf16 compute branch and widen the
+                # int8 cache to f32
+                att = int8_cache_attend(
+                    q * head_dim ** -0.5, read["k"], read["k_scale"],
+                    read["v"], read["v_scale"], mask,
+                    tail=(staged["k"], staged["k_scale"], staged["v"],
+                          staged["v_scale"], mask_staged))
+            else:
+                # heads unfolded: no byte moves, D is whole tiles
+                def apart(leaf):
+                    return leaf.reshape((slots, heads, -1, leaf.shape[-1]))
+
+                att = _cache_attend(
+                    q, apart(read["k"]), apart(read["v"]), mask,
+                    tail=(apart(staged["k"]), apart(staged["v"]),
+                          mask_staged))
+            return att.reshape(slots, 1, -1)
+
+    @staticmethod
+    def out(blk, x, att):
+        with jax.named_scope("attn.out"):
+            return x + matmul_any(att.astype(x.dtype), blk["wout"]) \
+                + blk["bout"]
+
+
+class Latent:
+    """``attention="mla"``: one row ``[c | k_rope]`` a position for all
+    heads. Leaves of a block: ``attn_norm``, ``wq_a``, ``q_norm``,
+    ``wq_b`` (q_rank, H·(nope+rope)), ``wkv_a`` (E, kv_rank+rope),
+    ``kv_norm``, ``wkv_b`` (kv_rank, H·(nope+v)), ``wout``."""
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        return {"kv": ((arch.kv_rank + arch.rope_dim,), dtype)}
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
+        """``positions`` (B, T): where each token stands in its
+        sequence. Returns ``((q_nope, q_rope), {"kv": (B, T, W)})``."""
+        batch, t, _ = x.shape
+        with jax.named_scope("attn.qkv"):
+            h = rms_norm(x, blk["attn_norm"], arch.eps)
+            with jax.named_scope("mla.q"):
+                q = rms_norm(h @ blk["wq_a"], blk["q_norm"], arch.eps) \
+                    @ blk["wq_b"]
+                q = q.reshape(batch, t, heads, -1)
+                q_nope, q_rope = q[..., :arch.nope_dim], \
+                    q[..., arch.nope_dim:]
+            with jax.named_scope("mla.kv"):
+                kv = h @ blk["wkv_a"]
+                c = rms_norm(kv[..., :arch.kv_rank], blk["kv_norm"],
+                             arch.eps)
+            with jax.named_scope("mla.rope"):
+                q_rope = rope(q_rope, positions, arch.rope_theta)
+                k_rope = rope(kv[..., arch.kv_rank:], positions,
+                              arch.rope_theta)
+            return (q_nope, q_rope), {
+                "kv": jnp.concatenate([c, k_rope], -1)}
+
+    @staticmethod
+    def columns(state, rows):
+        """``(..., T, W)`` -> ``{"kv": (..., W, T)}``."""
+        return {"kv": jnp.swapaxes(rows["kv"], -2, -1)
+                .astype(state["kv"][0].dtype)}
+
+    @staticmethod
+    def _up(arch, blk, heads):
+        """``wkv_b`` as (kv_rank, H, nope + v)."""
+        return blk["wkv_b"].reshape(arch.kv_rank, heads, -1)
+
+    @staticmethod
+    def attend_prompt(arch, blk, q, rows):
+        """Expanded: ``k_nope`` and ``v`` made from ``c`` for every
+        head, ``k_rope`` shared by the heads, scores over
+        ``nope + rope``."""
+        q_nope, q_rope = q
+        heads = q_nope.shape[2]
+        with jax.named_scope("attn.attend"):
+            c = rows["kv"][..., :arch.kv_rank]
+            k_rope = rows["kv"][..., None, arch.kv_rank:]
+            up = jnp.einsum("btc,chd->bthd", c,
+                            Latent._up(arch, blk, heads))
+            k = jnp.concatenate(
+                [up[..., :arch.nope_dim],
+                 jnp.broadcast_to(k_rope, k_rope.shape[:2] + (heads,)
+                                  + k_rope.shape[3:])], -1)
+            att = attention(jnp.concatenate([q_nope, q_rope], -1), k,
+                            up[..., arch.nope_dim:], causal=True)
+            return att.reshape(att.shape[:2] + (-1,))
+
+    @staticmethod
+    def attend_cached(arch, blk, q, read, staged, mask, mask_staged):
+        """Absorbed: the query goes into the latent space
+        (``q_nope . W^K``), the heads attend the one row a position as
+        queries of one sequence, and ``W^V`` lifts what they gather.
+        The weighted sum runs over the whole row; its ``k_rope`` tail
+        is dropped (an eighth more work, and no cut of the window)."""
+        q_nope, q_rope = q                          # (S, 1, H, ·)
+        slots, _, heads, _ = q_nope.shape
+        up = Latent._up(arch, blk, heads)
+        with jax.named_scope("attn.attend"):
+            with jax.named_scope("mla.absorb"):
+                q_lat = jnp.einsum("sqhn,chn->sqhc", q_nope,
+                                   up[..., :arch.nope_dim])
+            # the heads stand where _cache_attend has its queries, the
+            # one row where it has its one head: (S, H, 1, W)
+            q_all = jnp.concatenate([q_lat.astype(q_rope.dtype), q_rope],
+                                    -1).reshape(slots, heads, 1, -1)
+            got = _cache_attend(
+                q_all, read["kv"][:, None], read["kv"][:, None], mask,
+                tail=(staged["kv"][:, None], staged["kv"][:, None],
+                      mask_staged),
+                scale=(arch.nope_dim + arch.rope_dim) ** -0.5)
+            with jax.named_scope("mla.absorb"):
+                att = jnp.einsum(
+                    "shc,chv->shv",
+                    got[:, :, 0, :arch.kv_rank].astype(q_rope.dtype),
+                    up[..., arch.nope_dim:])
+            return att.reshape(slots, 1, -1)
+
+    @staticmethod
+    def out(blk, x, att):
+        with jax.named_scope("attn.out"):
+            return x + att.astype(x.dtype) @ blk["wout"]
+
+
+ATTENTION = {"mha": FusedQKV, "mla": Latent}
+
+
+def attention_kind(arch):
+    if arch.attention not in ATTENTION:
+        raise ValueError("no attention kind %r (known: %s)"
+                         % (arch.attention, ", ".join(sorted(ATTENTION))))
+    return ATTENTION[arch.attention]
+
+
+# -- feed-forward and head -----------------------------------------------------
+
+def ffn(arch, blk, x, live=None):
+    """The block's residual feed-forward, its kind read off the
+    block's leaves. ``live`` (B, T) bool marks the tokens that are
+    someone's (not padding, not an idle slot): routed experts leave
+    the others out. Returns ``(x, load)``, ``load`` the assignments
+    per held expert of an expert block and None otherwise."""
+    if "w1" in blk:
+        return _mlp(blk, x), None
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, blk["ffn_norm"], arch.eps)
+        if "router" not in blk:
+            return x + moe.swiglu(h, blk), None
+        flat = h.reshape(-1, h.shape[-1])
+        y, load = moe.expert_layer(
+            flat, blk, arch.top_k, arch.route_scale, held=arch.held,
+            live=None if live is None else live.reshape(-1))
+        return x + y.reshape(x.shape), load
+
+
+def head(arch, params, x):
+    """Final norm and vocabulary projection."""
+    if "lnf_w" in params:
+        return _head(params, x)
+    with jax.named_scope("head"):
+        return rms_norm(x, params["norm_w"], arch.eps) @ params["head"]
+
+
+def block_forward(arch, blk, x, heads, positions, live=None):
+    """One block over whole sequences ``x`` (B, T, E): ``(x, rows)``.
+    The prompt's path through a block, shared by the plain full
+    forward and the admission."""
+    kind = attention_kind(arch)
+    q, rows = kind.project(arch, blk, x, heads, positions)
+    x = kind.out(blk, x, kind.attend_prompt(arch, blk, q, rows))
+    return ffn(arch, blk, x, live)[0], rows

@@ -26,14 +26,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from veles_tpu.ops.attention import attention
 from veles_tpu.ops.quant import (int8_cache_attend, matmul_any,
                                  quantize_int8)
 from veles_tpu.observe.xla_stats import instrument
-from veles_tpu.parallel.mesh import shard_map
+from veles_tpu.parallel import blocks
 # ONE copy of the sublayer math, shared with the training-side full
-# forward — the equivalence the module contract promises is structural
-from veles_tpu.parallel.transformer_step import _block_qkv, _head, _mlp
+# forward — the equivalence the module contract promises is structural.
+# The slot engine runs whatever block the parameters declare through
+# the seam (parallel/blocks.py); ``generate`` and the tensor-parallel
+# decode run GPT-2's block through its helpers directly.
+from veles_tpu.parallel.blocks import (  # noqa: F401  (their old home)
+    _block_qkv, _cache_attend, _head, _mlp, _positions_last, _quantize_kv)
+from veles_tpu.parallel.mesh import shard_map
 
 
 def init_kv_cache(n_blocks, batch, max_len, heads, head_dim,
@@ -62,59 +66,65 @@ def init_kv_cache(n_blocks, batch, max_len, heads, head_dim,
             "length": jnp.zeros((), jnp.int32)}
 
 
-def _quantize_kv(x):
-    """Per-(batch, position, head) symmetric int8: (..., D) ->
-    (int8 (..., D), f32 scale (...,)). The quantization the cache
-    stores; one copy for prefill and decode appends."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale[..., 0]
-
-
-def _prefill_forward(params, x, heads, length=None):
+def _prompt_forward(params, x, heads, length=None):
     """The prompt forward pass shared by every prefill surface: run
-    ``x`` (B, T, E) through all blocks once and return
-    ``(last_logits, k_all, v_all, cache_len)`` with ``k_all``/``v_all``
-    stacked (L, B, T, H, D) — the caller decides how to store them
-    (full-cache write for :func:`prefill`, bucket-shaped slot slab for
-    :func:`slot_admit_many`).
+    ``x`` (B, T, E) through all blocks once and return ``(last_logits,
+    rows, cache_len)``: ``rows`` per block what each position leaves
+    in the cache, as the attention kind's ``project`` makes it (the
+    caller decides how to store it).
 
     ``length`` may be ``None`` (use T), a traced scalar (one shared
     right-padded length), or a traced (B,) vector (per-row true lengths
     — the batched same-bucket admission path); the logits always read
-    from each row's position ``length - 1``."""
-    batch, t, embed = x.shape
-    ks, vs = [], []
+    from each row's position ``length - 1``. Where the architecture
+    says so (``prefill_tokens``), the rows of a large group go through
+    a block in turn, so that its temporaries stay those of a part."""
+    batch, t, _ = x.shape
+    arch = blocks.arch_of(params)
+    cache_len = jnp.int32(t) if length is None \
+        else jnp.asarray(length, jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(t), (batch, t))
+    live = positions < jnp.reshape(cache_len, (-1, 1))
+    parts = 1
+    if arch.prefill_tokens:
+        parts = max(1, batch * t // arch.prefill_tokens)
+        while batch % parts:
+            parts -= 1
+    rows_all = []
     for blk in params["blocks"]:
-        q, k, v = _block_qkv(blk, x, heads)
-        ks.append(k)
-        vs.append(v)
-        # full causal attention over the prompt — the SAME gated op the
-        # training forward uses (flash kernel for prompts >= 4096).
-        # With a quantized cache the prompt attention still runs on the
-        # exact K/V; only the CACHED copies are rounded (decode steps
-        # then attend against what was stored, like every later token).
-        with jax.named_scope("attn.attend"):
-            att = attention(q, k, v, causal=True)
-        with jax.named_scope("attn.out"):
-            x = x + matmul_any(att.reshape(batch, t, embed),
-                               blk["wout"]) + blk["bout"]
-        x = _mlp(blk, x)
+        if parts == 1:
+            x, rows = blocks.block_forward(arch, blk, x, heads,
+                                           positions, live)
+        else:
+            def apart(a):
+                return a.reshape((parts, batch // parts) + a.shape[1:])
+
+            x, rows = lax.map(
+                lambda part, blk=blk: blocks.block_forward(
+                    arch, blk, part[0], heads, part[1], part[2]),
+                (apart(x), apart(positions), apart(live)))
+            x, rows = jax.tree.map(
+                lambda a: a.reshape((batch,) + a.shape[2:]), (x, rows))
+        rows_all.append(rows)
     if length is None:
         last = x[:, -1]
-        cache_len = jnp.int32(t)
+    elif cache_len.ndim == 0:
+        last = lax.dynamic_slice_in_dim(x, cache_len - 1, 1, axis=1)[:, 0]
     else:
-        cache_len = jnp.asarray(length, jnp.int32)
-        if cache_len.ndim == 0:
-            last = lax.dynamic_slice_in_dim(x, cache_len - 1, 1,
-                                            axis=1)[:, 0]
-        else:
-            last = jnp.take_along_axis(
-                x, (cache_len - 1)[:, None, None], axis=1)[:, 0]
-    logits = _head(params, last)
-    return logits, jnp.stack(ks), jnp.stack(vs), cache_len
+        last = jnp.take_along_axis(
+            x, (cache_len - 1)[:, None, None], axis=1)[:, 0]
+    return blocks.head(arch, params, last), rows_all, cache_len
+
+
+def _prefill_forward(params, x, heads, length=None):
+    """:func:`_prompt_forward` for the callers that store GPT-2's K/V
+    themselves (``prefill``, the paged pool): ``(last_logits, k_all,
+    v_all, cache_len)`` with ``k_all``/``v_all`` stacked
+    (L, B, T, H, D)."""
+    blocks.require_gpt2(params, "a cache of k/v leaves")
+    logits, rows, cache_len = _prompt_forward(params, x, heads, length)
+    return (logits, jnp.stack([r["k"] for r in rows]),
+            jnp.stack([r["v"] for r in rows]), cache_len)
 
 
 def prefill(params, x, heads, cache, length=None):
@@ -148,44 +158,6 @@ def prefill(params, x, heads, cache, length=None):
         new["v"] = lax.dynamic_update_slice(
             cache["v"], v_all.astype(cache["v"].dtype), (0, 0, 0, 0, 0))
     return logits, new
-
-
-def _cache_attend(q, k_all, v_all, mask, tail=None):
-    """Attention of query tokens against the cache prefix, f32 softmax:
-    ONE copy of the math for the single-device and tensor-parallel
-    decode paths (the TP guarantee of token-identity depends on it).
-    K/V come head-major with positions minor, ``(B, H, D, T)``: the
-    order the slot slab holds them in (:func:`init_slot_state`), so the
-    slot step hands over its window as it lies. A caller whose cache
-    is positions-major hands a transposed view (:func:`_positions_last`),
-    which XLA folds into the dots. ``tail`` is ``(k, v, mask)`` of more
-    positions that lie in another buffer (the slot chunk's staged
-    columns): one softmax over both, no copy that joins them. The
-    int8-cache variant lives in ``ops/quant.int8_cache_attend`` (same
-    order, dequantization fused into the dots)."""
-    scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-    parts = [(k_all, v_all, mask)] + ([tail] if tail is not None else [])
-    # q (B,1,H,D) x cache K (B,H,D,T) -> (B,H,1,T)
-    scores = [jnp.where(m, jnp.einsum(
-        "bqhd,bhdk->bhqk", q, k.astype(q.dtype),
-        preferred_element_type=jnp.float32) * scale, -1e30)
-        for k, _, m in parts]
-    p = jax.nn.softmax(jnp.concatenate(scores, axis=-1)
-                       if tail is not None else scores[0], axis=-1)
-    out, at = None, 0
-    for (_, v, _), s in zip(parts, scores):
-        part = jnp.einsum(
-            "bhqk,bhdk->bqhd", p[..., at:at + s.shape[-1]].astype(q.dtype),
-            v.astype(q.dtype), preferred_element_type=jnp.float32)
-        out = part if out is None else out + part
-        at += s.shape[-1]
-    return out
-
-
-def _positions_last(x):
-    """``(..., T, H, D)`` -> ``(..., H, D, T)``: rows of K/V as
-    ``_block_qkv`` makes them, in the slab's order."""
-    return jnp.moveaxis(x, -3, -1)
 
 
 def decode_step(params, x_tok, heads, cache):
@@ -260,6 +232,7 @@ def quantize_params(params):
     vocab head become ``{"q8": int8, "scale": f32}`` leaves that
     ``matmul_any`` dequantizes inside the product. Norms, biases and
     the caller's embed table stay in the serving float dtype."""
+    blocks.require_gpt2(params, "the int8 tiers' quantize_params")
     qblocks = []
     for blk in params["blocks"]:
         qblk = dict(blk)
@@ -338,6 +311,7 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
     if quantize not in (None, "none", "int8", "int8-kv"):
         raise ValueError("quantize must be None, 'int8' or 'int8-kv', "
                          "got %r" % (quantize,))
+    blocks.require_gpt2(params, "generate()'s scan over one shared cache")
     if quantize in ("int8", "int8-kv") \
             and not isinstance(params["head"], dict):
         params = quantize_params(params)
@@ -401,21 +375,28 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
 SLOT_SPAN_TILE = 128
 
 
-#: the state's K/V leaves (the int8-KV tier adds the scales): a tuple
-#: of one array per block under each name, everything else is control
-KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+#: the state's control leaves; every other leaf is the cache's, a
+#: tuple of one array per block under each name the attention kind
+#: declares (``blocks.ATTENTION[...].leaves``: GPT-2's ``k`` and ``v``,
+#: with ``k_scale``/``v_scale`` in the int8-KV tier; latent
+#: attention's one ``kv``)
 CONTROL_LEAVES = ("lengths", "logits", "req_key", "step")
 
 
 def _kv_names(state):
-    return [name for name in KV_LEAVES if name in state]
+    return sorted(name for name in state if name not in CONTROL_LEAVES)
 
 
 def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
                     dtype=jnp.float32, quantized=False, mesh=None,
                     mesh_axis="model", paged=False, pages=None,
-                    page_size=None, formats=None):
+                    page_size=None, formats=None, arch=blocks.GPT2):
     """Cache + control state for ``slots`` concurrent sequences.
+
+    The cache's leaves are the ones ``arch``'s attention kind declares
+    (``blocks.ATTENTION``), each a tuple of ``n_blocks`` arrays
+    ``(S, row..., T)``, positions minor: latent attention's one ``kv``
+    of ``(S, kv_rank + rope_dim, T)``, and GPT-2's as follows.
 
     The slab is ONE K and ONE V leaf per block, ``state["k"]`` and
     ``state["v"]`` tuples of ``n_blocks`` arrays ``(S, H·D, T)``:
@@ -454,6 +435,11 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
     host page table, created in-layout under ``mesh`` exactly like the
     slab (pool pages shard over HEADS)."""
     if paged:
+        if arch != blocks.GPT2:
+            raise ValueError(
+                "the page pool (parallel/kv_pool.py) holds k/v pages "
+                "of heads x head_dim; attention=%r has no paged cache "
+                "yet" % arch.attention)
         from veles_tpu.parallel.kv_pool import (default_pool_pages,
                                                 init_paged_state)
 
@@ -474,14 +460,10 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
         "req_key": jax.random.split(jax.random.key(0), slots),
         "step": jnp.zeros((slots,), jnp.int32),
     }
-    leaves = dict.fromkeys(
-        ("k", "v"), ((slots, heads * head_dim, max_len), dtype))
-    if quantized:
-        leaves = dict.fromkeys(
-            ("k", "v"), ((slots, heads, head_dim, max_len), jnp.int8))
-        leaves.update(dict.fromkeys(
-            ("k_scale", "v_scale"),
-            ((slots, heads, max_len), jnp.float32)))
+    leaves = {
+        name: ((slots,) + row + (max_len,), leaf_dtype)
+        for name, (row, leaf_dtype) in blocks.attention_kind(arch).leaves(
+            arch, heads, head_dim, dtype, quantized).items()}
     # where each leaf lives: every leaf is committed to its place, so
     # the first dispatch and every later one (whose state is a
     # program's output) are the same call to the same program
@@ -528,25 +510,6 @@ def param_tree_bytes(params, embed_table=None):
     return pytree_nbytes(params) + pytree_nbytes(embed_table)
 
 
-def _kv_columns(state, k, v):
-    """New K/V rows ``(..., T, H, D)`` as the state's leaves hold them:
-    ``{leaf name: (..., H·D, T)}`` in the leaves' dtype, and for the
-    int8-KV tier the quantized rows ``(..., H, D, T)`` with their
-    scales ``(..., H, T)``. One copy for the admission scatter and the
-    per-step appends."""
-    if "k_scale" not in state:
-        dtype = state["k"][0].dtype
-        folded = k.shape[:-3] + (-1, k.shape[-3])   # (..., H·D, T)
-        return {"k": _positions_last(k).astype(dtype).reshape(folded),
-                "v": _positions_last(v).astype(dtype).reshape(folded)}
-    out = {}
-    for name, val in (("k", k), ("v", v)):
-        q8, scale = _quantize_kv(val)           # (..,T,H,D), (..,T,H)
-        out[name] = _positions_last(q8)
-        out[name + "_scale"] = jnp.swapaxes(scale, -2, -1)
-    return out
-
-
 def _slot_admit_many(params, embed_table, heads, state, slots,
                      prompt_x, req_keys, lengths):
     """Admit a whole same-bucket group in ONE dispatch: prefill
@@ -566,11 +529,12 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
     ``req_keys`` (B,) seeds each slot's sampling stream; ``lengths``
     (B,) are the true prompt lengths inside the padded rows."""
     t = prompt_x.shape[1]
+    kind = blocks.attention_kind(blocks.arch_of(params))
     # named after the host-side "decode.admit" span so the XLA device
     # trace and the span timeline line up in a profiler capture
     # (observe/profile.py; zero cost post-compile)
     with jax.named_scope("decode.admit"):
-        logits, k_all, v_all, lengths = _prefill_forward(
+        logits, rows_all, lengths = _prompt_forward(
             params, prompt_x, heads, lengths)
         # the sampling stream's books, then positions [0, t) of each
         # admitted slot's K/V lane
@@ -587,14 +551,14 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
         with jax.named_scope("cache.append"):
             # positions [0, t) of each admitted slot's lane, block by
             # block: a block's rows (B, ..., T) are turned and written
-            # on their own, so no second copy of all blocks' K/V
-            # stands beside the stack the prefill returns
+            # on their own, so no second copy of all blocks' rows
+            # stands beside what the prefill returns
             fresh = {name: [] for name in _kv_names(state)}
-            for i in range(len(state["k"])):
-                rows = _kv_columns(state, k_all[i], v_all[i])
+            for i, rows in enumerate(rows_all):
+                columns = kind.columns(state, rows)
                 for name, leaf in fresh.items():
-                    leaf.append(
-                        state[name][i].at[slots, ..., :t].set(rows[name]))
+                    leaf.append(state[name][i].at[slots, ..., :t].set(
+                        columns[name]))
             new.update({name: tuple(leaves)
                         for name, leaves in fresh.items()})
     return new
@@ -621,8 +585,11 @@ def slot_admit(params, embed_table, heads, state, slot, prompt_x,
 
 def _slot_steps(params, embed_table, heads, state, active, n,
                 temperature, sample, top_k, span):
-    """``n`` lockstep decode steps across ALL slots, the K/V leaves
-    used in place: ``(state, emitted (n, S))``.
+    """``n`` lockstep decode steps across ALL slots, the cache's
+    leaves used in place: ``(state, emitted (n, S))``. A model with
+    routed experts emits ``(tokens (n, S), load (n, blocks, experts))``:
+    beside the tokens, the assignments per expert of each expert block
+    at each step, over the active slots (:func:`split_emitted`).
 
     Positions are the leaves' minor dimension, so one slot's new
     column is a strided write that costs an op of its own (3.5 us
@@ -639,7 +606,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     slots = state["lengths"].shape[0]
     quantized = "k_scale" in state
     names = _kv_names(state)
-    max_len = state["k"][0].shape[-1]       # positions are minor
+    arch = blocks.arch_of(params)
+    kind = blocks.attention_kind(arch)
+    max_len = state[names[0]][0].shape[-1]  # positions are minor
     if span is None or span > max_len:
         span = max_len
     before = state["lengths"]
@@ -648,9 +617,6 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     # visible from its own step on (the new token attends to itself)
     with jax.named_scope("attn.attend"):
         cached = jnp.arange(span)[None, :] < before[:, None]
-        # python float (weak type): `q * inv_sqrt` must NOT promote
-        # a bf16 q to f32 (see decode_step)
-        inv_sqrt = (embed_table.shape[-1] // heads) ** -0.5
 
     def masks(visible):
         if quantized:
@@ -662,9 +628,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
         lengths = control["lengths"]
         # the named scopes of a step (HLO metadata; the scope table,
         # observe/xla_stats.scope_table, carries them to a traced op):
-        # sample, embed, then per block attn.qkv (_block_qkv),
-        # cache.append, cache.read, attn.attend, attn.out, mlp (_mlp),
-        # then head (_head)
+        # sample, embed, then per block attn.qkv (the kind's project),
+        # cache.append, cache.read, attn.attend, attn.out, mlp (ffn),
+        # then head
         with jax.named_scope("sample"):
             if sample:
                 step_keys = jax.vmap(jax.random.fold_in)(
@@ -680,19 +646,20 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                 tok_in = jnp.argmax(control["logits"], axis=-1)
         with jax.named_scope("embed"):
             x = embed_table[tok_in][:, None, :]
-        embed = x.shape[-1]
         with jax.named_scope("attn.attend"):
             mask = masks(cached)
             mask_staged = masks(jnp.broadcast_to(
                 jnp.arange(n)[None, :] <= j, (slots, n)))
         staged = {name: list(staged[name]) for name in names}
+        loads = []
         for i, blk in enumerate(params["blocks"]):
-            q, k, v = _block_qkv(blk, x, heads)
+            # the new token stands at its slot's own length
+            q, rows = kind.project(arch, blk, x, heads, lengths[:, None])
             # every slot's new column at once, into column j of this
             # block's staging buffers: (S, H·D, 1); the int8 tier's
-            # (S, H, D, 1) and (S, H, 1)
+            # (S, H, D, 1) and (S, H, 1); latent attention's (S, W, 1)
             with jax.named_scope("cache.append"):
-                for name, cols in _kv_columns(state, k, v).items():
+                for name, cols in kind.columns(state, rows).items():
                     at = (0,) * (cols.ndim - 1) + (j,)
                     staged[name][i] = lax.dynamic_update_slice(
                         staged[name][i], cols, at)
@@ -702,29 +669,16 @@ def _slot_steps(params, embed_table, heads, state, active, n,
             with jax.named_scope("cache.read"):
                 read = {name: state[name][i][..., :span]
                         for name in names}
-            with jax.named_scope("attn.attend"):
-                if quantized:
-                    att = int8_cache_attend(
-                        q * inv_sqrt, read["k"], read["k_scale"],
-                        read["v"], read["v_scale"], mask,
-                        tail=(staged["k"][i], staged["k_scale"][i],
-                              staged["v"][i], staged["v_scale"][i],
-                              mask_staged))
-                else:
-                    # heads unfolded: no byte moves, D is whole tiles
-                    apart = (slots, heads, -1)
-                    att = _cache_attend(
-                        q, read["k"].reshape(apart + (span,)),
-                        read["v"].reshape(apart + (span,)), mask,
-                        tail=(staged["k"][i].reshape(apart + (n,)),
-                              staged["v"][i].reshape(apart + (n,)),
-                              mask_staged))
-            with jax.named_scope("attn.out"):
-                att = att.astype(x.dtype)
-                x = x + matmul_any(att.reshape(slots, 1, embed),
-                                   blk["wout"]) + blk["bout"]
-            x = _mlp(blk, x)
-        logits = _head(params, x[:, 0]).astype(jnp.float32)
+            att = kind.attend_cached(
+                arch, blk, q, read,
+                {name: staged[name][i] for name in names}, mask,
+                mask_staged)
+            x = kind.out(blk, x, att)
+            # an idle slot's lane is computed, but routed to no expert
+            x, load = blocks.ffn(arch, blk, x, active[:, None])
+            if load is not None:
+                loads.append(load)
+        logits = blocks.head(arch, params, x[:, 0]).astype(jnp.float32)
         with jax.named_scope("sample"):
             control = dict(
                 control,
@@ -733,8 +687,8 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                                  control["logits"]),
                 step=jnp.where(active, control["step"] + 1,
                                control["step"]))
-        return (control, {name: tuple(staged[name])
-                          for name in names}), tok_in
+        return (control, {name: tuple(staged[name]) for name in names}), \
+            ((tok_in, jnp.stack(loads)) if loads else tok_in)
 
     control = {name: state[name] for name in CONTROL_LEAVES}
     staged = {name: tuple(jnp.zeros(leaf.shape[:-1] + (n,), leaf.dtype)
@@ -761,6 +715,12 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                               functools.partial(put, block=block), leaf)
                 for leaf, block in zip(state[name], staged[name]))
     return new_state, emitted
+
+
+def split_emitted(emitted):
+    """``(tokens, load)`` of what a chunk emits; ``load`` is None for
+    a model without routed experts."""
+    return emitted if isinstance(emitted, tuple) else (emitted, None)
 
 
 def _slot_step(params, embed_table, heads, state, active,
@@ -792,7 +752,7 @@ def _slot_step(params, embed_table, heads, state, active,
     state, emitted = _slot_steps(params, embed_table, heads, state,
                                  active, 1, temperature, sample, top_k,
                                  span)
-    return state, emitted[0]
+    return state, split_emitted(emitted)[0][0]
 
 
 def _slot_step_many(params, embed_table, heads, state, active, n,
@@ -857,7 +817,7 @@ def slot_fns(state):
     The check-then-insert is LOCKED: two tiers of the same place built
     concurrently (a breaker rebuild racing a new API) must share one
     jit object, not compile twice."""
-    lead = state["k"][0]
+    lead = state[_kv_names(state)[0]][0]
     concrete = isinstance(lead, jax.Array) \
         and not isinstance(lead, jax.core.Tracer)
     key = tuple((name, state[name][0].format)
@@ -971,8 +931,8 @@ def decide_slot_formats(params, embed_table, heads, state, n, span,
 
     names = _kv_names(state)
     if mesh is not None:
-        specs = slot_state_specs(len(state["k"]), "k_scale" in state,
-                                 axis=mesh_axis)
+        specs = slot_state_specs(len(state[names[0]]),
+                                 "k_scale" in state, axis=mesh_axis)
         where = {name: NamedSharding(mesh, specs[name][0])
                  for name in names}
         control = NamedSharding(mesh, P())
@@ -980,7 +940,7 @@ def decide_slot_formats(params, embed_table, heads, state, n, span,
         here = SingleDeviceSharding(jax.devices()[0])
         where = {name: getattr(state[name][0], "sharding", None) or here
                  for name in names}
-        control = where["k"]
+        control = where[names[0]]
     memo = (tuple((name, state[name][0].shape, state[name][0].dtype,
                    where[name]) for name in names), heads, n, span)
     with _SLOT_FNS_LOCK:
@@ -1404,6 +1364,7 @@ def shard_slot_params(params, embed_table, heads, mesh, axis="model"):
     ``(params, embed_table)``; validates divisibility first."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    blocks.require_gpt2(params, "tensor-parallel serving (mesh=)")
     validate_slot_mesh(mesh, heads, params, embed_table, axis=axis)
     params = shard_slot_tree(params, mesh, slot_param_specs(params, axis))
     return params, jax.device_put(embed_table, NamedSharding(mesh, P()))

@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from veles_tpu.ops.quant import matmul_any
+from veles_tpu.ops.attention import ring_attention, ulysses_attention
+from veles_tpu.parallel import blocks
+from veles_tpu.parallel.blocks import (  # noqa: F401  (their old home)
+    _block_qkv, _head, _ln, _mlp)
 from veles_tpu.parallel.mesh import shard_map
-from veles_tpu.ops.attention import (attention, ring_attention,
-                                     ulysses_attention)
 
 
 def init_transformer_params(rng, n_blocks, embed, heads, vocab,
@@ -51,67 +52,33 @@ def init_transformer_params(rng, n_blocks, embed, heads, vocab,
             "head": mat(embed, vocab)}
 
 
-def _ln(x, w, b, eps=1e-5):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
-
-
-# The sublayer helpers are shared with the KV-cache decode path
-# (parallel/decode.py) — ONE copy of the block math keeps the cached
-# and full-recompute forwards numerically equivalent by construction.
-# Each writes its ``jax.named_scope`` (``attn.qkv``, ``mlp``, ``head``:
-# HLO metadata, nothing at run time), which the scope table
-# (observe/xla_stats.scope_table) carries to a traced op.
-
-def _block_qkv(blk, x, heads):
-    """Pre-LN qkv projection: (B, T, E) -> three (B, T, H, D)."""
-    batch, t, embed = x.shape
-    with jax.named_scope("attn.qkv"):
-        h = _ln(x, blk["ln1_w"], blk["ln1_b"])
-        qkv = matmul_any(h, blk["wqkv"]) + blk["bqkv"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (batch, t, heads, embed // heads)
-        return q.reshape(shape), k.reshape(shape), v.reshape(shape)
-
-
-def _mlp(blk, x, reduce=None):
-    """Pre-LN residual gelu MLP. ``reduce`` completes a sharded
-    contraction (tensor-parallel decode passes a psum; ``b2`` is added
-    AFTER it, so it stays replicated) — one copy of the math for the
-    single-device and TP paths alike. The products route through
-    ``matmul_any`` so the int8 serving tier (``ops/quant.py``) shares
-    this exact sublayer math."""
-    with jax.named_scope("mlp"):
-        h = _ln(x, blk["ln2_w"], blk["ln2_b"])
-        y = matmul_any(jax.nn.gelu(matmul_any(h, blk["w1"]) + blk["b1"]),
-                       blk["w2"])
-        if reduce is not None:
-            y = reduce(y)
-        return x + y + blk["b2"]
-
-
-def _head(params, x):
-    """Final layer norm + vocab projection."""
-    with jax.named_scope("head"):
-        return matmul_any(_ln(x, params["lnf_w"], params["lnf_b"]),
-                          params["head"])
-
+# The sublayers live in ``parallel/blocks.py`` (the model seam): ONE
+# definition each, shared with the KV-cache decode path
+# (parallel/decode.py), keeps the cached and full-recompute forwards
+# numerically equivalent by construction. GPT-2's helpers keep their
+# names here for the callers that import them from this module.
 
 def _forward(params, x, heads, seq_ax, sp_strategy):
+    """The plain full forward of whatever block ``params`` declare
+    (``blocks.arch_of``). Sequence parallelism is GPT-2's alone (it
+    has no position encoding to shard)."""
     batch, t, embed = x.shape
+    arch = blocks.arch_of(params)
+    if seq_ax > 1:
+        blocks.require_gpt2(params, "sequence-parallel training")
+    kind = blocks.attention_kind(arch)
+    positions = jnp.broadcast_to(jnp.arange(t), (batch, t))
     for blk in params["blocks"]:
-        q, k, v = _block_qkv(blk, x, heads)
-        if seq_ax > 1 and sp_strategy == "ring":
-            att = ring_attention(q, k, v, "seq", causal=True)
-        elif seq_ax > 1:
-            att = ulysses_attention(q, k, v, "seq", causal=True)
+        if seq_ax > 1:
+            q, rows = kind.project(arch, blk, x, heads, positions)
+            spread = ring_attention if sp_strategy == "ring" \
+                else ulysses_attention
+            att = spread(q, rows["k"], rows["v"], "seq", causal=True)
+            x = kind.out(blk, x, att.reshape(batch, t, embed))
+            x, _ = blocks.ffn(arch, blk, x)
         else:
-            att = attention(q, k, v, causal=True)
-        x = x + matmul_any(att.reshape(batch, t, embed),
-                           blk["wout"]) + blk["bout"]
-        x = _mlp(blk, x)
-    return _head(params, x)
+            x, _ = blocks.block_forward(arch, blk, x, heads, positions)
+    return blocks.head(arch, params, x)
 
 
 def build_transformer_train_step(heads, mesh=None, learning_rate=0.1,
@@ -127,6 +94,8 @@ def build_transformer_train_step(heads, mesh=None, learning_rate=0.1,
     seq_ax = mesh.shape.get("seq", 1) if mesh is not None else 1
 
     def local_step(params, x, labels):
+        # training any block but GPT-2's is not built yet
+        blocks.require_gpt2(params, "the transformer train step")
         # static: shard shapes are known at trace time — no collective
         n_tokens = jnp.float32(
             x.shape[0] * x.shape[1] * data_ax * seq_ax)

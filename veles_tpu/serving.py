@@ -497,6 +497,10 @@ class ServingHealth:
                 # the dense slab's device layout (decode.py
                 # slot_layout_facts): which layout this run is in
                 snap["kv_layout"] = layout
+            if getattr(getattr(deploy, "decoder", None), "moe_load",
+                       None) is not None:
+                # the routed experts' load, among the counters
+                snap["counters"].update(deploy.decoder.moe_counters())
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -813,6 +817,8 @@ class ContinuousDecoder:
         import jax
 
         from veles_tpu.ops.platform import on_tpu
+        from veles_tpu.parallel.blocks import (arch_of, expert_blocks,
+                                               require_gpt2)
         from veles_tpu.parallel.decode import (SLOT_SPAN_TILE,
                                                init_slot_state,
                                                quantize_params,
@@ -821,6 +827,22 @@ class ContinuousDecoder:
         if quantize not in (None, "none", "int8", "int8-kv"):
             raise ValueError("quantize must be None, 'int8' or "
                              "'int8-kv', got %r" % (quantize,))
+        #: the model's own architecture (parallel/blocks.py: it rides
+        #: in params["arch"]; a tree without one is GPT-2's block). The
+        #: dense slab serves every kind; a tier that is built on
+        #: GPT-2's leaves refuses another kind by name, here, before
+        #: anything is placed on the device
+        arch = arch_of(params)
+        for asked, tier in ((paged, "paged=True (the page pool)"),
+                            (quantize not in (None, "none"),
+                             "quantize=%r" % (quantize,)),
+                            (mesh is not None, "mesh= (tensor-parallel "
+                             "serving)"),
+                            (aot is not None, "aot= (exported programs)"),
+                            (prefix_cache is not None, "prefix_cache= "
+                             "(prefix reuse over pages)")):
+            if asked:
+                require_gpt2(params, tier)
         #: quantize="int8" serves the W8A16 tier (weight matrices int8,
         #: dequant fused into the products via matmul_any);
         #: "int8-kv" additionally stores the SLOT KV cache as int8 with
@@ -938,7 +960,7 @@ class ContinuousDecoder:
             embed // heads, vocab, dtype=embed_table.dtype,
             quantized=self.quantize == "int8-kv",
             mesh=mesh, mesh_axis=mesh_axis, paged=self.paged,
-            pages=self.pool_pages, page_size=self.page_size)
+            pages=self.pool_pages, page_size=self.page_size, arch=arch)
         #: the dense slab's device layout, decided once: the layout
         #: the chunk program works in, taken from the compiler on one
         #: representative chunk and pinned on every slot program from
@@ -970,6 +992,16 @@ class ContinuousDecoder:
         if not self.paged:
             from veles_tpu.parallel.decode import slot_layout_facts
             self.kv_layout = slot_layout_facts(self.state)
+        #: the routed experts' books (None for a model without): what
+        #: the decode chunks counted (decode._slot_steps emits, beside
+        #: the tokens, each expert block's assignments per expert at
+        #: each step). ``assignments`` per (expert block, expert), and
+        #: by the number of live slots a chunk ran with
+        #: ``[block-steps, assignments, experts touched]``: how many
+        #: experts a step reads depends on how many tokens it routes
+        self.moe_load = None
+        if expert_blocks(params):
+            self.moe_load = {"assignments": None, "by_lanes": {}}
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1936,10 +1968,17 @@ class ContinuousDecoder:
             dispatched if len(dispatched) == 3
             else (dispatched[0], dispatched[1], None))
         # span writes stay outside the timed window (see decode.admit)
-        with self._span("decode.collect", list(snapshot.values())):
+        from veles_tpu.parallel.decode import split_emitted
+
+        emitted, load = split_emitted(emitted)
+        with self._span("decode.collect",
+                        list(snapshot.values())) as span:
             t0 = time.perf_counter()
             emitted = numpy.asarray(emitted)  # (chunk, slots) — syncs
             elapsed = time.perf_counter() - t0
+            if load is not None:
+                span.annotate(**self._book_moe_load(numpy.asarray(load),
+                                                    len(snapshot)))
         self.timings["collect_s"] += elapsed
         self.metrics.observe(
             "veles_decode_collect_seconds", elapsed,
@@ -2007,6 +2046,52 @@ class ContinuousDecoder:
                 len(snapshot) * int(emitted.shape[0]), kept_total,
                 elapsed)
         return out
+
+    def _book_moe_load(self, load, lanes):
+        """Book one chunk's expert load ``(steps, expert blocks,
+        experts)``, run with ``lanes`` live slots; returns what the
+        chunk's collect span says of it."""
+        books = self.moe_load
+        per_expert = load.sum(0, dtype=numpy.int64)
+        books["assignments"] = per_expert if books["assignments"] is None \
+            else books["assignments"] + per_expert
+        said = {"moe_assignments": int(per_expert.sum()),
+                "moe_experts_touched": int((load > 0).sum())}
+        row = books["by_lanes"].setdefault(lanes, [0, 0, 0])
+        row[0] += load.shape[0] * load.shape[1]
+        row[1] += said["moe_assignments"]
+        row[2] += said["moe_experts_touched"]
+        labels = {"lanes": str(lanes)}
+        self.metrics.incr(
+            "veles_moe_assignments_total", said["moe_assignments"],
+            labels=labels, help="(token, expert) assignments the decode "
+            "steps routed, by live slots of the chunk")
+        self.metrics.incr(
+            "veles_moe_experts_touched_total", said["moe_experts_touched"],
+            labels=labels, help="experts with at least one assignment, "
+            "summed over decode steps and expert blocks")
+        self.metrics.set(
+            "veles_moe_load_max_over_mean", self.moe_load_max_over_mean(),
+            help="the busiest expert's assignments over the mean")
+        return said
+
+    def moe_load_max_over_mean(self):
+        """The busiest expert's assignments over the mean expert's, of
+        all the decode steps so far (the worst expert block's); None
+        before any."""
+        total = None if self.moe_load is None \
+            else self.moe_load["assignments"]
+        if total is None or not total.sum():
+            return None
+        return float((total.max(-1) / total.mean(-1)).max())
+
+    def moe_counters(self):
+        """The experts' books as ``/healthz`` has them among its
+        counters."""
+        return {"moe_load_max_over_mean": self.moe_load_max_over_mean(),
+                "moe_by_lanes": {
+                    str(lanes): list(row) for lanes, row
+                    in sorted(self.moe_load["by_lanes"].items())}}
 
     def dispatch_chunk(self, chunk):
         """Admit what fits and enqueue one chunk WITHOUT waiting for
