@@ -14,7 +14,9 @@ path. Two checks keep that from recurring, neither needing a chip:
   libtpu cannot describe a topology.
 
 Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
-128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows.
+128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows;
+the routed experts' streaming kernel at 256 rows over 256 experts of
+2048 x 768.
 """
 
 import os
@@ -87,6 +89,22 @@ def kernel_cases():
             attention.FORCE_FLASH = prev
 
     cases.append(("flash_attention_t4096_d128", flash, (qkv, qkv, qkv)))
+    # the routed experts' streaming kernel at the published shapes of
+    # the benchmark's expert model: a decode step of 32 slots x top-8
+    # = 256 rows over 256 experts of 2048 x 768
+    from veles_tpu.ops import moe
+
+    count, width, inner, rows = 256, 2048, 768, 256
+    cases.append((
+        "moe_streamed_experts_r256_e256_2048x768",
+        lambda r, load, gate, up, down: moe.streamed_experts(
+            r, moe.visit_table(load, rows),
+            {"w_gate": gate, "w_up": up, "w_down": down},
+            interpret=False),
+        (_sds((rows, width), "bfloat16"), _sds((count,), "int32"),
+         _sds((count, width, inner), "bfloat16"),
+         _sds((count, width, inner), "bfloat16"),
+         _sds((count, inner, width), "bfloat16"))))
     return cases
 
 
@@ -242,3 +260,72 @@ def test_chunk_program_uses_the_slab_in_place_on_v5e():
     # and the small control leaves (logits: 16 x 50257 f32)
     assert result["padded_bytes"] < slab + result["weights_bytes"] \
         + 0.01 * slab, result
+
+
+# -- the routed experts: streamed in the chunk, grouped in an admission -------
+
+_EXPERTS_CHILD = """
+import json, os, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, %(repo)r)
+sys.path.insert(0, os.path.join(%(repo)r, "benchmark", "tools"))
+from jax.experimental import topologies
+try:
+    topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+except Exception as exc:
+    print("NO-TOPOLOGY %%s" %% exc)
+    sys.exit(0)
+# this process sees the CPU: the rule and the kernel are told that the
+# programs compiled here are the chip's
+from veles_tpu.ops import moe
+moe.on_tpu = lambda: True
+moe.pallas_interpret = lambda: False
+import offchip_compile_arch
+with open(os.path.join(
+        %(repo)r, "benchmark/configs/joyai-llm-flash.json")) as fin:
+    config = json.load(fin)
+offchip_compile_arch.serve_programs(config, %(programs)r, %(out)r)
+"""
+
+
+def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
+        tmp_path):
+    """The benchmark's expert model at its published widths, compiled
+    for a described v5e: the chunk program (32 slots x top-8 = 256
+    rows an expert layer) holds the streaming kernel, once an expert
+    layer, and no ``ragged-dot`` custom call; the smallest admission
+    (one prompt of 128 tokens: 1,024 rows) still holds the compiler's
+    grouped kernel and no other. The chunk's temporaries stay far
+    under one expert layer's matrices: no copy of the experts."""
+    import json
+
+    programs = ["step:8:1408", "admit:128:1"]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c", _EXPERTS_CHILD % {
+                "repo": REPO, "programs": programs,
+                "out": str(tmp_path)}],
+            env=env, capture_output=True, text=True, timeout=600)
+    except subprocess.TimeoutExpired:
+        pytest.skip("the compile-only TPU client did not answer here")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    if any(line.startswith("NO-TOPOLOGY") for line in lines):
+        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
+    said = {row["program"]: row for row in (
+        json.loads(line) for line in lines if line.startswith("{"))}
+    chunk = (tmp_path / "step_8_1408.txt").read_text()
+    admit = (tmp_path / "admit_128_1.txt").read_text()
+    expert_layers = 4
+    assert "ragged-dot" not in chunk
+    # (the compiler's grouped kernel is a tpu_custom_call too)
+    assert chunk.count('custom_call_target="tpu_custom_call"') \
+        == expert_layers
+    assert chunk.count(
+        'mlp/moe.experts/moe_streamed_experts/pallas_call"') \
+        == expert_layers
+    assert "moe_streamed_experts" not in admit
+    assert admit.count('op_name="ragged-dot-none"') == 3 * expert_layers
+    one_layer = 3 * 256 * 2048 * 768 * 2
+    assert said["step:8:1408"]["temp_bytes"] < 0.1 * one_layer, said

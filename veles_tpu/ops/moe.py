@@ -10,21 +10,50 @@ token goes to its ``top_k`` experts whatever the others chose:
     y = sum_i w_i . E_chosen_i(h)           E_e = W_down(silu(W_gate h) * W_up h)
 
 The products are grouped: the (token, expert) assignments are sorted
-by expert and each expert multiplies the rows that chose it
-(``jax.lax.ragged_dot``: on the TPU a grouped kernel that visits the
-groups that have rows, so an expert no token chose is not read). The
-layer is told which experts it holds (``held = (first, count)``): it
-routes over all of them and computes the part of ``y`` that the held
-ones give; the parts of disjoint shares add up to the whole layer.
+by expert and each expert multiplies the rows that chose it, so an
+expert no token chose is not read. One algorithm, two tilings, chosen
+from static shapes when a program is traced (:func:`expert_path`):
+
+- ``"streamed"`` (:func:`streamed_experts`): few rows (a decode step:
+  live slots x ``top_k`` assignments over as many experts). The work
+  is a read of the touched experts and nothing else, so a Pallas
+  kernel visits each touched expert once and takes its three matrices
+  from HBM whole, a DMA of megabytes each, while the one before is
+  multiplied; the ``(rows, F)`` intermediate never leaves VMEM.
+- ``"grouped"`` (``jax.lax.ragged_dot``: the compiler's own grouped
+  kernel, 512 x 256 weight tiles): many rows an expert (an admission,
+  the plain forward), every platform but the TPU, widths that are not
+  lane multiples, leaves sharded over a mesh.
+
+The layer is told which experts it holds (``held = (first, count)``):
+it routes over all of them and computes the part of ``y`` that the
+held ones give; the parts of disjoint shares add up to the whole
+layer.
 
 The named scopes (``moe.route``, ``moe.dispatch``, ``moe.experts``,
 ``moe.combine``, ``moe.shared``) are HLO metadata that the scope table
 (``observe/xla_stats.scope_table``) carries to a traced op.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from veles_tpu.ops.platform import on_tpu, pallas_interpret
+
+#: the most assignments (rows: tokens x ``top_k``) a call may have and
+#: still take the streaming kernel. Measured on the v5e at the
+#: published widths (256 experts of 2048 x 768, bfloat16; PERF.md §5,
+#: the rows sweep of PR 30).
+STREAM_MAX_ROWS = 512
+#: rows of one product inside the kernel. An expert's rows start
+#: anywhere, a tile at a multiple of 16 (a bfloat16 sublane tile), so
+#: a tile of 32 takes any expert of up to 17 rows in one pass.
+_ROW_TILE, _ROW_ALIGN = 32, 16
 
 
 def route(h, router, bias, top_k, scale):
@@ -47,6 +76,152 @@ def swiglu(h, p):
     return (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
 
 
+def _sharded(leaf):
+    """Whether ``leaf`` says it lies over more than one device: an
+    array by its sharding, a tracer by the mesh its type carries. (A
+    jit that shards its arguments without saying so in their types
+    shows nothing here; no serving path shards the experts yet.)"""
+    sharding = getattr(leaf, "sharding", None)
+    if isinstance(leaf, jax.core.Tracer) or sharding is None:
+        sharding = getattr(jax.typeof(leaf), "sharding", None)
+        return sharding is not None and not sharding.mesh.empty \
+            and any(axis is not None for axis in sharding.spec)
+    return len(sharding.device_set) > 1
+
+
+def expert_path(n_rows, experts):
+    """Which tiling the grouped products of ``n_rows`` assignments
+    over the stacked ``experts`` take: ``"streamed"`` or
+    ``"grouped"``. Read off the platform and static shapes, so it is
+    known when a program is traced (and to whoever knows the program's
+    shapes: the decoder books it per dispatch)."""
+    w_gate = experts["w_gate"]
+    _, width, inner = w_gate.shape
+    if on_tpu() and n_rows <= STREAM_MAX_ROWS \
+            and width % 128 == 0 and inner % 128 == 0 \
+            and not _sharded(w_gate):
+        return "streamed"
+    return "grouped"
+
+
+def _padded_rows(n_rows):
+    return -(-n_rows // _ROW_TILE) * _ROW_TILE
+
+
+def visit_table(load, n_rows):
+    """The streaming kernel's walk over ``load`` (count,), the rows
+    each expert got: ``(expert, first row, rows)`` of each visit, int32
+    ``(visits,)`` each with ``visits = min(count, n_rows)``. The
+    touched experts come first, in their order; a visit past the last
+    of them repeats its expert (the same block index: no DMA) and has
+    no rows."""
+    count = load.shape[0]
+    visit = jnp.arange(min(count, n_rows))
+    touched = load > 0
+    n_touched = jnp.sum(touched, dtype=jnp.int32)
+    ids = jnp.argsort(~touched, stable=True).astype(jnp.int32)
+    ids = jnp.take(ids, jnp.minimum(visit, jnp.maximum(n_touched - 1, 0)))
+    first = jnp.cumsum(load) - load
+    return (ids, jnp.take(first, ids),
+            jnp.where(visit < n_touched, jnp.take(load, ids), 0))
+
+
+def _stream_kernel(ids_ref, first_ref, n_ref, rows_ref, gate_ref, up_ref,
+                   down_ref, out_ref):
+    """One visit: the expert's matrices are in VMEM whole (the next
+    visit's are on their way); its rows ``[first, first + n)`` of the
+    resident ``rows`` go through gate, up and down a tile at a time
+    and land in the resident ``out``, the rows of other experts that
+    a tile covers left as they were."""
+    visit = pl.program_id(0)
+    n_rows = rows_ref.shape[0]
+
+    @pl.when(visit == 0)
+    def _clear():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    first, n = first_ref[visit], n_ref[visit]
+
+    @pl.when(n > 0)
+    def _products():
+        base = first // _ROW_ALIGN * _ROW_ALIGN
+
+        def tile(i, carry):
+            at = pl.multiple_of(jnp.minimum(base + i * _ROW_TILE,
+                                            n_rows - _ROW_TILE),
+                                _ROW_ALIGN)
+            x = rows_ref[pl.ds(at, _ROW_TILE), :]
+            gate = jnp.dot(x, gate_ref[0],
+                           preferred_element_type=jnp.float32)
+            up = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+            inner = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+            y = jnp.dot(inner, down_ref[0],
+                        preferred_element_type=jnp.float32)
+            row = at + lax.broadcasted_iota(jnp.int32, (_ROW_TILE, 1), 0)
+            mine = (row >= first) & (row < first + n)
+            out_ref[pl.ds(at, _ROW_TILE), :] = jnp.where(
+                mine, y, out_ref[pl.ds(at, _ROW_TILE), :])
+            return carry
+
+        lax.fori_loop(0, (first + n - base + _ROW_TILE - 1) // _ROW_TILE,
+                      tile, 0)
+
+
+def streamed_experts(rows, visits, experts, interpret=None):
+    """``W_down(silu(W_gate r) * W_up r)`` of every row ``r`` of
+    ``rows`` (M, E), sorted by expert, M a multiple of the row tile,
+    through the expert that :func:`visit_table`'s ``visits`` give it:
+    ``(M, E)`` float32, zero where no visit reaches. Each touched
+    expert's ``w_gate``/``w_up`` (E, F) and ``w_down`` (F, E) leave
+    HBM once, whole, from the stacked leaves as they lie; ``rows`` and
+    the result stay in VMEM throughout. Operands as they come
+    (bfloat16 in serving), products accumulated and gated in float32.
+    ``interpret=None`` resolves from the platform."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    n_rows, width = rows.shape
+    inner = experts["w_gate"].shape[-1]
+    assert n_rows % _ROW_TILE == 0, (n_rows, _ROW_TILE)
+    item = experts["w_gate"].dtype.itemsize
+
+    def resident(v, *_):
+        return (0, 0)
+
+    def visited(v, ids, *_):
+        return (ids[v], 0, 0)
+
+    # two buffers of everything the pipeline moves, the products' float32
+    # temporaries, and room for the compiler's own
+    vmem = 2 * (3 * width * inner * item
+                + n_rows * width * (rows.dtype.itemsize + 4)) \
+        + 4 * _ROW_TILE * (3 * inner + 2 * width) + (8 << 20)
+    return pl.pallas_call(
+        _stream_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=visits[0].shape,
+            in_specs=[pl.BlockSpec((n_rows, width), resident),
+                      pl.BlockSpec((1, width, inner), visited),
+                      pl.BlockSpec((1, width, inner), visited),
+                      pl.BlockSpec((1, inner, width), visited)],
+            out_specs=pl.BlockSpec((n_rows, width), resident)),
+        out_shape=jax.ShapeDtypeStruct((n_rows, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        name="moe_streamed_experts",
+        interpret=interpret,
+    )(*visits, rows, experts["w_gate"], experts["w_up"],
+      experts["w_down"])
+
+
+def grouped_experts(rows, load, experts):
+    """The same products through ``jax.lax.ragged_dot``, ``load`` (count,)
+    the rows of each expert in turn: ``(M, E)`` in the rows' type."""
+    inner = jax.nn.silu(lax.ragged_dot(rows, experts["w_gate"], load)) \
+        * lax.ragged_dot(rows, experts["w_up"], load)
+    return lax.ragged_dot(inner, experts["w_down"], load)
+
+
 def routed_experts(h, chosen, weights, experts, held=None, live=None):
     """The held experts' part of the layer for tokens ``h`` (N, E):
     ``(y (N, E), load (n_held,) int32)``. ``experts`` holds the
@@ -58,6 +233,7 @@ def routed_experts(h, chosen, weights, experts, held=None, live=None):
     count = experts["w_gate"].shape[0]
     first = 0 if held is None else held[0]
     n, top_k = chosen.shape
+    streamed = expert_path(n * top_k, experts) == "streamed"
     with jax.named_scope("moe.dispatch"):
         local = chosen - first
         mine = (local >= 0) & (local < count)
@@ -71,11 +247,16 @@ def routed_experts(h, chosen, weights, experts, held=None, live=None):
         # (a comparison and a sum: a scatter-add is slow on the TPU)
         load = jnp.sum(key[:, None] == jnp.arange(count), axis=0,
                        dtype=jnp.int32)
-        rows = jnp.take(h, order // top_k, axis=0)
+        source = order // top_k
+        if streamed:
+            # whole row tiles; no visit reaches the rows added
+            source = jnp.pad(source, (0, _padded_rows(n * top_k)
+                                      - n * top_k))
+            visits = visit_table(load, n * top_k)
+        rows = jnp.take(h, source, axis=0)
     with jax.named_scope("moe.experts"):
-        inner = jax.nn.silu(lax.ragged_dot(rows, experts["w_gate"], load)) \
-            * lax.ragged_dot(rows, experts["w_up"], load)
-        out = lax.ragged_dot(inner, experts["w_down"], load)
+        out = streamed_experts(rows, visits, experts) if streamed \
+            else grouped_experts(rows, load, experts)
     with jax.named_scope("moe.combine"):
         # back in the tokens' order; a row past the last group holds
         # whatever the grouped product left there: selected away, not

@@ -78,6 +78,18 @@ def expert_blocks(params):
     return [i for i, blk in enumerate(params["blocks"]) if "router" in blk]
 
 
+def expert_path(params, tokens):
+    """The tiling the routed experts' products take
+    (``ops/moe.expert_path``) in a program whose feed-forward sees
+    ``tokens`` tokens at once; None for a model without routed
+    experts."""
+    routed = expert_blocks(params)
+    if not routed:
+        return None
+    return moe.expert_path(tokens * arch_of(params).top_k,
+                           params["blocks"][routed[0]]["experts"])
+
+
 def require_gpt2(params, what):
     """Refuse by name what only GPT-2's block has yet."""
     arch = arch_of(params)

@@ -66,6 +66,18 @@ def init_kv_cache(n_blocks, batch, max_len, heads, head_dim,
             "length": jnp.zeros((), jnp.int32)}
 
 
+def prefill_parts(arch, batch, t):
+    """In how many parts a group of ``batch`` prompts of ``t``
+    positions goes through a block (``Arch.prefill_tokens``): a
+    divisor of ``batch``, 1 for the whole group at once."""
+    parts = 1
+    if arch.prefill_tokens:
+        parts = max(1, batch * t // arch.prefill_tokens)
+        while batch % parts:
+            parts -= 1
+    return parts
+
+
 def _prompt_forward(params, x, heads, length=None):
     """The prompt forward pass shared by every prefill surface: run
     ``x`` (B, T, E) through all blocks once and return ``(last_logits,
@@ -85,11 +97,7 @@ def _prompt_forward(params, x, heads, length=None):
         else jnp.asarray(length, jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(t), (batch, t))
     live = positions < jnp.reshape(cache_len, (-1, 1))
-    parts = 1
-    if arch.prefill_tokens:
-        parts = max(1, batch * t // arch.prefill_tokens)
-        while batch % parts:
-            parts -= 1
+    parts = prefill_parts(arch, batch, t)
     rows_all = []
     for blk in params["blocks"]:
         if parts == 1:

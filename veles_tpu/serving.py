@@ -999,9 +999,14 @@ class ContinuousDecoder:
         #: by the number of live slots a chunk ran with
         #: ``[block-steps, assignments, experts touched]``: how many
         #: experts a step reads depends on how many tokens it routes
+        #: ``paths``: dispatches (chunks, steps and admissions alike)
+        #: by the tiling their grouped products took, which the
+        #: program's shapes decide when it is traced
+        #: (``ops/moe.expert_path``)
         self.moe_load = None
         if expert_blocks(params):
-            self.moe_load = {"assignments": None, "by_lanes": {}}
+            self.moe_load = {"assignments": None, "by_lanes": {},
+                             "paths": {"streamed": 0, "grouped": 0}}
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1388,7 +1393,9 @@ class ContinuousDecoder:
         stays O(buckets x log2(slots))."""
         import jax
 
-        from veles_tpu.parallel.decode import slot_admit_many
+        from veles_tpu.parallel.blocks import arch_of
+        from veles_tpu.parallel.decode import (prefill_parts,
+                                               slot_admit_many)
 
         admit = (self._aot.admit if self._aot is not None
                  else slot_admit_many)
@@ -1415,11 +1422,15 @@ class ContinuousDecoder:
             req_keys = jax.vmap(jax.random.fold_in,
                                 in_axes=(None, 0))(self.base_key, rids)
             x = self.embed_table[jnp.asarray(prompts)]
+            # the feed-forward sees a part of the group at a time
+            said = self._book_moe_path(
+                len(rows) * bucket // prefill_parts(
+                    arch_of(self.params), len(rows), bucket))
             # span entered OUTSIDE the timed window: the span's own
             # begin/end writes (file I/O when tracing) must not inflate
             # the host-overhead attribution they exist to explain
             with self._span("decode.admit", [r[0] for r in group],
-                            bucket=bucket, group=len(group)):
+                            bucket=bucket, group=len(group), **said):
                 t0 = time.perf_counter()
                 self.state = admit(
                     self.params, self.embed_table, self.heads,
@@ -1890,6 +1901,7 @@ class ContinuousDecoder:
         for slot in snapshot:
             self._slot_len[slot] += 1
         self.dispatch_counts["step"] += 1
+        self._book_moe_path(self.slots)
         self.flight.note("step", rids=list(snapshot.values()))
         ledger_aot = None
         if self.ledger is not None:
@@ -2075,6 +2087,23 @@ class ContinuousDecoder:
             help="the busiest expert's assignments over the mean")
         return said
 
+    def _book_moe_path(self, tokens):
+        """Book one dispatch whose feed-forward sees ``tokens`` tokens
+        at once by the tiling its grouped products take; returns what
+        the dispatch's span says of it (nothing for a model without
+        routed experts)."""
+        if self.moe_load is None:
+            return {}
+        from veles_tpu.parallel.blocks import expert_path
+
+        path = expert_path(self.params, tokens)
+        self.moe_load["paths"][path] += 1
+        self.metrics.incr(
+            "veles_moe_expert_dispatches_total", 1,
+            labels={"path": path}, help="dispatches (chunks, steps, "
+            "admissions) by the tiling of the routed experts' products")
+        return {"moe_expert_path": path}
+
     def moe_load_max_over_mean(self):
         """The busiest expert's assignments over the mean expert's, of
         all the decode steps so far (the worst expert block's); None
@@ -2091,7 +2120,8 @@ class ContinuousDecoder:
         return {"moe_load_max_over_mean": self.moe_load_max_over_mean(),
                 "moe_by_lanes": {
                     str(lanes): list(row) for lanes, row
-                    in sorted(self.moe_load["by_lanes"].items())}}
+                    in sorted(self.moe_load["by_lanes"].items())},
+                "moe_expert_path": dict(self.moe_load["paths"])}
 
     def dispatch_chunk(self, chunk):
         """Admit what fits and enqueue one chunk WITHOUT waiting for
@@ -2118,6 +2148,7 @@ class ContinuousDecoder:
             self._layout_said = True
             said = {"kv_layout": json.dumps(self.kv_layout,
                                             sort_keys=True)}
+        said.update(self._book_moe_path(self.slots))
         # span writes stay outside the timed window (see decode.admit)
         with self._span("paged.dispatch" if self.paged
                         else "decode.dispatch",
