@@ -1,6 +1,6 @@
 PYTHON ?= python
 
-.PHONY: check test hooks chaos chaos-serve bench-serve metrics \
+.PHONY: check test hooks chaos chaos-serve metrics \
 	regress mesh paged paged-kernel fleet-mr aot slo governor history \
 	analyze fleetscope servescope deploy elastic replay memscope
 
@@ -57,8 +57,8 @@ paged:
 # kernel"): kernel-vs-gather token bit-identity through the real
 # serving engine via Pallas interpret mode (bf16 + int8-KV, mid-flight
 # joins, tail/hit admissions), the ragged admission path's per-row
-# masking + exact page allocation, the capability-probe fallback
-# matrix (FORCE toggle / config / backend auto), tile_pad waste
+# masking + exact page allocation, the rule's fallback matrix
+# (platform / mesh / the test seam), tile_pad waste
 # accounting with span/page overshoot pinned 0, and the warmed-sweep
 # zero-retrace guard. (The interpret-mode composites ride the `slow`
 # marker so tier-1 keeps its timeout margin; this target runs them.)
@@ -78,13 +78,6 @@ fleet-mr:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_mapreduce.py \
 		tests/test_fleet_chaos.py -m fleet_mr -q
 
-# Standalone continuous-batching serving bench (docs/
-# serving_performance.md): one JSON line with the decode_continuous_*
-# keys — tokens/sec, prefill ms, host-overhead fraction, dispatch
-# tallies and the veles_decode_* histogram summaries.
-bench-serve:
-	$(PYTHON) bench.py --serve
-
 # Observability suite standalone (docs/observability.md): registry
 # concurrency + exposition format, the disabled-path overhead guard
 # (shared null-span identity, zero registry mutations — observability
@@ -93,15 +86,12 @@ bench-serve:
 metrics:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_observe.py -q
 
-# Artifact-proof regression sentinel (docs/observability.md): compare
-# the committed previous-round BENCH json against itself through the
-# full loader (exercising the truncated-tail recovery the r5 artifact
-# needs) — must exit 0 — then run the sentinel suite, whose
-# seeded-regression fixture proves the gate exits NONZERO on a real
-# regression. CI runs this on every push.
+# Artifact-proof regression sentinel (docs/observability.md): the
+# sentinel suite — the loader's truncated-tail recovery over
+# tests/fixtures/bench_truncated_tail.json, and the seeded-regression
+# fixture that proves the gate exits NONZERO on a real regression. CI
+# runs this on every push.
 regress:
-	JAX_PLATFORMS=cpu $(PYTHON) -m veles_tpu observe regress \
-		BENCH_r05.json BENCH_r05.json
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_regress.py -q
 
 # Request-truth ledger + SLO suite (docs/observability.md): the

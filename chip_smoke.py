@@ -52,15 +52,14 @@ DEADLINE_S = 1150.0
 def device_facts():
     """Platform, kind and count as JAX reports them, the versions of the
     one installation there is, where the compile cache lives, and
-    whether anything from outside the tree was picked up (a Pallas
-    tuning file, a site config: on a fresh machine, neither)."""
+    whether anything from outside the tree was picked up (a site
+    config: on a fresh machine, none)."""
     from importlib import metadata
 
     import jax
     import jaxlib
 
     from veles_tpu.core.config import site_config_paths
-    from veles_tpu.ops import gemm
 
     devices = jax.devices()
     try:
@@ -74,7 +73,6 @@ def device_facts():
         "jax": jax.__version__, "jaxlib": jaxlib.__version__,
         "libtpu": libtpu,
         "compile_cache": jax.config.jax_compilation_cache_dir,
-        "tuning_file": os.path.exists(gemm._cache_path()),
         "site_config": any(os.path.exists(p)
                            for p in site_config_paths()),
     }
@@ -195,10 +193,10 @@ def serve_phase(quantize=None, paged=False, mesh=None, blocks=4,
                 n_tokens=64, chunk=64, n_requests=16, clients=8,
                 page_size=128, seed=0):
     """``GenerateAPI(...).start()`` over HTTP at the serving shape of
-    record (``bench.decode_continuous``; depth and the random weights
-    are the only cuts): two waves of ``n_requests`` POSTs from
-    ``clients`` threads, so the second half of a wave admits
-    mid-flight. The first wave pays the compiles, the second (fresh
+    record (e1024/h16/v32768, 8 slots x 512-token prompts; depth and
+    the random weights are the only cuts): two waves of ``n_requests``
+    POSTs from ``clients`` threads, so the second half of a wave
+    admits mid-flight. The first wave pays the compiles, the second (fresh
     prompts, same shapes) is the warm figure. ``paged`` and ``mesh`` go
     through ``root.common.serve`` — the ``--serve-paged`` /
     ``--serve-mesh`` landing spots — and the paged tier adds one prompt

@@ -23,8 +23,8 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 # keep ALL framework cache/state artifacts out of the user's home:
-# config.py derives every dir (incl. the pallas autotune cache) from
-# VELES_TPU_HOME, which must be set before veles_tpu imports
+# config.py derives every dir from VELES_TPU_HOME, which must be set
+# before veles_tpu imports
 _tmp = tempfile.mkdtemp(prefix="veles_tpu_test_")
 os.environ["VELES_TPU_HOME"] = _tmp
 

@@ -57,6 +57,28 @@ class TestDeviceBenchmark:
         assert 0.1 < power / power2 < 10
 
 
+class TestMoeRowsSweep:
+    def test_one_line_per_row_count_and_the_tilings_agree(self):
+        """``python -m veles_tpu.scripts.moe_rows_sweep`` at toy
+        lane-aligned sizes: the CPU's times mean nothing, the shape of
+        the answer and the two tilings' agreement do (the streamed
+        kernel runs interpreted here)."""
+        from veles_tpu.scripts.moe_rows_sweep import moe_rows_sweep
+
+        out = moe_rows_sweep(rows=(32, 64), count=4, width=128,
+                             inner=128, top_k=2, steps=1, repeats=1)
+        assert out["device"][0] == "cpu"
+        assert [line["rows"] for line in out["rows"]] == [32, 64]
+        for line in out["rows"]:
+            assert set(line) == {"rows", "touched", "grouped_ms",
+                                 "streamed_ms", "streamed_gb_per_s",
+                                 "gap"}
+            assert line["touched"] == 4
+            # tests/test_moe_streamed.py's bound, 2% of the widest
+            # value, which is 2.8 and 3.1 at these sizes and seeds
+            assert line["gap"] < 0.02 * 2.5, line
+
+
 class TestCompareSnapshots:
     def test_identical_and_diverged(self, tmp_path):
         from veles_tpu.models.mlp import MLPWorkflow

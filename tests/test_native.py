@@ -302,6 +302,14 @@ class TestMalformedPackages:
         runtime's f2->f32 widening keeps inference within the f16
         quantization tolerance of the f32 package."""
         from sklearn.datasets import load_digits
+
+        from veles_tpu.core import prng
+
+        # seeded: the argmax comparison below is exact, and weights
+        # drawn from whatever state an earlier test of the same
+        # process left can put one sample of 64 at a near tie
+        prng.get("default").seed(1)
+        prng.get("loader").seed(1)
         d = load_digits()
         X = d.data.astype(numpy.float32)
         y = d.target.astype(numpy.int32)

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 from veles_tpu import ops
 from veles_tpu.ops import activations, losses
-from veles_tpu.ops.gemm import matmul, pallas_matmul
+from veles_tpu.ops.gemm import dense_layer, matmul
 
 
 class TestGemm:
@@ -31,111 +31,87 @@ class TestGemm:
             tol = {0: 2e-2, 1: 1e-3, 2: 1e-5}[level]
             numpy.testing.assert_allclose(out, ref, rtol=tol)
 
-    def test_pallas_matmul_interpret(self):
-        """Blocked Pallas kernel vs numpy, incl. ragged shapes (padding)."""
-        rng = numpy.random.RandomState(2)
-        for m, k, n in ((128, 128, 128), (130, 70, 50)):
-            a = rng.rand(m, k).astype(numpy.float32)
-            b = rng.rand(k, n).astype(numpy.float32)
-            out = pallas_matmul(jnp.asarray(a), jnp.asarray(b),
-                                out_dtype=jnp.float32,
-                                bm=64, bn=64, bk=64, interpret=True)
-            numpy.testing.assert_allclose(out, a @ b, rtol=1e-4)
+    @pytest.mark.parametrize("level", (0, 1, 2))
+    def test_lowers_to_one_dot_without_a_custom_call(self, level):
+        """Lowered for the TPU (no chip needed), ``matmul`` and
+        ``dense_layer`` are XLA's dot and nothing of ours: one
+        ``dot_general`` each, no custom call, at every precision."""
+        a = jax.ShapeDtypeStruct((512, 1024), jnp.float32)
+        b = jax.ShapeDtypeStruct((1024, 512), jnp.float32)
+        bias = jax.ShapeDtypeStruct((512,), jnp.float32)
+        for fn, args in (
+                (lambda a, b: matmul(a, b, precision_level=level),
+                 (a, b)),
+                (lambda a, b, bias: dense_layer(
+                    a, b, bias, activation="tanh",
+                    precision_level=level), (a, b, bias))):
+            text = jax.export.export(
+                jax.jit(fn), platforms=["tpu"])(*args).mlir_module()
+            assert text.count("= stablehlo.dot_general") == 1, text
+            assert "stablehlo.custom_call" not in text, text
 
 
-class TestAutotuneCacheHygiene:
-    """ISSUE 5 satellite: the autotune cache must
-    reject physically impossible entries — the two-length slope
-    estimator can go negative under timing jitter, and a persisted
-    negative timing gated a product matmul on a measurement that never
-    happened."""
+def _rule_cases():
+    """(id, module name, platform is the TPU, call on the module, the
+    rule's answer): every kernel rule on both sides of each of its
+    conditions."""
+    def qk(t, d):
+        x = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
+        return lambda m: m.use_flash(x, x)
 
-    @pytest.fixture
-    def cache_file(self, tmp_path, monkeypatch):
-        from veles_tpu.core.config import root
-        from veles_tpu.ops import gemm
+    def int8(rows, k, n):
+        return lambda m: m.use_int8_kernel(rows, k, n)
 
-        path = str(tmp_path / "pallas_tuning.json")
-        monkeypatch.setattr(root.common.engine, "pallas_autotune_cache",
-                            path, raising=False)
-        monkeypatch.setattr(gemm, "_tuning_cache", None, raising=False)
-        monkeypatch.setattr(gemm, "_insane_warned", False,
-                            raising=False)
-        return path
+    def paged(mesh):
+        def call(m):
+            devices = numpy.array(jax.devices()[:2])
+            return m.use_paged_kernel(
+                jax.sharding.Mesh(devices, ("model",)) if mesh else None)
+        return call
 
-    def test_poisoned_rows_dropped_at_load_and_file_cleaned(
-            self, cache_file, caplog):
-        import json
-        import logging
+    def experts(rows, width, inner):
+        leaf = jax.ShapeDtypeStruct((4, width, inner), jnp.bfloat16)
+        return lambda m: m.expert_path(rows, {"w_gate": leaf})
 
-        from veles_tpu.ops import gemm
+    return [
+        ("flash-t4096-d128", "attention", True, qk(4096, 128), True),
+        ("flash-t2048", "attention", True, qk(2048, 128), False),
+        ("flash-d64", "attention", True, qk(4096, 64), False),
+        ("flash-cpu", "attention", False, qk(4096, 128), False),
+        ("int8-decode-rows", "quant", True, int8(8, 1024, 3072), True),
+        ("int8-prefill-rows", "quant", True,
+         int8(1024, 1024, 3072), False),
+        ("int8-n-off-512", "quant", True, int8(8, 1024, 1000), False),
+        ("int8-k-off-32", "quant", True, int8(8, 1000, 3072), False),
+        ("int8-cpu", "quant", False, int8(8, 1024, 3072), False),
+        ("paged-tpu", "paged_attention", True, paged(False), True),
+        ("paged-mesh", "paged_attention", True, paged(True), False),
+        ("paged-cpu", "paged_attention", False, paged(False), False),
+        ("experts-decode-rows", "moe", True,
+         experts(256, 2048, 768), "streamed"),
+        ("experts-prefill-rows", "moe", True,
+         experts(1024, 2048, 768), "grouped"),
+        ("experts-off-lane", "moe", True,
+         experts(256, 2048, 200), "grouped"),
+        ("experts-cpu", "moe", False,
+         experts(256, 2048, 768), "grouped"),
+    ]
 
-        # the literal r5 artifact shape: a negative xla_seconds beside
-        # healthy rows
-        poisoned = {
-            "bfloat16:10": {"blocks": [256, 256, 512],
-                            "seconds": 9.4e-05,
-                            "xla_seconds": -0.000107,
-                            "beats_xla": True},
-            "bfloat16:11": {"blocks": [512, 512, 512],
-                            "seconds": 2e-4, "xla_seconds": 3e-4,
-                            "beats_xla": True},
-            "int8:1024x4096": {"use_pallas": True, "block_n": 512,
-                               "seconds": 0.0},
-        }
-        with open(cache_file, "w") as fout:
-            json.dump(poisoned, fout)
-        with caplog.at_level(logging.WARNING, logger="gemm.autotune"):
-            cache = gemm._load_cache()
-        assert set(cache) == {"bfloat16:11"}
-        # the artifact on disk is cleaned too — it stops advertising
-        # the impossible measurement
-        assert set(json.load(open(cache_file))) == {"bfloat16:11"}
-        warnings = [r for r in caplog.records
-                    if "physically impossible" in r.getMessage()]
-        assert len(warnings) == 1  # warn-once
 
-    def test_dropped_bucket_retunes_as_default(self, cache_file):
-        import json
+class TestKernelRules:
+    """Every kernel is chosen by one function in the module that owns
+    it, over the platform, static shapes and the mesh
+    (``ops/platform.py``): nothing else moves the answer."""
 
-        from veles_tpu.ops import gemm
+    @pytest.mark.parametrize("case", _rule_cases(), ids=lambda c: c[0])
+    def test_rule(self, case, monkeypatch):
+        import importlib
 
-        with open(cache_file, "w") as fout:
-            json.dump({"bfloat16:10": {"blocks": [128, 128, 512],
-                                       "seconds": -1.0,
-                                       "beats_xla": True}}, fout)
-        # the poisoned verdict must not engage the kernel...
-        a = jnp.ones((1024, 1024), jnp.bfloat16)
-        assert gemm._tuned_beats_xla(a, a) is False
-        # ...and the block lookup falls back to the defaults
-        assert gemm._tuned_blocks(1024, 1024, 1024, "bfloat16") \
-            == gemm._DEFAULT_BLOCKS
+        _, name, tpu, call, want = case
+        module = importlib.import_module("veles_tpu.ops." + name)
+        monkeypatch.setattr(module, "on_tpu", lambda: tpu)
+        assert call(module) == want
 
-    def test_persist_rejects_insane_rows(self, cache_file):
-        import json
-
-        from veles_tpu.ops import gemm
-
-        gemm._persist_cache({
-            "good": {"blocks": [1, 1, 1], "seconds": 1e-4,
-                     "xla_seconds": 2e-4, "beats_xla": True},
-            "negative": {"blocks": [1, 1, 1], "seconds": -1e-4},
-            "zero": {"blocks": [1, 1, 1], "seconds": 0.0},
-            "nan": {"blocks": [1, 1, 1], "seconds": float("nan")},
-            "inf": {"blocks": [1, 1, 1], "xla_seconds": float("inf")},
-            "not-a-dict": 7,
-        })
-        assert set(json.load(open(cache_file))) == {"good"}
-
-    def test_sane_entry_predicate(self):
-        from veles_tpu.ops import gemm
-
-        assert gemm._sane_entry({"seconds": 1e-5, "xla_seconds": 2e-5})
-        assert gemm._sane_entry({"blocks": [1, 2, 3]})  # no timings
-        assert not gemm._sane_entry({"seconds": -1e-5})
-        assert not gemm._sane_entry({"xla_seconds": 0})
-        assert not gemm._sane_entry({"seconds": True})
-        assert not gemm._sane_entry([1, 2])
 
 
 class TestActivations:
@@ -206,76 +182,3 @@ class TestDataOps:
         numpy.testing.assert_array_equal(ops.reduce_sum(x, 0),
                                          [12.0, 15.0, 18.0, 21.0])
         assert float(ops.reduce_max(x, None)) == 11.0
-
-
-class TestDenseEpilogue:
-    """Fused matmul+bias+activation kernel (the Pallas product consumer)
-    — forward parity in interpret mode, and the custom
-    VJP against jax.grad of the XLA path."""
-
-    def test_pallas_dense_interpret_matches_xla(self):
-        import numpy
-        from veles_tpu.ops.gemm import pallas_dense
-
-        rng = numpy.random.RandomState(0)
-        x = rng.randn(96, 80).astype(numpy.float32)
-        w = rng.randn(80, 64).astype(numpy.float32)
-        b = rng.randn(64).astype(numpy.float32)
-        got = pallas_dense(jnp.asarray(x), jnp.asarray(w),
-                           jnp.asarray(b), activation="tanh",
-                           bm=32, bn=32, bk=16, interpret=True)
-        # the library "tanh" is Znicz's scaled 1.7159*tanh(0.6666x)
-        from veles_tpu.ops import activations as act_lib
-        want = act_lib.ACTIVATIONS["tanh"][0](jnp.asarray(x @ w + b))
-        numpy.testing.assert_allclose(numpy.asarray(got),
-                                      numpy.asarray(want),
-                                      rtol=2e-5, atol=2e-5)
-
-    def test_dense_layer_custom_vjp_matches_xla_grads(self, monkeypatch):
-        import numpy
-        from veles_tpu.core.config import root
-        from veles_tpu.ops import gemm
-
-        rng = numpy.random.RandomState(1)
-        x = jnp.asarray(rng.randn(64, 48).astype(numpy.float32))
-        w = jnp.asarray(rng.randn(48, 32).astype(numpy.float32))
-        b = jnp.asarray(rng.randn(32).astype(numpy.float32))
-
-        # force the pallas path through interpret-mode (CPU) by
-        # monkeypatching eligibility + the kernel call
-        monkeypatch.setattr(gemm, "_pallas_eligible",
-                            lambda a, bb: True)
-        real = gemm.pallas_dense
-
-        def interp(a, bb, bias, activation="linear", **kw):
-            kw.update(bm=32, bn=32, bk=16, interpret=True)
-            return real(a, bb, bias, activation=activation, **kw)
-
-        monkeypatch.setattr(gemm, "pallas_dense", interp)
-        real_mm = gemm.pallas_matmul
-
-        def interp_mm(a, bb, **kw):
-            # the custom bwd's matmuls hit the patched eligibility too
-            kw.update(bm=32, bn=32, bk=16, interpret=True)
-            return real_mm(a, bb, **kw)
-
-        monkeypatch.setattr(gemm, "pallas_matmul", interp_mm)
-        monkeypatch.setattr(root.common.engine, "precision_level", 1,
-                            raising=False)
-        gemm._dense_with_vjp.cache_clear()
-
-        def loss_pallas(x, w, b):
-            return jnp.sum(gemm.dense_layer(x, w, b, activation="tanh",
-                                            use_pallas=True) ** 2)
-
-        def loss_xla(x, w, b):
-            return jnp.sum(gemm.dense_layer(x, w, b, activation="tanh",
-                                            use_pallas=False) ** 2)
-
-        got = jax.grad(loss_pallas, argnums=(0, 1, 2))(x, w, b)
-        want = jax.grad(loss_xla, argnums=(0, 1, 2))(x, w, b)
-        for g, e in zip(got, want):
-            numpy.testing.assert_allclose(numpy.asarray(g),
-                                          numpy.asarray(e),
-                                          rtol=2e-4, atol=2e-4)
-        gemm._dense_with_vjp.cache_clear()

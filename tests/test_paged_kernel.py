@@ -56,46 +56,45 @@ def model():
 
 
 class TestCapabilityProbe:
-    """The ACT doctrine: accelerator codegen behind a probe with a
-    portable fallback — FORCE toggle beats config beats backend auto."""
+    """The rule (``use_paged_kernel``): the platform and the mesh
+    decide; the one seam stands in for the platform, never for the
+    mesh (``tests/test_ops.py::TestKernelRules`` has the rule's own
+    matrix)."""
 
     def test_force_toggle_wins(self):
+        from veles_tpu.parallel.mesh import build_mesh
+        mesh = build_mesh(devices=jax.devices()[:2], data=1, model=2)
         prev = pgatt.FORCE_PAGED_KERNEL
         try:
             pgatt.FORCE_PAGED_KERNEL = True
             assert pgatt.use_paged_kernel() is True
+            # under a mesh the answer is the gather whatever the seam
+            assert pgatt.use_paged_kernel(mesh) is False
             pgatt.FORCE_PAGED_KERNEL = False
             assert pgatt.use_paged_kernel() is False
         finally:
             pgatt.FORCE_PAGED_KERNEL = prev
 
-    def test_config_layer_overrides_backend_auto(self):
-        from veles_tpu.core.config import root
-        prev = root.common.serve.get("paged_kernel", None)
-        try:
-            root.common.serve.paged_kernel = True
-            assert pgatt.use_paged_kernel() is True
-            root.common.serve.paged_kernel = False
-            assert pgatt.use_paged_kernel() is False
-        finally:
-            root.common.serve.paged_kernel = prev
-
     def test_backend_auto_gathers_off_tpu(self):
-        # the CPU test env: auto must fall back to the gather path
+        # the CPU test env: the rule falls back to the gather path
         assert jax.default_backend() == "cpu"
         assert pgatt.use_paged_kernel() is False
 
-    def test_decoder_resolves_probe(self, model):
+    def test_decoder_resolves_probe(self, model, monkeypatch):
+        """``decoder.paged_kernel`` is a fact derived from the rule,
+        not an argument."""
         params, table, heads, _ = model
-        auto = ContinuousDecoder(params, table, heads, slots=2,
-                                 max_len=32, paged=True, page_size=PS)
-        assert auto.paged_kernel is False  # CPU backend auto
-        forced = ContinuousDecoder(params, table, heads, slots=2,
-                                   max_len=32, paged=True,
-                                   page_size=PS, paged_kernel=True)
-        assert forced.paged_kernel is True
+        kw = dict(slots=2, max_len=32, page_size=PS)
+        auto = ContinuousDecoder(params, table, heads, paged=True, **kw)
+        assert auto.paged_kernel is False  # the CPU
+        with pytest.raises(TypeError):
+            ContinuousDecoder(params, table, heads, paged=True,
+                              paged_kernel=True, **kw)
+        monkeypatch.setattr(pgatt, "on_tpu", lambda: True)
+        chip = ContinuousDecoder(params, table, heads, paged=True, **kw)
+        assert chip.paged_kernel is True
         dense = ContinuousDecoder(params, table, heads, slots=2,
-                                  max_len=32, paged_kernel=True)
+                                  max_len=32)
         assert dense.paged_kernel is False  # meaningless without paged
 
 

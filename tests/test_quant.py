@@ -202,10 +202,9 @@ def test_cache_attend_per_row_masks_match_per_row_calls():
 
 
 def test_forced_pallas_that_cannot_run_raises():
-    """An explicit use_pallas=True (or FORCE_PALLAS) on a shape the
-    kernel cannot take used to run the XLA product silently — a forced
-    benchmark then measured XLA against XLA. It raises, naming the
-    knob."""
+    """An explicit use_pallas=True on a shape the kernel cannot take
+    used to run the XLA product silently — a forced comparison then
+    measured XLA against XLA. It raises, naming the argument."""
     import pytest
     from veles_tpu.ops import quant
 
@@ -216,13 +215,9 @@ def test_forced_pallas_that_cannot_run_raises():
         quant.int8_matmul(x, q8, scale, use_pallas=True, interpret=True)
     x = jnp.ones((4, 64), jnp.float32)
     q8 = jnp.ones((64, 100), jnp.int8)          # n has no lane block
-    prev = quant.FORCE_PALLAS
-    quant.FORCE_PALLAS = True
-    try:
-        with pytest.raises(ValueError, match="FORCE_PALLAS"):
-            quant.int8_matmul(x, q8, jnp.ones((100,), jnp.float32))
-    finally:
-        quant.FORCE_PALLAS = prev
+    with pytest.raises(ValueError, match="use_pallas=True"):
+        quant.int8_matmul(x, q8, jnp.ones((100,), jnp.float32),
+                          use_pallas=True, interpret=True)
 
 
 def test_quantize_kv_roundtrip_bound():

@@ -1,9 +1,10 @@
 """Regression-sentinel tests (docs/observability.md): the incremental
 atomic BENCH artifact writer, the any-format loader (including the
-truncated-tail recovery against the REAL committed BENCH_r05
-artifact), the spread-aware comparator, and the CLI exit codes `make
-regress` gates CI on — the seeded-regression fixture here is the proof
-the gate actually exits nonzero."""
+truncated-tail recovery against a real truncated round artifact,
+tests/fixtures/bench_truncated_tail.json), the spread-aware
+comparator, and the CLI exit codes `make regress` gates CI on — the
+seeded-regression fixture here is the proof the gate actually exits
+nonzero."""
 
 import json
 import os
@@ -15,8 +16,8 @@ from veles_tpu.observe.regress import (BenchArtifact, compare,
                                        recover_keys, regressions,
                                        sha256_of, verify_sidecar)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-R05 = os.path.join(REPO, "BENCH_r05.json")
+R05 = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                   "fixtures", "bench_truncated_tail.json")
 
 
 class TestBenchArtifact:
@@ -163,10 +164,10 @@ class TestCompare:
                    for f in findings)
 
     def test_fleet_mapreduce_key_directions(self):
-        """The fleet section's keys (bench.py fleet_section /
-        docs/compiler_fleet.md) compare with the right better-
-        directions: reduce/baseline/step times and wire bytes regress
-        UP, MFU and the in-program speedup regress DOWN."""
+        """The fleet section's keys (docs/compiler_fleet.md) compare
+        with the right better-directions: reduce/baseline/step times
+        and wire bytes regress UP, MFU and the in-program speedup
+        regress DOWN."""
         old = {"fleet_reduce_ms": 10.0, "fleet_reduce_bytes": 1000,
                "fleet_reduce_int8_bytes": 250,
                "fleet_host_baseline_ms": 100.0,
@@ -212,7 +213,7 @@ class TestCompare:
         assert regressions(compare(old, better)) == []
 
     def test_history_key_directions(self):
-        """The metric-history keys (ISSUE 12, bench history_section):
+        """The metric-history keys (ISSUE 12):
         incident_mttd_ms rides the _ms rule (a slower detector
         regressed), the sampler-overhead _ns keys and the
         _anomaly_rate key are LOWER-better too (a pricier or noisier
@@ -231,8 +232,7 @@ class TestCompare:
         assert regressions(compare(old, better)) == []
 
     def test_servescope_key_directions(self):
-        """The serving goodput-observatory keys (bench
-        servescope_section / observe/servescope.py):
+        """The serving goodput-observatory keys (observe/servescope.py):
         serve_goodput_fraction and the occupancy fraction are
         HIGHER-better (less useful work is a regression), every
         *_waste_share key — aggregate and per-cause — regresses UP,
@@ -261,8 +261,8 @@ class TestCompare:
 
     def test_capacity_and_replay_key_directions(self):
         """The traffic record-replay + capacity keys (observe/
-        replay.py, observe/capacity.py, bench replay_section —
-        docs/traffic_replay.md): sustained tokens/sec, the cliff warp
+        replay.py, observe/capacity.py — docs/traffic_replay.md):
+        sustained tokens/sec, the cliff warp
         and round-trip fidelity are HIGHER-better (a config that
         sustains less, cliffs earlier or loses replayed tokens
         regressed); the replayer's schedule skew rides the _ms rule."""
@@ -395,27 +395,3 @@ class TestSentinelCLI:
         BenchArtifact(path).update({"a_tokens_per_sec": 1.0})
         assert observe_main(["regress", path, path]) == 0
 
-
-class TestBenchHooks:
-    def test_spread_warn_flags(self):
-        import bench
-
-        out = {"decode_spread": 0.42, "tight_spread": 0.004,
-               "other_key": 1.0, "flagless_spread_warn": True}
-        warns = bench._spread_warns(out)
-        assert warns == {"decode_spread_warn": True}
-
-    def test_two_length_times_runs_warmup_passes(self):
-        import bench
-
-        calls = {"a": 0, "b": 0}
-
-        def runner(name):
-            def fn():
-                calls[name] += 1
-            return fn
-
-        fns = {("v", 1): runner("a"), ("v", 3): runner("b")}
-        bench._two_length_times(fns, (1, 3), repeats=3, warmup=2)
-        # 2 warmup + 3 timed visits each
-        assert calls == {"a": 5, "b": 5}

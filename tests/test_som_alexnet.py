@@ -1,5 +1,5 @@
-"""Tests for Kohonen SOM, the AlexNet topology, and the autotune CLI
-(SURVEY §7 item 10 + BASELINE conv anchor)."""
+"""Tests for Kohonen SOM and the AlexNet topology (SURVEY §7 item 10 +
+BASELINE conv anchor)."""
 
 import numpy
 import pytest
@@ -124,59 +124,3 @@ class TestAlexNet:
         assert layers[-1]["output_sample_shape"] == 1000
         assert sum(1 for l in layers if l["type"].startswith("conv")) == 5
 
-
-class TestAutotuneCLI:
-    def test_cache_roundtrip(self, tmp_path, monkeypatch):
-        """--autotune persists winners and _tuned_blocks
-        reads them back (devices/device_infos.json semantics)."""
-        from veles_tpu.core.config import root
-        from veles_tpu.ops import gemm
-
-        cache_file = str(tmp_path / "tuning.json")
-        monkeypatch.setattr(root.common.engine, "pallas_autotune_cache",
-                            cache_file, raising=False)
-        monkeypatch.setattr(gemm, "_tuning_cache", None, raising=False)
-        calls = []
-
-        def fake_matmul(a, b, out_dtype=None, bm=None, bn=None, bk=None):
-            calls.append((bm, bn, bk))
-            return jnp.zeros((a.shape[0], b.shape[1]), jnp.float32)
-
-        monkeypatch.setattr(gemm, "pallas_matmul", fake_matmul)
-
-        # deterministic positive timings: the fake kernel is a no-op,
-        # so the real two-length slope would measure pure noise — and
-        # the cache-hygiene gate (rightly) refuses to persist a
-        # noise-negative "measurement"
-        def fake_scan_time(product, a, lengths=(50, 350), repeats=4):
-            product(a)  # exercise the candidate (records its blocks)
-            return 1e-4
-
-        monkeypatch.setattr(gemm, "_matmul_scan_time", fake_scan_time)
-        blocks = gemm.autotune_matmul(512, 512, 1024, iters=1)
-        assert calls, "no candidates benchmarked"
-        assert blocks in [c for c in calls]
-        # cache round-trips through a fresh load
-        monkeypatch.setattr(gemm, "_tuning_cache", None, raising=False)
-        assert gemm._tuned_blocks(512, 512, 1024, "bfloat16") == blocks
-
-    def test_cli_entry(self, tmp_path, monkeypatch, capsys):
-        from veles_tpu.core.config import root
-        from veles_tpu.ops import gemm
-
-        monkeypatch.setattr(root.common.engine, "pallas_autotune_cache",
-                            str(tmp_path / "t.json"), raising=False)
-        monkeypatch.setattr(gemm, "_tuning_cache", None, raising=False)
-        monkeypatch.setattr(
-            gemm, "pallas_matmul",
-            lambda a, b, **kw: jnp.zeros((a.shape[0], b.shape[1]),
-                                         jnp.float32))
-        # positive stub timing: see test_cache_roundtrip — a no-op
-        # kernel's measured slope is noise the hygiene gate rejects
-        monkeypatch.setattr(
-            gemm, "_matmul_scan_time",
-            lambda product, a, lengths=(50, 350), repeats=4:
-            (product(a), 1e-4)[1])
-        assert gemm.autotune_main(["512x512x1024"]) == 0
-        out = capsys.readouterr().out
-        assert '"shape": [512, 512, 1024]' in out

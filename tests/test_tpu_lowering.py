@@ -38,7 +38,7 @@ def _sds(shape, dtype):
 
 def kernel_cases():
     """[(name, fn, abstract args)] — every kernel behind a TPU gate."""
-    from veles_tpu.ops import attention, gemm, paged_attention, quant
+    from veles_tpu.ops import attention, paged_attention, quant
 
     table = _sds((SLOTS, PAGES_PER_SLOT), "int32")
     lengths = _sds((SLOTS,), "int32")
@@ -68,25 +68,15 @@ def kernel_cases():
             lambda x, q8, s: quant.int8_matmul(x, q8, s, use_pallas=True),
             (_sds((8, 1024), "bfloat16"), _sds((1024, n), "int8"),
              _sds((n,), "float32"))))
-    a = _sds((512, 1024), "bfloat16")
-    b = _sds((1024, 512), "bfloat16")
-    cases.append(("pallas_matmul", lambda a, b: gemm.pallas_matmul(a, b),
-                  (a, b)))
-    cases.append(("pallas_dense",
-                  lambda a, b, bias: gemm.pallas_dense(
-                      a, b, bias, activation="tanh"),
-                  (a, b, _sds((512,), "float32"))))
-    # flash attention exactly at the _use_pallas_flash gate (T >= 4096,
-    # head_dim % 128 == 0); FORCE_FLASH stands in for the platform probe
+    # flash attention exactly at the use_flash rule's edge (T >= 4096,
+    # head_dim % 128 == 0); the tracing process is on the CPU, so the
+    # rule's platform is steered for the length of the trace
     qkv = _sds((1, 4096, 2, 128), "bfloat16")
 
     def flash(q, k, v):
-        prev = attention.FORCE_FLASH
-        attention.FORCE_FLASH = True
-        try:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(attention, "on_tpu", lambda: True)
             return attention.attention(q, k, v, causal=True)
-        finally:
-            attention.FORCE_FLASH = prev
 
     cases.append(("flash_attention_t4096_d128", flash, (qkv, qkv, qkv)))
     # the routed experts' streaming kernel at the published shapes of

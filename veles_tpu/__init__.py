@@ -3,7 +3,8 @@
 A from-scratch re-design of the capabilities of Samsung VELES
 (``gujunli/veles``) for TPUs: models are Workflows — directed graphs of Units
 linked by control and data edges — whose accelerated segments compile into
-fused XLA computations via JAX (jit/pjit), with Pallas kernels for hot ops,
+fused XLA computations via JAX (jit/pjit), with a Pallas kernel where a
+rule over the platform and the shapes picks one (``ops/platform.py``),
 data/tensor/sequence parallelism over a ``jax.sharding.Mesh`` (ICI
 collectives), an elastic host-orchestrated fleet mode over TCP (DCN),
 whole-workflow snapshot/resume, plotting/web-status/REST services, genetic

@@ -190,15 +190,6 @@ class Main(Logger):
                            metavar="N", help="positions per KV page "
                            "(default SLOT_SPAN_TILE=128; must be a "
                            "multiple of the span tile on TPU)")
-        serve.add_argument("--serve-paged-kernel", default=None,
-                           metavar="on|off",
-                           type=lambda s: s.strip().lower() not in
-                           ("off", "0", "false", "no"),
-                           help="force the fused Pallas paged-"
-                           "attention kernel tier on or off for the "
-                           "paged slot engine (default: auto — kernel "
-                           "on TPU, page-table gather elsewhere; "
-                           "docs/paged_kv.md)")
         serve.add_argument("--serve-aot", default=None, metavar="PATH",
                            help="boot GenerateAPI from an AOT "
                            "compiled-program bundle (veles_tpu aot "
@@ -602,8 +593,6 @@ class Main(Logger):
                 ("serve_paged", root.common.serve, "paged"),
                 ("serve_page_size", root.common.serve, "page_size"),
                 ("serve_pool_pages", root.common.serve, "pool_pages"),
-                ("serve_paged_kernel", root.common.serve,
-                 "paged_kernel"),
                 ("serve_aot", root.common.serve, "aot"),
                 ("serve_slo", root.common.observe, "slo"),
                 ("serve_governor", root.common.serve, "governor"),
@@ -701,9 +690,6 @@ def main(argv=None):
     if argv and argv[0] == "forge":
         from veles_tpu.forge.client import main as forge_main
         return forge_main(argv[1:])
-    if argv and argv[0] == "autotune":
-        from veles_tpu.ops.gemm import autotune_main
-        return autotune_main(argv[1:])
     if argv and argv[0] == "parity":
         from veles_tpu.parity import main as parity_main
         return parity_main(argv[1:])

@@ -41,7 +41,7 @@ import threading
 logger = logging.getLogger("aot.ExecCache")
 
 #: bump when the entry payload layout changes (part of every key)
-CACHE_SCHEMA = 1
+CACHE_SCHEMA = 2
 
 #: entry filename suffix (content-addressed: ``<key>.xc``)
 ENTRY_SUFFIX = ".xc"
@@ -172,7 +172,13 @@ class ExecutableCache:
         """The deserialized executable for ``key``, or None (miss /
         torn entry — the caller falls back to live compilation). A
         torn or tampered entry warns ONCE and is unlinked so the next
-        live compile repairs it."""
+        live compile repairs it. The executable is loaded onto the
+        devices it was compiled for (their ids ride beside the
+        payload): left to its default, ``deserialize_and_load`` takes
+        EVERY local device, and a one-device program then refuses its
+        arguments on a host with more. An entry naming a device this
+        process lacks is a plain miss."""
+        import jax
         from jax.experimental.serialize_executable import \
             deserialize_and_load
 
@@ -189,8 +195,14 @@ class ExecutableCache:
                 raise ValueError(
                     "sha256 mismatch against sidecar %s" % sidecar)
             with open(path, "rb") as fin:
-                payload, in_tree, out_tree = pickle.load(fin)
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+                payload, in_tree, out_tree, device_ids = pickle.load(fin)
+            local = {device.id: device for device in jax.local_devices()}
+            if not all(i in local for i in device_ids):
+                self._count("misses")
+                return None
+            compiled = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[local[i] for i in device_ids])
         except Exception as exc:
             # torn write, missing sidecar, bit rot, or a pickle from a
             # different jax than the key promised: refuse LOUDLY
@@ -221,7 +233,9 @@ class ExecutableCache:
         from jax.experimental.serialize_executable import serialize
 
         try:
-            triple = serialize(compiled)
+            entry = serialize(compiled) + (
+                [device.id for device in
+                 compiled.runtime_executable().local_devices()],)
         except Exception as exc:
             _warn_once(
                 ("serialize", self.directory, type(exc).__name__),
@@ -237,7 +251,7 @@ class ExecutableCache:
             os.makedirs(self.directory, exist_ok=True)
             with open(tmp, "wb") as raw:
                 tee = _HashingWriter(raw)
-                pickle.dump(triple, tee,
+                pickle.dump(entry, tee,
                             protocol=pickle.HIGHEST_PROTOCOL)
             sidecar_tmp = tmp + ".sha256"
             with open(sidecar_tmp, "w") as fout:

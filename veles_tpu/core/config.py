@@ -7,7 +7,7 @@ attribute access so workflow config files can write ``root.mnist.learning_rate
 ``protect()``-ed read-only keys, layered site overrides, and pretty printing.
 
 Unlike the reference, engine defaults here describe the XLA/TPU engine
-(precision/dtype policy, pallas autotune cache, mesh defaults) instead of
+(precision/dtype policy, mesh defaults) instead of
 OpenCL/CUDA block sizes.
 """
 
@@ -155,21 +155,6 @@ root.common.update({
         # 2 - highest XLA precision (multi-partial tier).
         "precision_level": 0,
         "donate_params": True,
-        # pallas kernel toggles — OFF by default on the train path:
-        # measured on the v5e flagship dense step (fwd+bwd+update,
-        # mb 4096), XLA's dot + its own fusion beats the blocked Pallas
-        # matmul 2.1x and the fused-epilogue kernel 1.8x (numbers in
-        # docs/performance.md "Pallas + autotune"). The kernels remain
-        # the opt-in substrate (autotune cache, custom epilogues,
-        # forward-only tall-skinny shapes where pallas_dense measured
-        # 2.6x FASTER than XLA).
-        "use_pallas": False,
-        # fused matmul+bias+activation kernel on the product dense path
-        # (ops/gemm.py dense_layer); measured vs XLA's own epilogue
-        # fusion in docs/performance.md
-        "pallas_epilogue": False,
-        "pallas_autotune_cache": os.path.join(
-            _home, "cache", "pallas_tuning.json"),
     },
     "mesh": {
         # logical mesh axes; sizes resolve against the actual device
@@ -195,10 +180,6 @@ root.common.update({
         "deadline": 300.0,
         "rebuild_backoff": 0.5,
         "rebuild_backoff_max": 30.0,
-        # fused paged-attention tier (ops/paged_attention.py): None =
-        # backend auto (kernel on TPU, page-table gather elsewhere);
-        # True/False force (--serve-paged-kernel)
-        "paged_kernel": None,
     },
     "fleet": {
         "job_timeout": 120.0,

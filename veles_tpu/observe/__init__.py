@@ -12,8 +12,8 @@ Three coordinated parts (docs/observability.md):
   the EventRecorder, propagated by the ``X-Veles-Trace`` serving
   header and the fleet frames' ``trace`` field; exported to Chrome
   trace JSON by ``veles_tpu observe export-trace``;
-- :mod:`veles_tpu.observe.profile` — ``--profile-dir`` windows around
-  bench/serving with span-named ``jax.profiler.TraceAnnotation``s;
+- :mod:`veles_tpu.observe.profile` — ``--profile`` windows around
+  a run with span-named ``jax.profiler.TraceAnnotation``s;
 - :mod:`veles_tpu.observe.xla_stats` — device truth: XLA compile/cache
   counters with recompilation-storm detection, per-device memory
   gauges, online MFU from ``cost_analysis`` FLOPs;

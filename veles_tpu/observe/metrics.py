@@ -309,12 +309,12 @@ class MetricsRegistry:
         for fn in dead:
             self.remove_collector(fn)
 
-    # -- summaries (bench / BENCH json consumers) -------------------------
+    # -- summaries (plain-dict consumers) ---------------------------------
     def histogram_summary(self, prefix=""):
         """Histogram families (optionally name-prefixed) as plain dicts:
         ``{name: {labels: {"count", "sum", "buckets": {le: n}}}}`` — the
-        BENCH-json-friendly view ``bench.py --serve`` persists so the
-        perf trajectory carries host-overhead attribution."""
+        JSON-friendly view a measuring process can persist so a perf
+        record carries host-overhead attribution."""
         self._run_collectors()
         out = {}
         with self._lock:

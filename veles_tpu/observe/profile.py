@@ -1,6 +1,6 @@
 """Opt-in jax.profiler integration: device traces aligned with spans.
 
-``--profile-dir`` (bench.py / the CLI's ``--profile``) wraps a run
+The CLI's ``--profile`` wraps a run
 window in ``jax.profiler.trace``; while a capture is active the tracer
 also enters a ``jax.profiler.TraceAnnotation`` named after each span
 (``Span.__enter__``), so the host-side span timeline and the XLA device

@@ -1,11 +1,14 @@
 """Artifact-proof bench sentinel: incremental atomic writes + regression gate.
 
-The complaint on record (BENCH_r05): the round's BENCH artifact lost its
+The complaint on record (round 5's artifact, now
+tests/fixtures/bench_truncated_tail.json): a BENCH artifact lost its
 headline keys to tail truncation — a number that cannot be re-read from
 the artifact was never really measured. Two halves fix that:
 
-- **writer** (:class:`BenchArtifact`): ``bench.py`` streams each
-  section's keys into a schema-versioned JSON as they are computed —
+- **writer** (:class:`BenchArtifact`): a measuring process streams each
+  section's keys into a schema-versioned JSON as they are computed
+  (the root bench script that did so is retired; nothing in the tree
+  writes one today) —
   every write is temp + ``os.replace`` (a torn process never leaves a
   half-file) with a SHA-256 sidecar, and the doc carries the device
   fingerprint and git sha, so a BENCH json is self-identifying and
@@ -52,32 +55,30 @@ SCHEMA_VERSION = 1
 #: "_hit_fraction" is the paged admission ratio (hit admit wall over
 #: cold prefill wall — a cache that stops saving work regressed) and
 #: "_flatness" the paged step-time max/min across the length sweep
-#: (docs/paged_kv.md; decode_paged in bench.py).
-#: "_compiles" covers the AOT cold-start section (bench.py
-#: coldstart_section): coldstart_compiles counts live XLA compiles
+#: (docs/paged_kv.md).
+#: "_compiles" covers the AOT cold-start keys: coldstart_compiles counts live XLA compiles
 #: booked against decode programs during an AOT-booted warmup — its
 #: flat-zero value IS the zero-retrace proof, so any growth regressed;
 #: coldstart_*_ms keys ride the "_ms" rule (docs/aot_artifacts.md).
 #: The request-truth observability keys (observe/reqledger.py +
-#: observe/slo.py): bench's per-request decode_continuous_ttft_p50/
+#: observe/slo.py): the per-request decode_continuous_ttft_p50/
 #: p95/p99_ms and decode_continuous_tpot_p95_ms ride the "_ms" rule
 #: (latency percentiles regress UP); "burn_rate" covers any exported
 #: SLO burn-rate key (veles_slo_burn_rate snapshots in artifacts) —
 #: burning MORE error budget is always a regression.
-#: The fleet mapreduce section's directions (bench.py fleet_section):
+#: The fleet mapreduce keys' directions (docs/compiler_fleet.md):
 #: fleet_reduce*_ms / fleet_host_baseline_ms / fleet_step_ms regress
 #: UP via "_ms"; fleet_reduce*_bytes regress UP via "_bytes";
 #: fleet_step_mfu and fleet_inprogram_speedup use the higher-is-better
 #: default (and "_mfu"/"_speedup" carry spread siblings below).
-#: The serving-governor keys (observe/governor.py, bench governor
-#: section): governor_demote_to_recover_ms rides the "_ms" rule (a
+#: The serving-governor keys (observe/governor.py):
+#: governor_demote_to_recover_ms rides the "_ms" rule (a
 #: slower fault->demote->recover loop regressed); "_transitions"
 #: regresses UP (more ladder moves for the same seeded fault profile
 #: is oscillation — the hysteresis got worse); the per-tier
 #: governor_*_attainment keys use the higher-is-better default (SLO
 #: attainment dropping at a tier is a regression).
-#: The metric-history keys (observe/history.py, bench history
-#: section): incident_mttd_ms (fault injection -> anomaly firing, the
+#: The metric-history keys (observe/history.py): incident_mttd_ms (fault injection -> anomaly firing, the
 #: mean-time-to-detect of the seeded chaos profile) rides the "_ms"
 #: rule — a slower detector regressed; "_ns" covers the sampler
 #: overhead keys (history_sample_on_ns / history_sample_off_ns:
@@ -86,8 +87,8 @@ SCHEMA_VERSION = 1
 #: regression); "_anomaly_rate" regresses UP (more rule firings for
 #: the same seeded fault profile means the rules got noisier, the
 #: detector equivalent of governor oscillation).
-#: The fleet goodput-observatory keys (observe/fleetscope.py, bench
-#: fleetscope_section): fleet_goodput_fraction uses the
+#: The fleet goodput-observatory keys (observe/fleetscope.py):
+#: fleet_goodput_fraction uses the
 #: higher-is-better default (less of the fleet's wall time doing
 #: useful compute is a regression — the bare "_fraction" suffix is
 #: deliberately NOT lower-better; only _hit_fraction /
@@ -95,8 +96,8 @@ SCHEMA_VERSION = 1
 #: slower straggler detector regressed) and
 #: fleet_span_ship_overhead_ns rides "_ns" (the span ring growing its
 #: record-path tax is a regression).
-#: The serving goodput-observatory keys (observe/servescope.py, bench
-#: servescope_section): serve_goodput_fraction and the occupancy
+#: The serving goodput-observatory keys (observe/servescope.py):
+#: serve_goodput_fraction and the occupancy
 #: fraction use the higher-is-better default (less of the dispatched
 #: work being useful — or fewer lane-steps carrying a live request —
 #: is a regression; the bare "_fraction" stays higher-better, the
@@ -110,8 +111,8 @@ SCHEMA_VERSION = 1
 #: at 0 — any shed across the swap window breaks the zero-downtime
 #: contract, enforced as a hard assert in tests/test_deploy.py since
 #: a 0 baseline passes the ratio gate vacuously).
-#: The fused paged-attention kernel keys (ops/paged_attention.py,
-#: bench decode_paged_kernel): the per-length
+#: The fused paged-attention kernel keys (ops/paged_attention.py):
+#: the per-length
 #: decode_paged_kernel_step_len<L>_ms and the mixed-occupancy
 #: decode_paged_{kernel,gather}_step_mixed_ms ride "_ms";
 #: decode_paged_kernel_step_flatness rides "_flatness" (the kernel's
@@ -121,8 +122,7 @@ SCHEMA_VERSION = 1
 #: uses the higher-is-better default via "_speedup", so the
 #: kernel-vs-gather win is itself regress-gated.
 #: The traffic record-replay + capacity keys (observe/replay.py,
-#: observe/capacity.py, bench replay_section —
-#: docs/traffic_replay.md): capacity_sustained_tokens_per_sec (what
+#: observe/capacity.py — docs/traffic_replay.md): capacity_sustained_tokens_per_sec (what
 #: the config sustains at the recorded mix before an SLO breach) and
 #: capacity_cliff_warp_x (the warp factor where the cliff sits) use
 #: the higher-is-better default — a PR that silently costs 15% of
@@ -134,8 +134,7 @@ SCHEMA_VERSION = 1
 #: round trip) uses the higher-is-better default — trace round-trip
 #: fidelity decaying is a recorder or replayer bug, gated like any
 #: throughput loss.
-#: The memscope keys (observe/memscope.py, bench memscope_section —
-#: docs/memscope.md): the per-owner hbm_owner_*_bytes keys ride
+#: The memscope keys (observe/memscope.py — docs/memscope.md): the per-owner hbm_owner_*_bytes keys ride
 #: "_bytes" (an owner's footprint quietly growing at fixed geometry is
 #: a regression — the whole point of attribution is making that
 #: visible per cause); "_untagged_fraction" regresses UP and needs its
@@ -339,7 +338,7 @@ def load_bench(path):
                 return line, info
         except ValueError:
             pass
-        # the BENCH_r05 case: the tail lost its head — salvage the
+        # the round-5 artifact's case: the tail lost its head — salvage the
         # complete pairs instead of declaring the round unmeasured
         info["recovered"] = True
         return recover_keys(tail), info

@@ -42,8 +42,8 @@ from collections import deque
 #: MXU's native precision and the honest MFU ceiling — keyed by the
 #: EXACT ``device_kind`` string JAX reports, each row with its source.
 #: Exact keys on purpose: substring matching let "TPU v5" (the v5p)
-#: claim any unknown v5 kind. The bench (``bench.py``) and the online
-#: MFU gauge share THIS one table.
+#: claim any unknown v5 kind. Every offline reader of a peak and the
+#: online MFU gauge share THIS one table.
 PEAK_BF16_TFLOPS = {
     "TPU v4 lite": (138.0, "Jouppi et al. 2021, 'Ten Lessons' (TPUv4i)"),
     "TPU v4": (275.0, "Google Cloud documentation, 'TPU v4'"),

@@ -34,7 +34,7 @@ def attention(q, k, v, causal=False, scale=None):
     if v.shape[-1] < q.shape[-1]:
         wide = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - v.shape[-1])])
         return attention(q, k, wide, causal, scale)[..., :v.shape[-1]]
-    if _use_pallas_flash(q, k):
+    if use_flash(q, k):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention)
         # pallas kernel wants (B, H, T, D)
@@ -46,23 +46,18 @@ def attention(q, k, v, causal=False, scale=None):
         q, k, v, scale=scale, is_causal=causal)
 
 
-#: None = auto (the measured >=4096 gate); True/False pin the flash
-#: kernel for every call — the bench's interleaved on/off comparison
-FORCE_FLASH = None
+def use_flash(q, k):
+    """Whether ``attention`` takes the Pallas flash kernel: on the TPU,
+    from 4096 positions on each side, head_dim a multiple of 128.
 
-
-def _use_pallas_flash(q, k):
-    if FORCE_FLASH is not None:
-        return FORCE_FLASH
-    if not on_tpu():
-        return False
-    # MEASURED crossover on the v5e (two-length device timing, causal,
-    # hd=128): XLA's attention wins below ~4k sequence (0.08 vs
-    # 0.34 ms at S=512, 1.38 vs 1.74 ms at S=2048); the flash kernel
-    # takes over once the S x S score materialization dominates
-    # (1.06x at S=4096, 1.21x at S=8192). It also tiles (T, D) onto
-    # (128, 128) MXU blocks, so head_dim must divide 128.
-    return (q.shape[1] >= 4096 and k.shape[1] >= 4096
+    The crossover was measured on the v5e before this round (two-length
+    device timing, causal, hd=128; no ledger row yet): XLA's attention
+    wins below ~4k sequence (0.08 vs 0.34 ms at S=512, 1.38 vs 1.74 ms
+    at S=2048); the flash kernel takes over once the S x S score
+    materialization dominates (1.06x at S=4096, 1.21x at S=8192). It
+    also tiles (T, D) onto (128, 128) MXU blocks, so head_dim must
+    divide 128."""
+    return (on_tpu() and q.shape[1] >= 4096 and k.shape[1] >= 4096
             and q.shape[-1] % 128 == 0)
 
 

@@ -25,14 +25,12 @@ capability probe with a portable fallback):
   slot's live count are SKIPPED (``pl.when`` — the copy of scratch
   page 0 still streams, but zero FLOPs run), so attended work scales
   with each slot's live tokens, not the padded max-span.
-- :func:`use_paged_kernel` — the capability probe
-  (``root.common.serve.paged_kernel`` / ``--serve-paged-kernel``;
-  ``None`` = auto: the TPU, single chip). Everywhere else — the CPU,
-  and any serve mesh — the established gather path runs unchanged: it
-  IS the CPU bit-identity contract (tests/test_paged.py), and
-  interpret mode executes these kernels on CPU to prove the kernel
-  path's token streams match it (tests/test_paged_kernel.py, marked
-  ``slow``).
+- :func:`use_paged_kernel` — the rule: the kernel on the TPU with no
+  serve mesh. Everywhere else — the CPU, and any serve mesh — the
+  established gather path runs unchanged: it IS the CPU bit-identity
+  contract (tests/test_paged.py), and interpret mode executes these
+  kernels on CPU to prove the kernel path's token streams match it
+  (tests/test_paged_kernel.py, marked ``slow``).
 
 Formulation (what Mosaic takes, libtpu 0.0.34): decode attention with
 equal Q and KV heads is one matrix-VECTOR product per head, so the
@@ -68,45 +66,33 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from veles_tpu.core.config import root
 from veles_tpu.ops.platform import on_tpu, pallas_interpret
 
-#: None = auto (config, then platform probe); True/False pin the kernel
-#: on/off for every paged dispatch — the test seam and emergency
-#: opt-out. Flipping it does NOT invalidate already-traced programs:
-#: the probe is read at TRACE time inside ``_paged_slot_step``, so
-#: tests toggling it must ``jax.clear_caches()``.
+#: Test seam: None = the rule; True/False stand in for the platform so
+#: the interpret-mode tests engage the kernel on the CPU (and pin the
+#: gather beside it). Flipping it does NOT invalidate already-traced
+#: programs: the rule is read at TRACE time inside
+#: ``_paged_slot_step``, so tests toggling it must
+#: ``jax.clear_caches()``.
 FORCE_PAGED_KERNEL = None
 
 
 def use_paged_kernel(mesh=None):
-    """The capability probe: should paged dispatches run the fused
-    kernel? Resolution order — :data:`FORCE_PAGED_KERNEL` (the test /
-    emergency seam), then ``root.common.serve.paged_kernel``
-    (``--serve-paged-kernel``), then auto: the TPU only (the gather
-    path is the portable fallback AND the CPU bit-identity reference).
-    Read at trace time by ``kv_pool._paged_slot_step`` — no jitted
-    signature carries it, so the AOT facade and the ``paged.*``
-    instrument names extend unchanged.
+    """Whether paged dispatches run the fused kernel: on the TPU with
+    no serve ``mesh`` (the gather path is the portable fallback AND the
+    CPU bit-identity reference). Read at trace time by
+    ``kv_pool._paged_slot_step`` — no jitted signature carries it, so
+    the AOT facade and the ``paged.*`` instrument names extend
+    unchanged.
 
-    Under a serve ``mesh`` the answer is the gather by rule, not by
-    probe: the sharded paged programs are plain GSPMD jits, and a bare
+    Under a serve ``mesh`` the answer is the gather whatever the seam
+    says: the sharded paged programs are plain GSPMD jits, and a bare
     ``pallas_call`` is a custom call XLA cannot partition over the
-    head-sharded pool. Forcing the kernel on there raises, naming the
-    knob — it is decided here, never by catching the dispatch."""
-    forced = FORCE_PAGED_KERNEL
-    if forced is None:
-        forced = root.common.serve.get("paged_kernel", None)
+    head-sharded pool."""
     if mesh is not None:
-        if forced:
-            raise ValueError(
-                "the fused paged-attention kernel cannot run under a "
-                "serve mesh (GSPMD cannot partition the pallas_call "
-                "over the head-sharded pool); drop paged_kernel / "
-                "--serve-paged-kernel on, or serve single-chip")
         return False
-    if forced is not None:
-        return bool(forced)
+    if FORCE_PAGED_KERNEL is not None:
+        return FORCE_PAGED_KERNEL
     return on_tpu()
 
 

@@ -1,11 +1,14 @@
 """The one place that asks JAX which platform this process runs on.
 
-Every kernel gate (``ops/gemm``, ``ops/quant``, ``ops/attention``,
-``ops/paged_attention``) and the serving bucket policy decide from
-these two functions, so "is this the chip?" has one answer and one
-spelling. Two platforms exist for this code: ``tpu`` (Pallas kernels
-compile through Mosaic) and ``cpu`` (tests and drives; Pallas kernels
-run in interpret mode there and only there).
+The rule for choosing a kernel: the module that owns a kernel has one
+function that returns the choice, read off ``on_tpu()``, static shapes
+and the sharding or mesh — ``ops/moe.expert_path``,
+``ops/quant.use_int8_kernel``, ``ops/attention.use_flash``,
+``ops/paged_attention.use_paged_kernel``. No config key, flag,
+argument or file decides; a test that wants the other side patches
+that module's ``on_tpu``. Two platforms exist for this code: ``tpu``
+(Pallas kernels compile through Mosaic) and ``cpu`` (tests and drives;
+Pallas kernels run in interpret mode there and only there).
 """
 
 import jax

@@ -7,16 +7,14 @@ weights live in HBM as int8 with one f32 scale per output channel, and
 the Pallas kernel dequantizes inside the matvec — the bf16/f32 weights
 never exist in HBM at all.
 
-Measured on TPU v5e (two-length scan timing, m=8 decode rows): the
-kernel beats XLA's fused-convert dot 6x on the qkv projection shape
-(k1024 x n3072 — XLA handles the non-power-of-two N badly) and ~1.3x
-on the 32k vocab head, and ties within noise on the square shapes —
-WHEN the in-kernel dequant matches the activation dtype (bf16 serving)
-and the lane block suits the shape. Those two knobs are what this
-module tunes; the decision persists in the same autotune cache as the
-Pallas GEMM blocks (``ops/gemm.py`` — the ``device_infos.json``
-descendant, reference ``backends.py:623-731``), and the runtime gate
-auto-engages the kernel only where it measured faster (the
+Measured on TPU v5e before this round (two-length scan timing, m=8
+decode rows; no ledger row yet): the kernel beats XLA's fused-convert
+dot 6x on the qkv projection shape (k1024 x n3072 — XLA handles the
+non-power-of-two N badly) and ~1.3x on the 32k vocab head, and ties
+within noise on the square shapes — WHEN the in-kernel dequant matches
+the activation dtype (bf16 serving) and the lane block suits the
+shape. ``use_int8_kernel`` is the rule read off that sweep: the
+platform and the static shapes decide, nothing else (the
 flash-attention >=4096 doctrine).
 
 Quantization scheme: symmetric per-output-channel absmax
@@ -44,12 +42,6 @@ PALLAS_MAX_ROWS = 256
 
 #: lane-block candidates per grid step (N must divide by the choice)
 BLOCK_N_CANDIDATES = (2048, 1024, 512)
-
-#: None = auto (tuned decision); True/False pin the kernel on/off for
-#: every auto-gated call — the bench's interleaved on/off comparison
-#: and emergency opt-out knob
-FORCE_PALLAS = None
-
 
 def quantize_int8(w):
     """Symmetric per-output-channel int8 quantization of ``w`` (K, N):
@@ -94,31 +86,22 @@ def _pallas_int8_matmul(x, q, scale, block_n, interpret=False):
 
 
 def _default_block_n(k, n):
-    """Lane block when the shape has no tuned cache entry. From the
-    v5e sweep: the 32k vocab head wants 2048; mid-width projections
-    want 1024; 512 is the floor that still always fits VMEM."""
+    """Lane block for the shape. From the v5e sweep: the 32k vocab
+    head wants 2048; mid-width projections want 1024; 512 is the floor
+    that still always fits VMEM."""
     for candidate in BLOCK_N_CANDIDATES:
         if n % candidate == 0 and (candidate < 2048 or n >= 16384):
             return candidate
     return 512 if n % 512 == 0 else None
 
 
-def _tuned_decision(m, k, n):
-    """(use_pallas, block_n) for this shape — the persisted autotune
-    verdict when one exists, else the measured-defaults heuristic.
-    The decode-regime row bound applies EITHER way: tuned entries are
-    measured at decode m, and a prefill/training call (m up to B x T)
-    would blow the kernel's whole-x VMEM block."""
-    if m > PALLAS_MAX_ROWS:
-        return False, None
-    from veles_tpu.ops import gemm
-
-    entry = gemm._load_cache().get("int8:%dx%d" % (k, n))
-    if entry:
-        return bool(entry.get("use_pallas")), entry.get("block_n")
-    block_n = _default_block_n(k, n)
-    ok = block_n is not None and k % 32 == 0
-    return ok, block_n
+def use_int8_kernel(m, k, n):
+    """Whether ``int8_matmul`` of (m, k) x (k, n) takes the Pallas
+    kernel: on the TPU, in the decode regime (a prefill/training call,
+    m up to B x T, would blow the kernel's whole-x VMEM block and is
+    MXU-bound, where XLA wins), at shapes the kernel tiles."""
+    return (on_tpu() and m <= PALLAS_MAX_ROWS and k % 32 == 0
+            and _default_block_n(k, n) is not None)
 
 
 def int8_matmul(x, q, scale, use_pallas=None, interpret=False):
@@ -126,31 +109,23 @@ def int8_matmul(x, q, scale, use_pallas=None, interpret=False):
     product. ``x`` (M, K) float; ``q`` (K, N) int8; ``scale`` (N,) f32.
     Returns (M, N) in ``x``'s dtype.
 
-    ``use_pallas=None`` auto-engages the Pallas kernel on TPU in the
-    decode regime per the tuned decision (persisted by
-    ``autotune_int8`` / heuristic defaults) — the measured-win gate.
-    Everywhere else the XLA formulation runs: dequant-to-x.dtype
-    feeding dot_general (prefill/training sizes are MXU-bound, where
-    XLA wins)."""
+    ``use_pallas=None`` asks ``use_int8_kernel``; True/False are how
+    the tests compare the kernel with the XLA formulation
+    (dequant-to-x.dtype feeding dot_general)."""
     m, k = x.shape
     n = q.shape[1]
-    block_n = None
-    if use_pallas is None and FORCE_PALLAS is not None:
-        use_pallas = FORCE_PALLAS
     if use_pallas is None:
-        use_pallas, block_n = (_tuned_decision(m, k, n) if on_tpu()
-                               else (False, None))
+        use_pallas = use_int8_kernel(m, k, n)
     if use_pallas:
-        if block_n is None:
-            block_n = _default_block_n(k, n)
+        block_n = _default_block_n(k, n)
         if block_n is None or k % 32:
-            # only an EXPLICIT request reaches here (the auto decision
-            # already checked both): running the XLA product under a
-            # forced-on flag would measure XLA against XLA
+            # only an EXPLICIT request reaches here (the rule already
+            # checked both): running the XLA product under a forced-on
+            # flag would measure XLA against XLA
             raise ValueError(
-                "int8_matmul: use_pallas=True / FORCE_PALLAS cannot be "
-                "honoured for k=%d, n=%d (the kernel needs k %% 32 == 0 "
-                "and n %% 512 == 0); drop the force or pad the shape"
+                "int8_matmul: use_pallas=True cannot be honoured for "
+                "k=%d, n=%d (the kernel needs k %% 32 == 0 and "
+                "n %% 512 == 0); drop the force or pad the shape"
                 % (k, n))
         out = _pallas_int8_matmul(x, q, scale, block_n,
                                   interpret=interpret)
@@ -178,7 +153,7 @@ def int8_cache_attend(q, k_q, k_scale, v_q, v_scale, mask_addend,
     int8 payloads narrow all the way into the dots (the positions-major
     layouts were what forced the materialized bf16 widening). The
     per-batch Pallas twin that used to sit beside it lost to this on
-    record (``decode_int8kv_pallas_speedup`` 0.977, BENCH_r05) and its
+    the pre-round record (speedup 0.977) and its
     per-row ``(1, T)`` mask block broke Mosaic's (8, 128) rule, so it
     was deleted rather than left forceable; the paged engine's fused
     kernel is ``ops/paged_attention.paged_attend_int8``."""
@@ -215,56 +190,3 @@ def matmul_any(x, w):
         y = int8_matmul(x.reshape(-1, x.shape[-1]), w["q8"], w["scale"])
         return y.reshape(lead + (w["q8"].shape[1],))
     return x @ w
-
-
-def autotune_int8(m, k, n, dtype=jnp.bfloat16, repeats=4):
-    """Measure XLA vs the Pallas kernel over the lane-block candidates
-    for one (m, k, n) matvec on the current device, persist the winner
-    in the shared tuning cache, and return the decision dict.
-
-    Timing: a length-L ``lax.scan`` of the product at two L values —
-    the difference cancels dispatch and transfer constants (the same
-    protocol as ``bench.py``)."""
-    import numpy
-    from veles_tpu.ops import gemm
-
-    rng = numpy.random.RandomState(0)
-    x = jnp.asarray(rng.randn(m, k), dtype)
-    q = jnp.asarray(rng.randint(-127, 128, (k, n)), jnp.int8)
-    scale = jnp.asarray(rng.rand(n).astype(numpy.float32))
-
-    # ONE copy of the serialized-scan timing protocol
-    # (gemm._matmul_scan_time) serves the GEMM and int8 autotuners
-    def measure(fn):
-        return gemm._matmul_scan_time(fn, x, lengths=(200, 1400),
-                                      repeats=repeats)
-
-    results = {"xla": measure(
-        lambda v: int8_matmul(v, q, scale, use_pallas=False))}
-    for block_n in BLOCK_N_CANDIDATES:
-        if n % block_n:
-            continue
-        try:
-            results["pallas_%d" % block_n] = measure(
-                lambda v, b=block_n: _pallas_int8_matmul(
-                    v, q, scale, b).astype(v.dtype))
-        except Exception as exc:
-            gemm.log_candidate_failure(
-                "int8 %dx%dx%d block_n=%d" % (m, k, n, block_n), exc)
-    winner = min(results, key=results.get)
-    decision = {
-        "use_pallas": winner != "xla",
-        "block_n": (int(winner.split("_")[1])
-                    if winner != "xla" else None),
-        "seconds": results[winner],
-        "measured": {key: round(val * 1e6, 2)
-                     for key, val in results.items()},
-    }
-    if len(results) > 1:
-        # an XLA-only "race" (no kernel candidate compiled; their
-        # messages are logged above) is not a measurement: nothing is
-        # persisted, and the autotune CLI exits non-zero
-        cache = gemm._load_cache()
-        cache["int8:%dx%d" % (k, n)] = decision
-        gemm._persist_cache(cache)
-    return decision

@@ -213,9 +213,8 @@ def _layer_forward(spec):
 
         def fwd(p, x):
             x = x.reshape(x.shape[0], -1)
-            # one fused kernel (matmul + bias + activation epilogue)
-            # when the shapes qualify for the Pallas path; XLA dot with
-            # its own epilogue fusion otherwise — see ops/gemm.py
+            # XLA's dot with its own bias + activation epilogue fusion
+            # — see ops/gemm.py
             return dense_layer(x, p["w"], p["b"], activation=activation,
                                out_dtype=jnp.float32)
         return fwd
@@ -306,9 +305,7 @@ def _tick_key(specs, norm_type, with_confusion, augment, loss_kind,
     return (_freeze(specs), norm_type, with_confusion, augment,
             loss_kind, grad_reduce, None if mesh is None else id(mesh),
             root.common.engine.get("precision_level", 0),
-            str(root.common.engine.get("compute_dtype", "bfloat16")),
-            bool(root.common.engine.get("use_pallas", False)),
-            bool(root.common.engine.get("pallas_epilogue", False)))
+            str(root.common.engine.get("compute_dtype", "bfloat16")))
 
 
 def install_tick_steps(steps, specs, norm_type="none", mesh=None,

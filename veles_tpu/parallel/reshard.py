@@ -24,9 +24,7 @@ shard size for the transpose-resharding case); values are moved, never
 recomputed, so a round trip is bit-exact. Every call is measured:
 per-transition bytes-on-the-wire and wall seconds land in the metrics
 registry (``veles_reshard_bytes_total`` / ``veles_reshard_seconds`` —
-docs/sharded_serving.md) and ``bench.py``'s ``reshard`` section records
-the train→serve / serve→train transitions against the naive
-``device_put`` formulation.
+docs/sharded_serving.md).
 """
 
 import threading
@@ -431,16 +429,3 @@ def reshard(tree, mesh, dst_specs, src_specs=None, label="reshard",
                      help="wall seconds per reshard() transition")
     return out, stats
 
-
-def naive_reshard(tree, mesh, dst_specs):
-    """The baseline ``device_put`` formulation (what :func:`reshard`
-    replaces) — kept callable so the bench can measure the schedule
-    against it honestly on the same tree/mesh/specs."""
-    leaves, treedef = jax.tree.flatten(tree)
-    dst_list = _spec_list(dst_specs, leaves, treedef)
-    t0 = time.perf_counter()
-    out = jax.tree.unflatten(treedef, [
-        jax.device_put(leaf, NamedSharding(mesh, spec))
-        for leaf, spec in zip(leaves, dst_list)])
-    jax.block_until_ready(out)
-    return out, time.perf_counter() - t0

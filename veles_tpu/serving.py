@@ -810,7 +810,7 @@ class ContinuousDecoder:
                  max_len=512, n_tokens=32, eos=None,
                  temperature=0.0, top_k=0, key=None, quantize=None,
                  tile=None, mesh=None, mesh_axis="model", paged=False,
-                 page_size=None, pool_pages=None, paged_kernel=None,
+                 page_size=None, pool_pages=None,
                  prefix_cache=None, aot=None, ledger=None):
         import collections
 
@@ -921,26 +921,14 @@ class ContinuousDecoder:
         else:
             self.pool_pages = None
         #: fused-kernel tier (docs/paged_kv.md "The fused kernel"):
-        #: when engaged, the jitted paged step runs the Pallas
-        #: paged-attention kernel (ops/paged_attention.py) instead of
-        #: the page-table gather, and admission groups go RAGGED —
-        #: page-rounded widths, no pow2 row duplication. ``None``
-        #: defers to the global probe (FORCE toggle -> config ->
-        #: platform auto); an explicit override here must agree with
-        #: that probe, because the device fn reads the probe at trace
-        #: time (the jitted signature is shared with the gather path).
-        #: Under a serve mesh the rule is the gather, and asking for
-        #: the kernel raises here, at construction.
+        #: whether paged dispatches attend through the fused kernel
+        #: (ops/paged_attention.py) instead of the page-table gather;
+        #: admission groups then go RAGGED — page-rounded widths, no
+        #: pow2 row duplication. A fact read off the module's rule,
+        #: the same one the device fn reads at trace time.
         if paged:
             from veles_tpu.ops.paged_attention import use_paged_kernel
-            probe = use_paged_kernel(mesh)
-            if paged_kernel and mesh is not None:
-                raise ValueError(
-                    "paged_kernel=True cannot be honoured under a "
-                    "serve mesh: the fused kernel is single-chip "
-                    "(ops/paged_attention.use_paged_kernel)")
-            self.paged_kernel = (probe if paged_kernel is None
-                                 else bool(paged_kernel))
+            self.paged_kernel = use_paged_kernel(mesh)
         else:
             self.paged_kernel = False
         self.n_tokens = n_tokens
@@ -2349,7 +2337,7 @@ class GenerateAPI:
                  max_queue=None, deadline=None, rebuild_backoff=None,
                  rebuild_backoff_max=None, chaos=None, quantize=None,
                  tile=None, mesh=None, mesh_axis="model", paged=None,
-                 page_size=None, pool_pages=None, paged_kernel=None,
+                 page_size=None, pool_pages=None,
                  aot=None, slo=None, ledger=None, governor=None):
         import queue
 
@@ -2397,14 +2385,6 @@ class GenerateAPI:
             page_size = serve_cfg.get("page_size", None)
         if pool_pages is None:
             pool_pages = serve_cfg.get("pool_pages", None)
-        #: fused paged-attention tier (--serve-paged-kernel /
-        #: root.common.serve.paged_kernel): None = backend auto (the
-        #: ops/paged_attention.py probe). Resolved HERE so breaker
-        #: rebuilds reconstruct the same attend formulation — a tier
-        #: flip across a rebuild would silently change step compile
-        #: keys and retrace the warmed sweep.
-        if paged_kernel is None:
-            paged_kernel = serve_cfg.get("paged_kernel", None)
         #: AOT compiled-program boot (--serve-aot PATH /
         #: root.common.serve.aot — docs/aot_artifacts.md): load the
         #: bundle ONCE here, so the decoder and every breaker-rebuild
@@ -2458,8 +2438,7 @@ class GenerateAPI:
             temperature=temperature, top_k=top_k, eos=eos, key=key,
             quantize=quantize, tile=tile, mesh=mesh,
             mesh_axis=mesh_axis, paged=bool(paged),
-            page_size=page_size, pool_pages=pool_pages,
-            paged_kernel=paged_kernel, aot=aot,
+            page_size=page_size, pool_pages=pool_pages, aot=aot,
             ledger=self.ledger)
         self.decoder = ContinuousDecoder(**self._decoder_kwargs)
         self.vocab = embed_table.shape[0]
