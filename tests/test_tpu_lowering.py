@@ -16,7 +16,8 @@ path. Two checks keep that from recurring, neither needing a chip:
 Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
 128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows;
 the routed experts' streaming kernel at 256 rows over 256 experts of
-2048 x 768.
+2048 x 768; the dense slab's attend at 16 slots, 16 heads of 64 and
+lanes of 1,024.
 """
 
 import os
@@ -95,6 +96,24 @@ def kernel_cases():
          _sds((count, width, inner), "bfloat16"),
          _sds((count, width, inner), "bfloat16"),
          _sds((count, inner, width), "bfloat16"))))
+    # the dense slab's ragged-length attend at the serving cell's
+    # shapes: 16 slots, 16 heads of 64, lanes of 1,024, a span of 896
+    from veles_tpu.ops import slab_attention
+
+    def slab(q, k, v, lengths):
+        # (with the VMEM claim the chip's kind gives: 100 of 128 MiB)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(slab_attention, "device_kind",
+                          lambda: "TPU v5 lite")
+            return slab_attention.slab_attend(q, k, v, lengths, 896,
+                                              interpret=False)
+
+    for dtype in ("bfloat16", "float32"):
+        leaf = _sds((16, HEADS * 64, 1024), dtype)
+        cases.append((
+            "slab_attend_s16_h16_d64_t1024_%s" % dtype, slab,
+            (_sds((16, 1, HEADS, 64), dtype), leaf, leaf,
+             _sds((16,), "int32"))))
     return cases
 
 
@@ -171,6 +190,12 @@ except Exception as exc:
     print("NO-TOPOLOGY %%s" %% exc)
     sys.exit(0)
 jax.config.update("jax_enable_compilation_cache", False)
+# this process sees the CPU: the rule and the kernel are told that the
+# program compiled here is the chip's
+from veles_tpu.ops import slab_attention
+slab_attention.on_tpu = lambda: True
+slab_attention.device_kind = lambda: topo.devices[0].device_kind
+slab_attention.pallas_interpret = lambda: False
 from veles_tpu.parallel import decode
 chip = SingleDeviceSharding(topo.devices[0])
 E, HEADS, LAYERS, HIDDEN, VOCAB, MAX_LEN, SLOTS = %(sizes)r
@@ -206,7 +231,21 @@ shape = ",".join(str(n) for n in leaf.shape)
 whole = [line.strip()[:200] for line in text.splitlines()
          if re.search(r"= bf16\\[%%s\\]\\S* (copy|fusion)\\(" %% shape, line)
          and "dynamic-update-slice" not in line.split(" = ")[0]]
+# a float32 value of the attended window's size: every slot's K or V
+# widened at the span
+window = [line.strip()[:200] for line in text.splitlines()
+          if re.search(r"= f32\\[%%d,%%d,512\\]" %% leaf.shape[:2], line)]
+# a whole leaf copied into VMEM ahead of its use (memory-space
+# assignment's prefetch of a kernel's operand)
+prefetched = [line.strip()[:200] for line in text.splitlines()
+              if " copy-start(" in line
+              and line.split(" = ")[1].startswith("(bf16[%%s]" %% shape)]
 print("RESULT " + json.dumps({
+    "slab_attend_calls": len(re.findall(
+        r"%%slab_attend\\S* = .*custom_call_target=.tpu_custom_call", text)),
+    "leaf_prefetches": prefetched,
+    "custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "widened_windows": window,
     "layout": [str(formats[name].layout) for name in ("k", "v")],
     "padded_bytes": compiled.memory_analysis().argument_size_in_bytes,
     "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
@@ -221,10 +260,13 @@ print("RESULT " + json.dumps({
 def test_chunk_program_uses_the_slab_in_place_on_v5e():
     """Compiled for a described v5e at gpt2-medium's sizes and 16 slots
     (the benchmark's serving cell), the chunk program of
-    ``slot_step_many`` with the layout the decoder would pin: its
-    temporaries stay under 5% of the slab, no op of its own produces a
-    whole K/V leaf (a copy of a layer) and the slab's arguments are
-    its bytes, unpadded. Skips where no TPU compiler is installed."""
+    ``slot_step_many`` with the layout the decoder would pin: it holds
+    the ragged-length attend kernel once a block and no float32 window
+    of every slot at the span, its temporaries stay under 5% of the
+    slab, no op of its own produces a whole K/V leaf (a copy of a
+    layer, which a kernel's operand in another layout would cost) and
+    the slab's arguments are its bytes, unpadded. Skips where no TPU
+    compiler is installed."""
     import json
 
     sizes = (1024, 16, 24, 4096, 50257, 1024, 16)
@@ -243,6 +285,11 @@ def test_chunk_program_uses_the_slab_in_place_on_v5e():
     (result,) = [json.loads(line[len("RESULT "):]) for line in lines
                  if line.startswith("RESULT ")]
     slab = result["slab_bytes"]
+    layers = sizes[2]
+    assert result["slab_attend_calls"] == layers, result
+    assert result["custom_calls"] == layers, result
+    assert not result["leaf_prefetches"], result["leaf_prefetches"][:3]
+    assert not result["widened_windows"], result["widened_windows"][:3]
     assert result["temp_bytes"] < 0.05 * slab, result
     assert not result["whole_leaf_ops"], result["whole_leaf_ops"][:3]
     assert not result["remat_uncompressed"]

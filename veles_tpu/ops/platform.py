@@ -4,9 +4,11 @@ The rule for choosing a kernel: the module that owns a kernel has one
 function that returns the choice, read off ``on_tpu()``, static shapes
 and the sharding or mesh — ``ops/moe.expert_path``,
 ``ops/quant.use_int8_kernel``, ``ops/attention.use_flash``,
-``ops/paged_attention.use_paged_kernel``. No config key, flag,
+``ops/paged_attention.use_paged_kernel``,
+``ops/slab_attention.use_slab_kernel``. No config key, flag,
 argument or file decides; a test that wants the other side patches
-that module's ``on_tpu``. Two platforms exist for this code: ``tpu``
+that module's ``on_tpu`` (and ``device_kind`` where the rule reads the
+chip's generation). Two platforms exist for this code: ``tpu``
 (Pallas kernels compile through Mosaic) and ``cpu`` (tests and drives;
 Pallas kernels run in interpret mode there and only there).
 """
@@ -33,3 +35,10 @@ def pallas_interpret():
         "Pallas kernels here target the TPU (compiled) or the CPU "
         "(interpret mode); JAX platform %r is neither — set "
         "JAX_PLATFORMS to tpu or cpu" % backend)
+
+
+def device_kind():
+    """The default device's kind as JAX names it (``"TPU v5 lite"``,
+    ``"cpu"``): what a rule reads where a kernel's fit depends on the
+    chip's generation, not only on its being a TPU."""
+    return jax.devices()[0].device_kind

@@ -39,7 +39,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from veles_tpu.ops import moe
+from veles_tpu.ops import moe, slab_attention
 from veles_tpu.ops.attention import attention
 from veles_tpu.ops.quant import int8_cache_attend, matmul_any
 
@@ -88,6 +88,23 @@ def expert_path(params, tokens):
         return None
     return moe.expert_path(tokens * arch_of(params).top_k,
                            params["blocks"][routed[0]]["experts"])
+
+
+def attend_path(params, state, sharding):
+    """How a decode step over the slot state ``state`` (arrays,
+    tracers or shapes), whose K/V leaves lie as ``sharding`` says
+    (None: nobody knows), attends the cache: ``"kernel"`` where the
+    attention kind has a kernel over ragged lengths
+    (``attend_ragged``) and its rule takes it
+    (``ops/slab_attention.use_slab_kernel``, read off the platform,
+    the leaves' type and shape and the place), else ``"xla"``
+    (``attend_cached`` over the rectangular window). The ONE question:
+    ``decode._slot_steps`` asks it when a program is traced for a
+    place, the decoder asks it of the state it holds for its books."""
+    if arch_of(params).attention == "mha" and "k_scale" not in state \
+            and slab_attention.use_slab_kernel(state["k"][0], sharding):
+        return "kernel"
+    return "xla"
 
 
 def require_gpt2(params, what):
@@ -303,6 +320,26 @@ class FusedQKV:
                     q, apart(read["k"]), apart(read["v"]), mask,
                     tail=(apart(staged["k"]), apart(staged["v"]),
                           mask_staged))
+            return att.reshape(slots, 1, -1)
+
+    @staticmethod
+    def attend_ragged(q, leaves, staged, lengths, span, mask_staged):
+        """:meth:`attend_cached` where :func:`attend_path` says
+        ``kernel``: one query a slot against the first ``lengths[s]``
+        positions (at most ``span``, static) of the block's ``leaves``,
+        taken whole from where they lie, and the chunk's staged
+        columns, in one softmax (``ops/slab_attention.py``). No window
+        is sliced and no mask over it built. Returns ``(S, 1, H·D)``."""
+        slots, _, heads, _ = q.shape
+
+        def apart(leaf):
+            return leaf.reshape((slots, heads, -1, leaf.shape[-1]))
+
+        with jax.named_scope("attn.attend"):
+            att = slab_attention.join_tail(
+                q, slab_attention.slab_attend(q, leaves["k"], leaves["v"],
+                                              lengths, span),
+                apart(staged["k"]), apart(staged["v"]), mask_staged)
             return att.reshape(slots, 1, -1)
 
     @staticmethod

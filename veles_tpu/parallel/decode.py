@@ -592,7 +592,7 @@ def slot_admit(params, embed_table, heads, state, slot, prompt_x,
 
 
 def _slot_steps(params, embed_table, heads, state, active, n,
-                temperature, sample, top_k, span):
+                temperature, sample, top_k, span, place=None):
     """``n`` lockstep decode steps across ALL slots, the cache's
     leaves used in place: ``(state, emitted (n, S))``. A model with
     routed experts emits ``(tokens (n, S), load (n, blocks, experts))``:
@@ -610,7 +610,14 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     own, and only when the steps are done does each slot's block of
     ``n`` columns go to the leaf at the length the slot had when the
     chunk began: one write per slot and leaf a chunk, from a loop over
-    the slots, so the program holds one such write a leaf."""
+    the slots, so the program holds one such write a leaf.
+
+    ``place`` is where the state lies, leaf name -> ``Format``, as
+    :func:`slot_fns` pins it on the program it builds around this
+    function; None where nobody knows (an outer trace holds the
+    state). A step attends as ``blocks.attend_path`` says of it: each
+    slot over its own length by the attention kind's kernel, or every
+    slot over the window of ``span`` positions."""
     slots = state["lengths"].shape[0]
     quantized = "k_scale" in state
     names = _kv_names(state)
@@ -620,11 +627,17 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     if span is None or span > max_len:
         span = max_len
     before = state["lengths"]
-    # per-slot mask over the window: position p of slot s is cached iff
-    # p < the slot's length when the chunk began; a staged column is
-    # visible from its own step on (the new token attends to itself)
+    ragged = blocks.attend_path(
+        params, state, place and place[names[0]].sharding) == "kernel"
+    # what a slot has cached is what it held when the chunk began: said
+    # as lengths where each slot attends over its own (an idle lane's
+    # answer is no one's, so it reads nothing), else as a mask over the
+    # window (position p of slot s is cached iff p < the slot's
+    # length); a staged column is visible from its own step on (the new
+    # token attends to itself)
     with jax.named_scope("attn.attend"):
-        cached = jnp.arange(span)[None, :] < before[:, None]
+        cached = jnp.where(active, before, 0) if ragged \
+            else jnp.arange(span)[None, :] < before[:, None]
 
     def masks(visible):
         if quantized:
@@ -655,7 +668,7 @@ def _slot_steps(params, embed_table, heads, state, active, n,
         with jax.named_scope("embed"):
             x = embed_table[tok_in][:, None, :]
         with jax.named_scope("attn.attend"):
-            mask = masks(cached)
+            mask = None if ragged else masks(cached)
             mask_staged = masks(jnp.broadcast_to(
                 jnp.arange(n)[None, :] <= j, (slots, n)))
         staged = {name: list(staged[name]) for name in names}
@@ -671,16 +684,20 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                     at = (0,) * (cols.ndim - 1) + (j,)
                     staged[name][i] = lax.dynamic_update_slice(
                         staged[name][i], cols, at)
-            # ONE read per leaf: the attended window, consumed by the
-            # attend from the leaf where it lies; never the leaf at
-            # max_len
-            with jax.named_scope("cache.read"):
-                read = {name: state[name][i][..., :span]
-                        for name in names}
-            att = kind.attend_cached(
-                arch, blk, q, read,
-                {name: staged[name][i] for name in names}, mask,
-                mask_staged)
+            leaves = {name: state[name][i] for name in names}
+            columns = {name: staged[name][i] for name in names}
+            if ragged:
+                att = kind.attend_ragged(q, leaves, columns, cached, span,
+                                         mask_staged)
+            else:
+                # ONE read per leaf: the attended window, consumed by
+                # the attend from the leaf where it lies; never the
+                # leaf at max_len
+                with jax.named_scope("cache.read"):
+                    read = {name: leaf[..., :span]
+                            for name, leaf in leaves.items()}
+                att = kind.attend_cached(arch, blk, q, read, columns,
+                                         mask, mask_staged)
             x = kind.out(blk, x, att)
             # an idle slot's lane is computed, but routed to no expert
             x, load = blocks.ffn(arch, blk, x, active[:, None])
@@ -732,7 +749,8 @@ def split_emitted(emitted):
 
 
 def _slot_step(params, embed_table, heads, state, active,
-               temperature=1.0, sample=False, top_k=0, span=None):
+               temperature=1.0, sample=False, top_k=0, span=None,
+               place=None):
     """One decode step across ALL slots; ``active`` (S,) bool gates
     which slots advance (inactive slots' lanes are computed but their
     lengths/logits stay frozen and their emitted token is meaningless —
@@ -759,12 +777,13 @@ def _slot_step(params, embed_table, heads, state, active,
     before attending to it. The one-step case of :func:`_slot_steps`."""
     state, emitted = _slot_steps(params, embed_table, heads, state,
                                  active, 1, temperature, sample, top_k,
-                                 span)
+                                 span, place)
     return state, split_emitted(emitted)[0][0]
 
 
 def _slot_step_many(params, embed_table, heads, state, active, n,
-                    temperature=1.0, sample=False, top_k=0, span=None):
+                    temperature=1.0, sample=False, top_k=0, span=None,
+                    place=None):
     """``n`` lockstep ``slot_step``s as ONE dispatch (``lax.scan``
     inside :func:`_slot_steps`) — the throughput mode: admission
     happens between chunks, so a high-RTT host pays one round trip per
@@ -777,7 +796,7 @@ def _slot_step_many(params, embed_table, heads, state, active, n,
     # up as one labeled region in the XLA device trace
     with jax.named_scope("decode.dispatch"):
         return _slot_steps(params, embed_table, heads, state, active, n,
-                           temperature, sample, top_k, span)
+                           temperature, sample, top_k, span, place)
 
 
 # -- the jitted surface --------------------------------------------------------
@@ -851,8 +870,16 @@ def slot_fns(state):
 
 def _build_slot_fns(place):
     """The three jit objects with ``place`` pinned (``None``: nothing
-    pinned, for a state that an outer trace holds). Statics are
-    positional: jit takes no keyword beside pinned operand places."""
+    pinned, for a state that an outer trace holds) and told to the
+    step programs, whose attend depends on it (``_slot_steps``): a
+    tracer does not say where it lies, the builder of its program
+    knows. Statics are positional: jit takes no keyword beside pinned
+    operand places."""
+    def placed(fn):
+        # under fn's own name: it names the compiled module, which is
+        # what a trace's readers look for
+        return functools.wraps(fn)(functools.partial(fn, place=place))
+
     pins = ({}, {}, {})
     if place is not None:
         emitted = place["lengths"]
@@ -879,10 +906,10 @@ def _build_slot_fns(place):
             _slot_admit_many, static_argnums=(2,), donate_argnums=(3,),
             **pins[0])),
         instrument("decode.step", jax.jit(
-            _slot_step, static_argnums=(2, 6, 7, 8),
+            placed(_slot_step), static_argnums=(2, 6, 7, 8),
             donate_argnums=(3,), **pins[1])),
         instrument("decode.dispatch", jax.jit(
-            _slot_step_many, static_argnums=(2, 5, 7, 8, 9),
+            placed(_slot_step_many), static_argnums=(2, 5, 7, 8, 9),
             donate_argnums=(3,), **pins[2])))
 
 
@@ -987,6 +1014,15 @@ def slot_layout_facts(state):
         for leaf in jax.tree.leaves(state)
         for shard in leaf.addressable_shards)
     return facts
+
+
+def slot_attend_path(params, state):
+    """``"kernel"`` or ``"xla"``: how the step programs that
+    :func:`slot_fns` builds for ``state`` (arrays) attend the cache.
+    ``blocks.attend_path`` asked what ``_slot_steps`` asks it, with
+    the place the programs are told: the one the K/V leaves lie in."""
+    return blocks.attend_path(
+        params, state, state[_kv_names(state)[0]][0].sharding)
 
 
 def dispatch_program(fn, default):

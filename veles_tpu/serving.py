@@ -501,6 +501,11 @@ class ServingHealth:
                        None) is not None:
                 # the routed experts' load, among the counters
                 snap["counters"].update(deploy.decoder.moe_counters())
+            paths = getattr(getattr(deploy, "decoder", None),
+                            "attend_paths", None)
+            if paths is not None:
+                # the dense slab's decode dispatches by how they attend
+                snap["counters"]["attend_path"] = dict(paths)
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -995,6 +1000,15 @@ class ContinuousDecoder:
         if expert_blocks(params):
             self.moe_load = {"assignments": None, "by_lanes": {},
                              "paths": {"streamed": 0, "grouped": 0}}
+        #: the dense slab's decode dispatches (chunks and single
+        #: steps) by how their program attends the cache: ``kernel``
+        #: (each slot over its own length, ops/slab_attention.py) or
+        #: ``xla`` (every slot over the span), which the state's
+        #: leaves and the platform decide when the program is traced
+        #: (``parallel/decode.slot_attend_path``). None for the page pool,
+        #: whose attend is ``paged_kernel``'s affair.
+        self.attend_paths = None if self.paged \
+            else {"kernel": 0, "xla": 0}
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1845,6 +1859,28 @@ class ContinuousDecoder:
         span = -(-(longest + extra) // self.tile) * self.tile
         return int(min(span, self.max_len))
 
+    def _attend_overshoot(self, lens, chunk, span, pages, slab_kernel):
+        """Attended positions past the live slots' sequences in one
+        decode dispatch of ``chunk`` steps, for the waste plane: a
+        kernel that walks each slot's own length (the paged one its
+        live pages, the slab's its live tiles) leaves only the dead
+        lanes of the last page or tile, booked as ``tile_pad`` so the
+        ledger never silently credits zero; the gather and the
+        rectangular window attend ``pages`` pages or ``span``
+        positions for every slot."""
+        from veles_tpu.parallel.decode import (
+            page_overshoot_tokens, span_overshoot_tokens,
+            tile_pad_tokens)
+        if self.paged_kernel:
+            return tile_pad_tokens(lens, self.page_size, chunk)
+        if self.paged:
+            return page_overshoot_tokens(lens, pages, self.page_size,
+                                         chunk)
+        if slab_kernel:
+            from veles_tpu.ops.slab_attention import TILE
+            return tile_pad_tokens(lens, TILE, chunk)
+        return span_overshoot_tokens(lens, span, chunk)
+
     def _active(self):
         active = numpy.zeros(self.slots, bool)
         for slot in self._slot_req:
@@ -1890,6 +1926,8 @@ class ContinuousDecoder:
             self._slot_len[slot] += 1
         self.dispatch_counts["step"] += 1
         self._book_moe_path(self.slots)
+        slab_kernel = self._book_attend_path().get(
+            "attend_path") == "kernel"
         self.flight.note("step", rids=list(snapshot.values()))
         ledger_aot = None
         if self.ledger is not None:
@@ -1899,26 +1937,15 @@ class ContinuousDecoder:
         if self.scope.enabled:
             # the step path syncs inline, so the whole call is one
             # decode-compute window; every active lane keeps its token
-            from veles_tpu.parallel.decode import (
-                page_overshoot_tokens, span_overshoot_tokens,
-                tile_pad_tokens)
-            # kernel path attends live pages only: the gathered-span
-            # overshoot is structurally gone, and the residual — the
-            # last partial page's dead lanes — books as tile_pad so
-            # the waste ledger never silently credits zero
-            overshoot = (tile_pad_tokens(scope_lens, self.page_size, 1)
-                         if self.paged_kernel
-                         else page_overshoot_tokens(scope_lens, pb,
-                                                    self.page_size, 1)
-                         if self.paged
-                         else span_overshoot_tokens(scope_lens, span,
-                                                    1))
+            overshoot = self._attend_overshoot(scope_lens, 1, span, pb,
+                                               slab_kernel)
             elapsed = time.perf_counter() - t0
             self.scope.note_dispatch(1, self.slots, len(snapshot),
                                      overshoot, elapsed,
                                      paged=self.paged, span=span,
                                      pages=pb,
-                                     kernel=self.paged_kernel)
+                                     kernel=self.paged_kernel
+                                     or slab_kernel)
             self.scope.note_collect(len(snapshot), len(snapshot), 0.0)
         out = {}
         for slot, rid in snapshot.items():
@@ -2092,6 +2119,22 @@ class ContinuousDecoder:
             "admissions) by the tiling of the routed experts' products")
         return {"moe_expert_path": path}
 
+    def _book_attend_path(self):
+        """Book one decode dispatch of the dense slab by how its
+        program attends the cache; returns what the dispatch's span
+        says of it (nothing for the page pool)."""
+        if self.attend_paths is None:
+            return {}
+        from veles_tpu.parallel.decode import slot_attend_path
+
+        path = slot_attend_path(self.params, self.state)
+        self.attend_paths[path] += 1
+        self.metrics.incr(
+            "veles_decode_attend_dispatches_total", 1,
+            labels={"path": path}, help="decode dispatches of the dense "
+            "slab (chunks, steps) by how the program attends the cache")
+        return {"attend_path": path}
+
     def moe_load_max_over_mean(self):
         """The busiest expert's assignments over the mean expert's, of
         all the decode steps so far (the worst expert block's); None
@@ -2137,6 +2180,8 @@ class ContinuousDecoder:
             said = {"kv_layout": json.dumps(self.kv_layout,
                                             sort_keys=True)}
         said.update(self._book_moe_path(self.slots))
+        said.update(self._book_attend_path())
+        slab_kernel = said.get("attend_path") == "kernel"
         # span writes stay outside the timed window (see decode.admit)
         with self._span("paged.dispatch" if self.paged
                         else "decode.dispatch",
@@ -2170,23 +2215,14 @@ class ContinuousDecoder:
                     span=span)
             elapsed = time.perf_counter() - t0
         if self.scope.enabled:
-            from veles_tpu.parallel.decode import (
-                page_overshoot_tokens, span_overshoot_tokens,
-                tile_pad_tokens)
-            overshoot = (tile_pad_tokens(scope_lens, self.page_size,
-                                         chunk)
-                         if self.paged_kernel
-                         else page_overshoot_tokens(scope_lens, pb,
-                                                    self.page_size,
-                                                    chunk)
-                         if self.paged
-                         else span_overshoot_tokens(scope_lens, span,
-                                                    chunk))
+            overshoot = self._attend_overshoot(scope_lens, chunk, span,
+                                               pb, slab_kernel)
             self.scope.note_dispatch(chunk, self.slots, len(snapshot),
                                      overshoot, elapsed,
                                      paged=self.paged, span=span,
                                      pages=pb,
-                                     kernel=self.paged_kernel)
+                                     kernel=self.paged_kernel
+                                     or slab_kernel)
         self.timings["dispatch_s"] += elapsed
         self.metrics.observe(
             "veles_decode_dispatch_seconds", elapsed,
