@@ -16,7 +16,7 @@ path. Two checks keep that from recurring, neither needing a chip:
 Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
 128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows;
 the routed experts' streaming kernel at 256 rows over 256 experts of
-2048 x 768; the dense slab's attend at 16 slots, 16 heads of 64 and
+2048 x 768 and over 32 of 2048 x 1792; the dense slab's attend at 16 slots, 16 heads of 64 and
 lanes of 1,024.
 """
 
@@ -85,17 +85,20 @@ def kernel_cases():
     # = 256 rows over 256 experts of 2048 x 768
     from veles_tpu.ops import moe
 
-    count, width, inner, rows = 256, 2048, 768, 256
-    cases.append((
-        "moe_streamed_experts_r256_e256_2048x768",
-        lambda r, load, gate, up, down: moe.streamed_experts(
-            r, moe.visit_table(load, rows),
-            {"w_gate": gate, "w_up": up, "w_down": down},
-            interpret=False),
-        (_sds((rows, width), "bfloat16"), _sds((count,), "int32"),
-         _sds((count, width, inner), "bfloat16"),
-         _sds((count, width, inner), "bfloat16"),
-         _sds((count, inner, width), "bfloat16"))))
+    # and of its second: 64 slots x top-4 = 256 rows over 32 experts of
+    # 2048 x 1792, 22 MB each, two of them in VMEM at once
+    rows, width = 256, 2048
+    for count, inner in ((256, 768), (32, 1792)):
+        cases.append((
+            "moe_streamed_experts_r256_e%d_2048x%d" % (count, inner),
+            lambda r, load, gate, up, down: moe.streamed_experts(
+                r, moe.visit_table(load, rows),
+                {"w_gate": gate, "w_up": up, "w_down": down},
+                interpret=False),
+            (_sds((rows, width), "bfloat16"), _sds((count,), "int32"),
+             _sds((count, width, inner), "bfloat16"),
+             _sds((count, width, inner), "bfloat16"),
+             _sds((count, inner, width), "bfloat16"))))
     # the dense slab's ragged-length attend at the serving cell's
     # shapes: 16 slots, 16 heads of 64, lanes of 1,024, a span of 896
     from veles_tpu.ops import slab_attention
@@ -319,10 +322,34 @@ moe.on_tpu = lambda: True
 moe.pallas_interpret = lambda: False
 import offchip_compile_arch
 with open(os.path.join(
-        %(repo)r, "benchmark/configs/joyai-llm-flash.json")) as fin:
+        %(repo)r, "benchmark/configs/%(config)s.json")) as fin:
     config = json.load(fin)
 offchip_compile_arch.serve_programs(config, %(programs)r, %(out)r)
 """
+
+
+def _compile_off_the_chip(config, programs, out):
+    """``benchmark/tools/offchip_compile_arch.py`` over ``programs`` of
+    the configuration ``config`` in a child that tells ``ops/moe`` it
+    is the chip's: ``{program: what the tool said of it}``, the
+    compiled texts under ``out``."""
+    import json
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c", _EXPERTS_CHILD % {
+                "repo": REPO, "config": config, "programs": programs,
+                "out": str(out)}],
+            env=env, capture_output=True, text=True, timeout=600)
+    except subprocess.TimeoutExpired:
+        pytest.skip("the compile-only TPU client did not answer here")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    if any(line.startswith("NO-TOPOLOGY") for line in lines):
+        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
+    return {row["program"]: row for row in (
+        json.loads(line) for line in lines if line.startswith("{"))}
 
 
 def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
@@ -334,24 +361,8 @@ def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
     (one prompt of 128 tokens: 1,024 rows) still holds the compiler's
     grouped kernel and no other. The chunk's temporaries stay far
     under one expert layer's matrices: no copy of the experts."""
-    import json
-
-    programs = ["step:8:1408", "admit:128:1"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", _EXPERTS_CHILD % {
-                "repo": REPO, "programs": programs,
-                "out": str(tmp_path)}],
-            env=env, capture_output=True, text=True, timeout=600)
-    except subprocess.TimeoutExpired:
-        pytest.skip("the compile-only TPU client did not answer here")
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = proc.stdout.splitlines()
-    if any(line.startswith("NO-TOPOLOGY") for line in lines):
-        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
-    said = {row["program"]: row for row in (
-        json.loads(line) for line in lines if line.startswith("{"))}
+    said = _compile_off_the_chip("joyai-llm-flash",
+                                 ["step:8:1408", "admit:128:1"], tmp_path)
     chunk = (tmp_path / "step_8_1408.txt").read_text()
     admit = (tmp_path / "admit_128_1.txt").read_text()
     expert_layers = 4
@@ -366,3 +377,49 @@ def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
     assert admit.count('op_name="ragged-dot-none"') == 3 * expert_layers
     one_layer = 3 * 256 * 2048 * 768 * 2
     assert said["step:8:1408"]["temp_bytes"] < 0.1 * one_layer, said
+
+
+def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
+    """The benchmark's model with a kind for each block (10 gated
+    short convolutions, 3 grouped-query attentions, 12 layers of 32
+    experts of 2048 x 1792) at its serving shape, compiled for a
+    described v5e: the chunk program over the whole lane (64 slots x
+    top-4 = 256 rows an expert layer) holds the streaming kernel once
+    an expert layer and nothing else of its kind; the slab's leaves
+    are those of the three attention blocks alone and the chunk copies
+    none of them; the tied head reads the embedding table where it
+    lies; an admission of four prompts of 1,024 tokens (~500 rows an
+    expert) holds the compiler's grouped kernel."""
+    said = _compile_off_the_chip(
+        "lfm2-8b-a1b", ["step:8:2048", "admit:1024:4"], tmp_path)
+    chunk = (tmp_path / "step_8_2048.txt").read_text()
+    admit = (tmp_path / "admit_1024_4.txt").read_text()
+    expert_layers = 12
+    assert "ragged-dot" not in chunk
+    assert chunk.count('custom_call_target="tpu_custom_call"') \
+        == expert_layers
+    assert chunk.count(
+        'mlp/moe.experts/moe_streamed_experts/pallas_call"') \
+        == expert_layers
+    assert "moe_streamed_experts" not in admit
+    assert admit.count('op_name="ragged-dot-none"') == 3 * expert_layers
+    for scope in ("attn.qkv/conv.in", "attn.attend/conv.mix",
+                  "attn.out/conv.out", "cache.append/cache.state",
+                  "attn.qkv/gqa.norm", "attn.qkv/gqa.rope"):
+        assert scope in chunk, scope
+    # K and V of 8 heads x 64 over 2,048 positions in three blocks:
+    # what the program takes and gives back in place
+    slab = 3 * 2 * 64 * 512 * 2048 * 2
+    step = said["step:8:2048"]
+    assert slab < step["alias_bytes"] < 1.05 * slab, step
+    assert step["remat_uncompressed_copies"] == 0
+    # no second copy of the table stands as the head
+    assert not [line for line in chunk.splitlines()
+                if " copy(" in line and "bf16[65536,2048]" in
+                line.split(" = ")[1].split(" copy(")[0]]
+    one_layer = 3 * 32 * 2048 * 1792 * 2
+    assert step["temp_bytes"] < 0.15 * one_layer, step
+    weights = 4606249728 * 2
+    for row in said.values():
+        assert row["argument_bytes"] + row["temp_bytes"] < 15.75e9, row
+        assert row["argument_bytes"] > weights + slab

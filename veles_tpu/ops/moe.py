@@ -6,7 +6,7 @@ token goes to its ``top_k`` experts whatever the others chose:
 
     s = sigmoid(h . W_g)                    (float32, every expert)
     chosen = top_k(s + b)                   (b steers the choice only)
-    w = s[chosen] / sum(s[chosen]) * scale
+    w = s[chosen] / (sum(s[chosen]) + eps) * scale
     y = sum_i w_i . E_chosen_i(h)           E_e = W_down(silu(W_gate h) * W_up h)
 
 The products are grouped: the (token, expert) assignments are sorted
@@ -56,17 +56,19 @@ STREAM_MAX_ROWS = 512
 _ROW_TILE, _ROW_ALIGN = 32, 16
 
 
-def route(h, router, bias, top_k, scale):
+def route(h, router, bias, top_k, scale, eps=0.0):
     """``(chosen (N, top_k) int32, weights (N, top_k) float32)`` for
     tokens ``h`` (N, E): scores, bias add and top-k in float32 at full
-    precision (a bfloat16 score ties where a float32 one does not)."""
+    precision (a bfloat16 score ties where a float32 one does not).
+    ``eps`` (static) is what a model adds to the normalising sum."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             h.astype(jnp.float32), router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = picked / jnp.sum(picked, -1, keepdims=True) * scale
+        total = jnp.sum(picked, -1, keepdims=True)
+        weights = picked / (total + eps if eps else total) * scale
     return chosen.astype(jnp.int32), weights
 
 
@@ -269,11 +271,14 @@ def routed_experts(h, chosen, weights, experts, held=None, live=None):
     return y.astype(h.dtype), load
 
 
-def expert_layer(h, p, top_k, scale, held=None, live=None):
+def expert_layer(h, p, top_k, scale, held=None, live=None, eps=0.0):
     """The whole layer for tokens ``h`` (N, E): the held experts' part
-    plus the shared expert. Returns ``(y, load)``."""
-    chosen, weights = route(h, p["router"], p["router_bias"], top_k, scale)
+    plus the shared expert, where the layer has one (a ``shared``
+    leaf). Returns ``(y, load)``."""
+    chosen, weights = route(h, p["router"], p["router_bias"], top_k, scale,
+                            eps)
     y, load = routed_experts(h, chosen, weights, p["experts"], held, live)
-    with jax.named_scope("moe.shared"):
-        y = y + swiglu(h, p["shared"])
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(h, p["shared"])
     return y, load

@@ -1,4 +1,4 @@
-"""The block's sublayers, by architecture: the model seam.
+"""The block's sublayers, by the block's kind: the model seam.
 
 Every language-model path of the repo (the plain full forward of
 ``transformer_step._forward``, the prompt forward of
@@ -7,31 +7,46 @@ Every language-model path of the repo (the plain full forward of
 
     q, rows = kind.project(arch, blk, x, heads, positions)
     att     = kind.attend_prompt(...)  |  kind.attend_cached(...)
+                                       |  kind.step(...)  (fixed state)
     x       = kind.out(blk, x, att)
     x, load = ffn(arch, blk, x, live)
-    logits  = head(arch, params, x)
+    logits  = head(arch, params, x, embed_table)
 
-``rows`` is what a position leaves in the cache, and the attention
-kind declares the cache's leaves (``kind.leaves``): the slot state is
-built, written and read over whatever leaves it declares.
+``kind`` is the BLOCK's (:func:`block_kinds`): a model declares a kind
+for each of its blocks (``Arch.layers``), and a model whose blocks are
+all alike names the one. ``rows`` is what the block keeps of a
+sequence, and the kind declares the leaves that hold it
+(``kind.leaves``), of two sorts: a row a POSITION (``(S, row, T)``:
+what attention reads back) or a FIXED state a slot (``(S, ...)``, no
+position axis: what a recurrence carries). The slot state is built,
+written and read over whatever leaves the blocks' kinds declare.
 
 How an architecture travels: with the parameters. ``params["arch"]``
 is an :class:`Arch`, a static node of the pytree (no array in it; it
 is part of every jitted program's key). A tree without one is GPT-2's
-block (``GPT2``): pre-LN LayerNorm, fused biased qkv, equal Q and K/V
-heads, no position encoding, GELU MLP. ``attention="mla"`` is latent
-attention with RoPE (RMSNorm, no bias): a position leaves ONE row
-``[c | k_rope]`` of ``kv_rank + rope_dim`` values for all heads; the
-prompt attends expanded (``k_nope``, ``v`` made from ``c``), a decode
-step absorbed (the query goes into the latent space and attends the
-rows as they lie), the same numbers. The feed-forward kind is read
-off the block's own leaves: ``w1`` GELU MLP, ``w_gate`` SwiGLU,
-``router`` the routed experts of ``ops/moe.py``.
+block throughout (``GPT2``, kind ``"mha"``): pre-LN LayerNorm, fused
+biased qkv, equal Q and K/V heads, no position encoding, GELU MLP.
+``"mla"`` is latent attention with RoPE (RMSNorm, no bias): a position
+leaves ONE row ``[c | k_rope]`` of ``kv_rank + rope_dim`` values for
+all heads; the prompt attends expanded (``k_nope``, ``v`` made from
+``c``), a decode step absorbed (the query goes into the latent space
+and attends the rows as they lie), the same numbers. ``"gqa"`` is
+grouped-query attention (``heads`` query heads over ``kv_heads`` K/V
+heads, RMSNorm over each head's q and k, RoPE): GPT-2's ``k``/``v``
+leaves at ``kv_heads * head_dim`` a position, a group's query heads
+attending their one K/V head as it lies. ``"conv"`` is a gated short
+convolution: it keeps no row a position but the last
+``conv_taps - 1`` gated inputs of a slot, which a step reads and
+rewrites. The feed-forward kind is read off the block's own leaves:
+``w1`` GELU MLP, ``w_gate`` SwiGLU, ``router`` the routed experts of
+``ops/moe.py`` (with a shared expert where the block has a ``shared``
+leaf).
 
 The named scopes are the ones the per-layer readers know
-(``attn.qkv``, ``attn.attend``, ``attn.out``, ``mlp``, ``head``), with
-the new kinds' own nested under them (``mla.q``, ``mla.kv``,
-``mla.rope``, ``mla.absorb``, ``moe.*``).
+(``attn.qkv``, ``attn.attend``, ``attn.out``, ``mlp``, ``head``,
+``cache.append``), with the kinds' own nested under them (``mla.q``,
+``mla.kv``, ``mla.rope``, ``mla.absorb``, ``gqa.norm``, ``gqa.rope``,
+``conv.in``, ``conv.mix``, ``conv.out``, ``cache.state``, ``moe.*``).
 """
 
 import dataclasses
@@ -46,10 +61,20 @@ from veles_tpu.ops.quant import int8_cache_attend, matmul_any
 
 @dataclasses.dataclass(frozen=True)
 class Arch:
-    """What the block's sublayers are. Sizes the leaves' shapes do not
+    """What the blocks' sublayers are. Sizes the leaves' shapes do not
     give are stated here; ``heads`` travels as every caller passes it."""
-    attention: str = "mha"
+    #: the kind of each block (``KINDS``): a tuple of names, one a
+    #: block in the model's order, or the one name of a model whose
+    #: blocks are all alike
+    layers: object = "mha"
+    #: ``Arch(attention="mla")`` says ``layers="mla"``: the one kind of
+    #: every block, as a model with a single kind states it
+    attention: dataclasses.InitVar[str] = None
     eps: float = 1e-5
+    # grouped-query attention (RoPE's ``rope_theta`` is below)
+    kv_heads: int = 0
+    # gated short convolution: taps of the depthwise kernel
+    conv_taps: int = 3
     # latent attention
     kv_rank: int = 0
     nope_dim: int = 0
@@ -58,11 +83,19 @@ class Arch:
     # routed experts
     top_k: int = 0
     route_scale: float = 1.0
+    #: added to the sum that normalises the chosen experts' scores
+    route_eps: float = 0.0
     #: (first, count) of the experts held here; None: all of them
     held: tuple = None
     #: prompt tokens a block takes at once in an admission (rows of a
     #: group beyond that go through in turn); 0: the whole group
     prefill_tokens: int = 0
+
+    def __post_init__(self, attention):
+        layers = self.layers if attention is None else attention
+        if not isinstance(layers, str):
+            layers = tuple(layers)
+        object.__setattr__(self, "layers", layers)
 
 
 jax.tree_util.register_static(Arch)
@@ -93,30 +126,34 @@ def expert_path(params, tokens):
 def attend_path(params, state, sharding):
     """How a decode step over the slot state ``state`` (arrays,
     tracers or shapes), whose K/V leaves lie as ``sharding`` says
-    (None: nobody knows), attends the cache: ``"kernel"`` where the
-    attention kind has a kernel over ragged lengths
+    (None: nobody knows), attends the cache: ``"kernel"`` where every
+    block that attends has a kernel over ragged lengths
     (``attend_ragged``) and its rule takes it
     (``ops/slab_attention.use_slab_kernel``, read off the platform,
     the leaves' type and shape and the place), else ``"xla"``
     (``attend_cached`` over the rectangular window). The ONE question:
     ``decode._slot_steps`` asks it when a program is traced for a
     place, the decoder asks it of the state it holds for its books."""
-    if arch_of(params).attention == "mha" and "k_scale" not in state \
+    kinds = set(block_kinds(arch_of(params), len(params["blocks"])))
+    if all(kind.fixed or hasattr(kind, "attend_ragged") for kind in kinds) \
+            and "k" in state and "k_scale" not in state \
             and slab_attention.use_slab_kernel(state["k"][0], sharding):
         return "kernel"
     return "xla"
 
 
-def require_gpt2(params, what):
-    """Refuse by name what only GPT-2's block has yet."""
+def require_gpt2(params, what, lacks=None):
+    """Refuse by name what only GPT-2's block has yet; ``lacks`` says
+    what the tier would need for another kind."""
     arch = arch_of(params)
     if arch != GPT2 or expert_blocks(params):
         raise ValueError(
-            "%s is built for GPT-2's block (fused qkv, k/v leaves, GELU "
-            "MLP) and this model declares attention=%r%s: that tier "
-            "has no such kind yet" % (
-                what, arch.attention,
-                " with routed experts" if expert_blocks(params) else ""))
+            "%s is built for GPT-2's block (fused qkv, k/v leaves of "
+            "heads x head_dim, GELU MLP) and this model declares "
+            "%s%s: that tier has no such kind yet%s" % (
+                what, kinds_said(arch),
+                " with routed experts" if expert_blocks(params) else "",
+                " (%s)" % lacks if lacks else ""))
 
 
 # -- norms ---------------------------------------------------------------------
@@ -243,13 +280,34 @@ def _head(params, x):
                           params["head"])
 
 
-class FusedQKV:
-    """``attention="mha"``: K and V rows of ``heads * head_dim`` a
-    position (the int8-KV tier: int8 rows and a scale a head)."""
+class Kind:
+    """What every kind of block says of what it keeps of a sequence.
+    ``fixed`` is False where it keeps a row a position (attention: the
+    slab's ``(S, row, T)`` leaves, staged by a chunk and read back as
+    a window) and True where it keeps a fixed state a slot (``(S,
+    ...)`` leaves that a step rewrites: ``step`` in place of
+    ``attend_cached``)."""
+    fixed = False
+    #: the first of the leaf names it declares (kinds that share it
+    #: share their leaves' tuples in the slot state: ``leaf_ordinals``)
+    leaf = "k"
+
+    @staticmethod
+    def keep(arch, rows, live):
+        """What of a prompt's ``rows`` the cache keeps: of rows a
+        position, all of them (the slab is written to the bucket's
+        end, and a sequence's own appends overwrite the padding)."""
+        return rows
+
+
+class FusedQKV(Kind):
+    """``"mha"``: K and V rows of ``heads * head_dim`` a position
+    (the int8-KV tier: int8 rows and a scale a head)."""
 
     @staticmethod
     def leaves(arch, heads, head_dim, dtype, quantized=False):
-        """``{leaf name: (a position's row shape, dtype)}``."""
+        """``{leaf name: (a position's row shape, dtype)}``; for a
+        kind with ``fixed`` state, a slot's."""
         if not quantized:
             return dict.fromkeys(("k", "v"), ((heads * head_dim,), dtype))
         leaves = dict.fromkeys(("k", "v"), ((heads, head_dim), jnp.int8))
@@ -349,11 +407,12 @@ class FusedQKV:
                 + blk["bout"]
 
 
-class Latent:
-    """``attention="mla"``: one row ``[c | k_rope]`` a position for all
+class Latent(Kind):
+    """``"mla"``: one row ``[c | k_rope]`` a position for all
     heads. Leaves of a block: ``attn_norm``, ``wq_a``, ``q_norm``,
     ``wq_b`` (q_rank, H·(nope+rope)), ``wkv_a`` (E, kv_rank+rope),
     ``kv_norm``, ``wkv_b`` (kv_rank, H·(nope+v)), ``wout``."""
+    leaf = "kv"
 
     @staticmethod
     def leaves(arch, heads, head_dim, dtype, quantized=False):
@@ -450,14 +509,222 @@ class Latent:
             return x + att.astype(x.dtype) @ blk["wout"]
 
 
-ATTENTION = {"mha": FusedQKV, "mla": Latent}
+class Grouped(Kind):
+    """``"gqa"``: ``heads`` query heads over ``arch.kv_heads`` K/V
+    heads (query head ``i`` attends K/V head ``i // (heads //
+    kv_heads)``), RMSNorm over the ``head_dim`` of each head's q and
+    k, RoPE. K and V rows of ``kv_heads * head_dim`` a position, in
+    GPT-2's leaves. Leaves of a block: ``attn_norm``, ``wq`` (E, H·D),
+    ``wk``/``wv`` (E, H_kv·D), ``q_norm``/``k_norm`` (D,), ``wout``
+    (H·D, E); no bias."""
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        return FusedQKV.leaves(arch, arch.kv_heads, head_dim, dtype)
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
+        batch, t, _ = x.shape
+        with jax.named_scope("attn.qkv"):
+            h = rms_norm(x, blk["attn_norm"], arch.eps)
+            q = (h @ blk["wq"]).reshape(batch, t, heads, -1)
+            k = (h @ blk["wk"]).reshape(batch, t, arch.kv_heads, -1)
+            v = (h @ blk["wv"]).reshape(batch, t, arch.kv_heads, -1)
+            with jax.named_scope("gqa.norm"):
+                q = rms_norm(q, blk["q_norm"], arch.eps)
+                k = rms_norm(k, blk["k_norm"], arch.eps)
+            with jax.named_scope("gqa.rope"):
+                q = rope(q, positions, arch.rope_theta)
+                k = rope(k, positions, arch.rope_theta)
+            return q, {"k": k, "v": v}
+
+    @staticmethod
+    def columns(state, rows):
+        """GPT-2's, of rows that are values of their own. Fused with
+        the norm and the rotation that made ``k``, the chunk program's
+        staged column takes a slots-minor layout, and XLA's TPU
+        compiler then fails a RET_CHECK on the chunk's block write
+        ("The shape doesn't match when replacing", at every slab shape
+        tried, compiled off the chip for a v5e): the barrier keeps the
+        rows' making and their staging apart. 64 KB a step."""
+        return FusedQKV.columns(state, jax.lax.optimization_barrier(rows))
+
+    @staticmethod
+    def attend_prompt(arch, blk, q, rows):
+        """Causal attention over the prompt, the K/V heads as they
+        are (``ops.attention`` takes fewer K/V heads than queries)."""
+        with jax.named_scope("attn.attend"):
+            att = attention(q, rows["k"], rows["v"], causal=True)
+            return att.reshape(att.shape[:2] + (-1,))
+
+    @staticmethod
+    def attend_cached(arch, blk, q, read, staged, mask, mask_staged):
+        """One query a head a slot against the window and the staged
+        columns. A group's query heads stand where ``_cache_attend``
+        has its queries and the K/V heads where it has its heads: each
+        K/V row is read once, as it lies, for the whole group."""
+        slots, _, heads, head_dim = q.shape
+        groups = arch.kv_heads
+
+        def apart(leaf):
+            return leaf.reshape((slots, groups, -1, leaf.shape[-1]))
+
+        with jax.named_scope("attn.attend"):
+            # (S, 1, H, D) -> (S, H // groups, groups, D)
+            q_all = jnp.swapaxes(
+                q.reshape(slots, groups, heads // groups, head_dim), 1, 2)
+            att = _cache_attend(
+                q_all, apart(read["k"]), apart(read["v"]), mask,
+                tail=(apart(staged["k"]), apart(staged["v"]),
+                      mask_staged))
+            return jnp.swapaxes(att, 1, 2).reshape(slots, 1, -1)
+
+    @staticmethod
+    def out(blk, x, att):
+        with jax.named_scope("attn.out"):
+            return x + att.astype(x.dtype) @ blk["wout"]
 
 
-def attention_kind(arch):
-    if arch.attention not in ATTENTION:
-        raise ValueError("no attention kind %r (known: %s)"
-                         % (arch.attention, ", ".join(sorted(ATTENTION))))
-    return ATTENTION[arch.attention]
+class ShortConv(Kind):
+    """``"conv"``: a gated short convolution. ``B, C, u = split3(h .
+    W_in)``; ``z_t = sum_j w_j * (B * u)_{t - (taps - 1) + j}``
+    (depthwise, causal, zeros before the sequence, no bias); ``out =
+    (C * z) . W_out``. It keeps no row a position: its one leaf is the
+    FIXED state of a slot, the last ``taps - 1`` gated inputs ``B *
+    u`` side by side, oldest first: ``(S, (taps - 1) * E)``. Leaves of
+    a block: ``attn_norm``, ``w_in`` (E, 3E), ``conv_w`` (taps, E),
+    ``w_out`` (E, E)."""
+    fixed = True
+    leaf = "conv"
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        return {"conv": (((arch.conv_taps - 1) * heads * head_dim,),
+                         dtype)}
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
+        """``(C, {"conv": B * u})``, both ``(B, T, E)``."""
+        # the operator's norm under ``conv.in`` too: the compiler fuses
+        # it into the projection, and the scope table names a fusion by
+        # the scope most of its instructions carry
+        with jax.named_scope("attn.qkv"), jax.named_scope("conv.in"):
+            h = rms_norm(x, blk["attn_norm"], arch.eps)
+            b, c, u = jnp.split(h @ blk["w_in"], 3, axis=-1)
+        with jax.named_scope("attn.attend"), jax.named_scope("conv.mix"):
+            return c, {"conv": b * u}
+
+    @staticmethod
+    def _taps(blk, inputs):
+        """``sum_j w_j * inputs[j]`` in float32, ``inputs`` oldest
+        first."""
+        taps = blk["conv_w"].astype(jnp.float32)
+        return sum(taps[j] * part.astype(jnp.float32)
+                   for j, part in enumerate(inputs))
+
+    @staticmethod
+    def attend_prompt(arch, blk, q, rows):
+        """The whole sequence: tap ``j`` sees the gated input shifted
+        ``taps - 1 - j`` positions back, zeros before the start."""
+        gated = rows["conv"]
+        t, back = gated.shape[1], arch.conv_taps - 1
+        with jax.named_scope("attn.attend"), jax.named_scope("conv.mix"):
+            padded = jnp.pad(gated, ((0, 0), (back, 0), (0, 0)))
+            z = ShortConv._taps(blk, [padded[:, j:j + t]
+                                      for j in range(back + 1)])
+            return (q.astype(jnp.float32) * z).astype(q.dtype)
+
+    @staticmethod
+    def keep(arch, rows, live):
+        """The state after each row's LAST position: the gated inputs
+        at ``length - (taps - 1) .. length - 1`` of a right-padded
+        row (``live`` (B, T) marks its own positions; None: all of
+        them), zeros where the prompt is shorter:
+        ``{"conv": (B, (taps - 1) · E)}``."""
+        gated = rows["conv"]
+        batch, t, _ = gated.shape
+        back = arch.conv_taps - 1
+        lengths = jnp.full((batch,), t) if live is None \
+            else jnp.sum(live, -1)
+        at = lengths[:, None] - back + jnp.arange(back)         # (B, back)
+        got = jnp.take_along_axis(gated, jnp.maximum(at, 0)[..., None],
+                                  axis=1)
+        got = jnp.where((at >= 0)[..., None], got, 0)
+        return {"conv": got.reshape(batch, -1)}
+
+    @staticmethod
+    def columns(state, rows):
+        return {"conv": rows["conv"].astype(state["conv"][0].dtype)}
+
+    @staticmethod
+    def step(arch, blk, q, rows, fixed, active):
+        """One new position a slot: ``(att (S, 1, E), fixed)``. The
+        taps see what the slot carries (``fixed["conv"]`` (S,
+        (taps-1)·E)) and the new gated input; the state rolls by one,
+        and a lane that is not ``active`` keeps what it had."""
+        held, gated = fixed["conv"], rows["conv"][:, 0]
+        back = arch.conv_taps - 1
+        with jax.named_scope("attn.attend"), jax.named_scope("conv.mix"):
+            z = ShortConv._taps(
+                blk, jnp.split(held, back, axis=-1) + [gated])
+            att = (q[:, 0].astype(jnp.float32) * z).astype(q.dtype)
+        with jax.named_scope("cache.append"), \
+                jax.named_scope("cache.state"):
+            rolled = jnp.concatenate(
+                [held[:, gated.shape[-1]:], gated.astype(held.dtype)], -1)
+            return att[:, None], {
+                "conv": jnp.where(active[:, None], rolled, held)}
+
+    @staticmethod
+    def out(blk, x, att):
+        with jax.named_scope("attn.out"), jax.named_scope("conv.out"):
+            return x + att.astype(x.dtype) @ blk["w_out"]
+
+
+#: a block's kind by the name a model declares for it (``Arch.layers``)
+KINDS = {"mha": FusedQKV, "mla": Latent, "gqa": Grouped,
+         "conv": ShortConv}
+
+
+def _kind(name):
+    if name not in KINDS:
+        raise ValueError("no block kind %r (known: %s)"
+                         % (name, ", ".join(sorted(KINDS))))
+    return KINDS[name]
+
+
+def layer_names(arch, n_blocks):
+    """The name of each of a model's ``n_blocks`` blocks' kind."""
+    if isinstance(arch.layers, str):
+        return (arch.layers,) * n_blocks
+    if len(arch.layers) != n_blocks:
+        raise ValueError("the model declares a kind for %d blocks and "
+                         "has %d" % (len(arch.layers), n_blocks))
+    return arch.layers
+
+
+def block_kinds(arch, n_blocks):
+    """The kind of each of a model's ``n_blocks`` blocks, in order."""
+    return tuple(_kind(name) for name in layer_names(arch, n_blocks))
+
+
+def kinds_said(arch):
+    """The model's kinds as a refusal names them, in the words the
+    model declared them with."""
+    if isinstance(arch.layers, str):
+        return "attention=%r" % arch.layers
+    return "layers=(%s)" % ", ".join(
+        "%d x %r" % (arch.layers.count(name), name)
+        for name in sorted(set(arch.layers)))
+
+
+def leaf_ordinals(kinds):
+    """Where each block finds its leaves: the slot state holds, under
+    each leaf name, one array for every block whose kind declares the
+    name, in the blocks' order, and block ``i``'s are at
+    ``leaf_ordinals(kinds)[i]`` of each."""
+    return tuple(sum(1 for before in kinds[:i] if before.leaf == kind.leaf)
+                 for i, kind in enumerate(kinds))
 
 
 # -- feed-forward and head -----------------------------------------------------
@@ -477,23 +744,31 @@ def ffn(arch, blk, x, live=None):
         flat = h.reshape(-1, h.shape[-1])
         y, load = moe.expert_layer(
             flat, blk, arch.top_k, arch.route_scale, held=arch.held,
-            live=None if live is None else live.reshape(-1))
+            live=None if live is None else live.reshape(-1),
+            eps=arch.route_eps)
         return x + y.reshape(x.shape), load
 
 
-def head(arch, params, x):
-    """Final norm and vocabulary projection."""
+def head(arch, params, x, embed_table=None):
+    """Final norm and vocabulary projection. A model without a
+    ``head`` leaf ties it to the embedding: ``embed_table`` (V, E),
+    contracted as it lies."""
     if "lnf_w" in params:
         return _head(params, x)
     with jax.named_scope("head"):
-        return rms_norm(x, params["norm_w"], arch.eps) @ params["head"]
+        h = rms_norm(x, params["norm_w"], arch.eps)
+        if "head" in params:
+            return h @ params["head"]
+        return jnp.einsum("...e,ve->...v", h, embed_table)
 
 
-def block_forward(arch, blk, x, heads, positions, live=None):
-    """One block over whole sequences ``x`` (B, T, E): ``(x, rows)``.
-    The prompt's path through a block, shared by the plain full
-    forward and the admission."""
-    kind = attention_kind(arch)
+def block_forward(arch, blk, x, heads, positions, live=None, kind=None):
+    """One block (of ``kind``; None: the model's one kind) over whole
+    sequences ``x`` (B, T, E): ``(x, rows)``, ``rows`` what the cache
+    keeps of them (``kind.keep``). The prompt's path through a block,
+    shared by the plain full forward and the admission."""
+    if kind is None:
+        kind, = block_kinds(arch, 1)
     q, rows = kind.project(arch, blk, x, heads, positions)
     x = kind.out(blk, x, kind.attend_prompt(arch, blk, q, rows))
-    return ffn(arch, blk, x, live)[0], rows
+    return ffn(arch, blk, x, live)[0], kind.keep(arch, rows, live)

@@ -19,6 +19,7 @@ policy, so equality is numerical, not bitwise), because both use the
 identical parameter pytree and sublayer math.
 """
 
+import dataclasses
 import functools
 import threading
 
@@ -78,12 +79,14 @@ def prefill_parts(arch, batch, t):
     return parts
 
 
-def _prompt_forward(params, x, heads, length=None):
+def _prompt_forward(params, x, heads, length=None, embed_table=None):
     """The prompt forward pass shared by every prefill surface: run
     ``x`` (B, T, E) through all blocks once and return ``(last_logits,
-    rows, cache_len)``: ``rows`` per block what each position leaves
-    in the cache, as the attention kind's ``project`` makes it (the
-    caller decides how to store it).
+    rows, cache_len)``: ``rows`` per block what the cache keeps of the
+    prompt, as the block's kind makes it (``project``, ``keep``: a
+    row a position, or the fixed state after each row's true length;
+    the caller decides how to store it). ``embed_table`` is the head
+    of a model that ties the two.
 
     ``length`` may be ``None`` (use T), a traced scalar (one shared
     right-padded length), or a traced (B,) vector (per-row true lengths
@@ -99,17 +102,18 @@ def _prompt_forward(params, x, heads, length=None):
     live = positions < jnp.reshape(cache_len, (-1, 1))
     parts = prefill_parts(arch, batch, t)
     rows_all = []
-    for blk in params["blocks"]:
+    for blk, kind in zip(params["blocks"], blocks.block_kinds(
+            arch, len(params["blocks"]))):
         if parts == 1:
             x, rows = blocks.block_forward(arch, blk, x, heads,
-                                           positions, live)
+                                           positions, live, kind)
         else:
             def apart(a):
                 return a.reshape((parts, batch // parts) + a.shape[1:])
 
             x, rows = lax.map(
-                lambda part, blk=blk: blocks.block_forward(
-                    arch, blk, part[0], heads, part[1], part[2]),
+                lambda part, blk=blk, kind=kind: blocks.block_forward(
+                    arch, blk, part[0], heads, part[1], part[2], kind),
                 (apart(x), apart(positions), apart(live)))
             x, rows = jax.tree.map(
                 lambda a: a.reshape((batch,) + a.shape[2:]), (x, rows))
@@ -121,7 +125,8 @@ def _prompt_forward(params, x, heads, length=None):
     else:
         last = jnp.take_along_axis(
             x, (cache_len - 1)[:, None, None], axis=1)[:, 0]
-    return blocks.head(arch, params, last), rows_all, cache_len
+    return blocks.head(arch, params, last, embed_table), rows_all, \
+        cache_len
 
 
 def _prefill_forward(params, x, heads, length=None):
@@ -383,16 +388,23 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
 SLOT_SPAN_TILE = 128
 
 
-#: the state's control leaves; every other leaf is the cache's, a
-#: tuple of one array per block under each name the attention kind
-#: declares (``blocks.ATTENTION[...].leaves``: GPT-2's ``k`` and ``v``,
-#: with ``k_scale``/``v_scale`` in the int8-KV tier; latent
-#: attention's one ``kv``)
+#: the state's control leaves. Beside them a slot holds two kinds of
+#: state, as its blocks' kinds declare (``blocks.KINDS[...].leaves``).
+#: ROWS A POSITION ``(S, row, T)``: under each name a tuple of one
+#: array for every block that declares it, in the blocks' order
+#: (``blocks.leaf_ordinals``): GPT-2's and grouped-query attention's
+#: ``k`` and ``v``, with ``k_scale``/``v_scale`` in the int8-KV tier;
+#: latent attention's one ``kv``. And, only where some block declares
+#: it, FIXED STATE ``(S, ...)`` with no position axis, under ``FIXED``
+#: as ``{name: tuple}`` alike: the short convolution's ``conv``
 CONTROL_LEAVES = ("lengths", "logits", "req_key", "step")
+FIXED = "fixed"
 
 
 def _kv_names(state):
-    return sorted(name for name in state if name not in CONTROL_LEAVES)
+    """The names of the leaves that hold a row a position."""
+    return sorted(name for name in state
+                  if name not in CONTROL_LEAVES and name != FIXED)
 
 
 def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
@@ -401,10 +413,14 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
                     page_size=None, formats=None, arch=blocks.GPT2):
     """Cache + control state for ``slots`` concurrent sequences.
 
-    The cache's leaves are the ones ``arch``'s attention kind declares
-    (``blocks.ATTENTION``), each a tuple of ``n_blocks`` arrays
-    ``(S, row..., T)``, positions minor: latent attention's one ``kv``
-    of ``(S, kv_rank + rope_dim, T)``, and GPT-2's as follows.
+    The cache's leaves are the ones the kinds of ``arch``'s blocks
+    declare (``blocks.KINDS``; ``CONTROL_LEAVES`` above has the
+    state's outline): rows a position as tuples of arrays ``(S,
+    row..., T)``, positions minor (latent attention's one ``kv`` of
+    ``(S, kv_rank + rope_dim, T)``; grouped-query attention's ``k``
+    and ``v`` of ``(S, kv_heads * head_dim, T)``; GPT-2's as follows),
+    and fixed state a slot ``(S, ...)`` under ``FIXED`` (the short
+    convolution's ``(S, (taps - 1) * E)``).
 
     The slab is ONE K and ONE V leaf per block, ``state["k"]`` and
     ``state["v"]`` tuples of ``n_blocks`` arrays ``(S, H·D, T)``:
@@ -446,8 +462,8 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
         if arch != blocks.GPT2:
             raise ValueError(
                 "the page pool (parallel/kv_pool.py) holds k/v pages "
-                "of heads x head_dim; attention=%r has no paged cache "
-                "yet" % arch.attention)
+                "of heads x head_dim; %s has no paged cache "
+                "yet" % blocks.kinds_said(arch))
         from veles_tpu.parallel.kv_pool import (default_pool_pages,
                                                 init_paged_state)
 
@@ -468,10 +484,17 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
         "req_key": jax.random.split(jax.random.key(0), slots),
         "step": jnp.zeros((slots,), jnp.int32),
     }
-    leaves = {
-        name: ((slots,) + row + (max_len,), leaf_dtype)
-        for name, (row, leaf_dtype) in blocks.attention_kind(arch).leaves(
-            arch, heads, head_dim, dtype, quantized).items()}
+    # name -> one shape for every block that declares the leaf
+    leaves, fixed = {}, {}
+    for kind in blocks.block_kinds(arch, n_blocks):
+        for name, (row, leaf_dtype) in kind.leaves(
+                arch, heads, head_dim, dtype, quantized).items():
+            if kind.fixed:
+                fixed.setdefault(name, []).append(
+                    jax.ShapeDtypeStruct((slots,) + row, leaf_dtype))
+            else:
+                leaves.setdefault(name, []).append(jax.ShapeDtypeStruct(
+                    (slots,) + row + (max_len,), leaf_dtype))
     # where each leaf lives: every leaf is committed to its place, so
     # the first dispatch and every later one (whose state is a
     # program's output) are the same call to the same program
@@ -491,13 +514,18 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
         place.update(formats)
     state = {name: jax.device_put(leaf, place[name])
              for name, leaf in state.items()}
+    shapes = {name: tuple(each) for name, each in leaves.items()}
+    where = {name: place[name] for name in leaves}
+    if fixed:
+        # the fixed state lies where the control leaves do
+        shapes[FIXED] = {name: tuple(each) for name, each in fixed.items()}
+        where[FIXED] = place["lengths"]
     # the slab is made where and how it will lie (a program's outputs
     # in their pinned place): no second copy of it exists meanwhile
     state.update(jax.jit(
-        lambda: {name: tuple(jnp.zeros(shape, leaf_dtype)
-                             for _ in range(n_blocks))
-                 for name, (shape, leaf_dtype) in leaves.items()},
-        out_shardings={name: place[name] for name in leaves})())
+        lambda: jax.tree.map(lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+                             shapes),
+        out_shardings=where)())
     return state
 
 
@@ -522,8 +550,10 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
                      prompt_x, req_keys, lengths):
     """Admit a whole same-bucket group in ONE dispatch: prefill
     ``prompt_x`` (B, T, E) — each row right-padded to the bucket T —
-    and scatter the K/V rows into slots ``slots`` (B,) int32 of every
-    block's leaf.
+    and scatter what each block keeps of them into slots ``slots``
+    (B,) int32 of the block's leaves: the K/V rows of positions
+    [0, T), or the fixed state after each row's TRUE length, set
+    whole (nothing of a retired occupant's state stays).
 
     The prefill cost scales with the BUCKET (T), not ``max_len``: only
     positions [0, T) of each slot lane are written. Stale positions
@@ -537,13 +567,14 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
     ``req_keys`` (B,) seeds each slot's sampling stream; ``lengths``
     (B,) are the true prompt lengths inside the padded rows."""
     t = prompt_x.shape[1]
-    kind = blocks.attention_kind(blocks.arch_of(params))
+    kinds = blocks.block_kinds(blocks.arch_of(params),
+                               len(params["blocks"]))
     # named after the host-side "decode.admit" span so the XLA device
     # trace and the span timeline line up in a profiler capture
     # (observe/profile.py; zero cost post-compile)
     with jax.named_scope("decode.admit"):
         logits, rows_all, lengths = _prompt_forward(
-            params, prompt_x, heads, lengths)
+            params, prompt_x, heads, lengths, embed_table)
         # the sampling stream's books, then positions [0, t) of each
         # admitted slot's K/V lane
         with jax.named_scope("sample"):
@@ -561,14 +592,24 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
             # block: a block's rows (B, ..., T) are turned and written
             # on their own, so no second copy of all blocks' rows
             # stands beside what the prefill returns
-            fresh = {name: [] for name in _kv_names(state)}
-            for i, rows in enumerate(rows_all):
-                columns = kind.columns(state, rows)
-                for name, leaf in fresh.items():
-                    leaf.append(state[name][i].at[slots, ..., :t].set(
-                        columns[name]))
+            fresh = {name: list(state[name]) for name in _kv_names(state)}
+            fixed = {name: list(leaves)
+                     for name, leaves in state.get(FIXED, {}).items()}
+            for kind, i, rows in zip(kinds, blocks.leaf_ordinals(kinds),
+                                     rows_all):
+                if kind.fixed:      # a slot's state, set whole
+                    held, source, where = fixed, state[FIXED], (slots,)
+                else:               # positions [0, t) of a slot's lane
+                    held, source = fresh, state
+                    where = (slots, Ellipsis, slice(None, t))
+                for name, value in sorted(kind.columns(source,
+                                                       rows).items()):
+                    held[name][i] = held[name][i].at[where].set(value)
             new.update({name: tuple(leaves)
                         for name, leaves in fresh.items()})
+            if fixed:
+                new[FIXED] = {name: tuple(leaves)
+                              for name, leaves in fixed.items()}
     return new
 
 
@@ -622,7 +663,8 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     quantized = "k_scale" in state
     names = _kv_names(state)
     arch = blocks.arch_of(params)
-    kind = blocks.attention_kind(arch)
+    kinds = blocks.block_kinds(arch, len(params["blocks"]))
+    ordinals = blocks.leaf_ordinals(kinds)
     max_len = state[names[0]][0].shape[-1]  # positions are minor
     if span is None or span > max_len:
         span = max_len
@@ -645,7 +687,7 @@ def _slot_steps(params, embed_table, heads, state, active, n,
         return visible[:, None, None, :]
 
     def step(carry, j):
-        control, staged = carry
+        control, staged, fixed = carry
         lengths = control["lengths"]
         # the named scopes of a step (HLO metadata; the scope table,
         # observe/xla_stats.scope_table, carries them to a traced op):
@@ -672,38 +714,54 @@ def _slot_steps(params, embed_table, heads, state, active, n,
             mask_staged = masks(jnp.broadcast_to(
                 jnp.arange(n)[None, :] <= j, (slots, n)))
         staged = {name: list(staged[name]) for name in names}
-        loads = []
-        for i, blk in enumerate(params["blocks"]):
-            # the new token stands at its slot's own length
-            q, rows = kind.project(arch, blk, x, heads, lengths[:, None])
+        fixed = {name: list(fixed[name]) for name in fixed}
+
+        def attend(blk, kind, i, q, rows):
             # every slot's new column at once, into column j of this
             # block's staging buffers: (S, H·D, 1); the int8 tier's
             # (S, H, D, 1) and (S, H, 1); latent attention's (S, W, 1)
             with jax.named_scope("cache.append"):
-                for name, cols in kind.columns(state, rows).items():
+                mine = kind.columns(state, rows)
+                for name, cols in mine.items():
                     at = (0,) * (cols.ndim - 1) + (j,)
                     staged[name][i] = lax.dynamic_update_slice(
                         staged[name][i], cols, at)
-            leaves = {name: state[name][i] for name in names}
-            columns = {name: staged[name][i] for name in names}
+            leaves = {name: state[name][i] for name in mine}
+            columns = {name: staged[name][i] for name in mine}
             if ragged:
-                att = kind.attend_ragged(q, leaves, columns, cached, span,
-                                         mask_staged)
+                return kind.attend_ragged(q, leaves, columns, cached, span,
+                                          mask_staged)
+            # ONE read per leaf: the attended window, consumed by the
+            # attend from the leaf where it lies; never the leaf at
+            # max_len
+            with jax.named_scope("cache.read"):
+                read = {name: leaf[..., :span]
+                        for name, leaf in leaves.items()}
+            return kind.attend_cached(arch, blk, q, read, columns, mask,
+                                      mask_staged)
+
+        loads = []
+        for blk, kind, i in zip(params["blocks"], kinds, ordinals):
+            # the new token stands at its slot's own length
+            q, rows = kind.project(arch, blk, x, heads, lengths[:, None])
+            if kind.fixed:
+                # no row a position: the block reads the state its
+                # slot carries (under the names of what it projected)
+                # and rewrites it (an idle lane's stays)
+                att, new = kind.step(
+                    arch, blk, q, rows,
+                    {name: fixed[name][i] for name in rows}, active)
+                for name, value in new.items():
+                    fixed[name][i] = value
             else:
-                # ONE read per leaf: the attended window, consumed by
-                # the attend from the leaf where it lies; never the
-                # leaf at max_len
-                with jax.named_scope("cache.read"):
-                    read = {name: leaf[..., :span]
-                            for name, leaf in leaves.items()}
-                att = kind.attend_cached(arch, blk, q, read, columns,
-                                         mask, mask_staged)
+                att = attend(blk, kind, i, q, rows)
             x = kind.out(blk, x, att)
             # an idle slot's lane is computed, but routed to no expert
             x, load = blocks.ffn(arch, blk, x, active[:, None])
             if load is not None:
                 loads.append(load)
-        logits = blocks.head(arch, params, x[:, 0]).astype(jnp.float32)
+        logits = blocks.head(arch, params, x[:, 0],
+                             embed_table).astype(jnp.float32)
         with jax.named_scope("sample"):
             control = dict(
                 control,
@@ -712,14 +770,17 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                                  control["logits"]),
                 step=jnp.where(active, control["step"] + 1,
                                control["step"]))
-        return (control, {name: tuple(staged[name]) for name in names}), \
+        return (control, {name: tuple(staged[name]) for name in names},
+                {name: tuple(fixed[name]) for name in fixed}), \
             ((tok_in, jnp.stack(loads)) if loads else tok_in)
 
     control = {name: state[name] for name in CONTROL_LEAVES}
     staged = {name: tuple(jnp.zeros(leaf.shape[:-1] + (n,), leaf.dtype)
                           for leaf in state[name]) for name in names}
-    (control, staged), emitted = lax.scan(step, (control, staged),
-                                          jnp.arange(n))
+    # the fixed state rides in the carry and is the chunk's result as
+    # the last step left it: written back once a chunk
+    (control, staged, fixed), emitted = lax.scan(
+        step, (control, staged, state.get(FIXED, {})), jnp.arange(n))
     # each slot's block of n columns, to where the slot's sequence
     # stood. An inactive lane's block is what its frozen logits made:
     # it lands past the lane's length, where nothing reads before the
@@ -728,6 +789,8 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     # end, as the single append was: only a sequence that has overrun
     # its budget, whose tokens the host discards, stands there).
     new_state = dict(control)
+    if fixed:
+        new_state[FIXED] = fixed
     with jax.named_scope("cache.append"):
         for name in names:
             def put(s, leaf, block):
@@ -818,10 +881,12 @@ _DECIDED_FORMATS = {}       # state skeleton and place -> {name: Format}
 _SLOT_FNS_LOCK = threading.Lock()
 
 
-def _pinned_place(kv_place, control):
+def _pinned_place(kv_place, control, fixed=False):
     """The state's prefix tree of places: each K/V name's, and
-    ``control`` on every control leaf."""
-    return dict(dict.fromkeys(CONTROL_LEAVES, control), **kv_place)
+    ``control`` on every control leaf and on the ``fixed`` state
+    (where the state has any)."""
+    names = CONTROL_LEAVES + ((FIXED,) if fixed else ())
+    return dict(dict.fromkeys(names, control), **kv_place)
 
 
 def slot_fns(state):
@@ -834,7 +899,8 @@ def slot_fns(state):
 
     The place is read off the state's arrays: each K/V name's layout
     and sharding (one per name: its leaves are built alike), and for
-    the control leaves the sharding that stands for "replicated" where
+    the control leaves and the fixed state (in the platform's default
+    layout) the sharding that stands for "replicated" where
     the K/V are (on one device, that device: a place that is pinned
     lowers the same program whether or not the arrays handed in are
     committed to it, which is what lets ``xla_stats.scope_table`` find
@@ -847,8 +913,9 @@ def slot_fns(state):
     lead = state[_kv_names(state)[0]][0]
     concrete = isinstance(lead, jax.Array) \
         and not isinstance(lead, jax.core.Tracer)
-    key = tuple((name, state[name][0].format)
-                for name in _kv_names(state)) if concrete else None
+    key = (tuple((name, state[name][0].format)
+                 for name in _kv_names(state)),
+           FIXED in state) if concrete else None
     with _SLOT_FNS_LOCK:
         fns = _SLOT_FNS.get(key)
     if fns is not None:
@@ -860,7 +927,7 @@ def slot_fns(state):
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             control = NamedSharding(control.mesh, P())
-        place = _pinned_place(dict(key), control)
+        place = _pinned_place(dict(key[0]), control, key[1])
     fns = _build_slot_fns(place)
     with _SLOT_FNS_LOCK:
         # a racing builder may have won; keep ITS jit objects (their
@@ -947,9 +1014,11 @@ def decide_slot_formats(params, embed_table, heads, state, n, span,
     ``{leaf name: Format}`` for :func:`init_slot_state`'s ``formats``.
 
     One representative chunk program (``n`` steps over ``span``
-    positions, through the first two blocks: every block uses its
-    leaves alike, and two compile in a second where all of them take
-    a quarter of a minute of every set-up) is compiled with the layout
+    positions, through the first two of the blocks that hold each
+    leaf: every block uses its leaves alike, and two compile in a
+    second where all of them take a quarter of a minute of every
+    set-up; a block that keeps no row a position has no leaf to
+    decide and stays out) is compiled with the layout
     of every K/V leaf left to the compiler, in and out
     (``Layout.AUTO``), and the layout it chose for the leaves it takes
     is the layout the loop works in: pinned on every program from then
@@ -986,8 +1055,17 @@ def decide_slot_formats(params, embed_table, heads, state, n, span,
         {name: Format(Layout.AUTO, where[name]) for name in names},
         control)
     slots = state["lengths"].shape[0]
-    params = dict(params, blocks=params["blocks"][:2])
-    state = dict(state, **{name: state[name][:2] for name in names})
+    arch = blocks.arch_of(params)
+    kinds = blocks.block_kinds(arch, len(params["blocks"]))
+    kept = [i for i, (kind, at) in enumerate(
+        zip(kinds, blocks.leaf_ordinals(kinds)))
+        if at < 2 and not kind.fixed]
+    params = dict(params, blocks=[params["blocks"][i] for i in kept])
+    if not isinstance(arch.layers, str):
+        params["arch"] = dataclasses.replace(
+            arch, layers=tuple(arch.layers[i] for i in kept))
+    state = {name: state[name][:2] if name in names else state[name]
+             for name in state if name != FIXED}
     chosen = _build_slot_fns(place)[2].__wrapped__.lower(
         params, embed_table, heads, state,
         jax.ShapeDtypeStruct((slots,), jnp.bool_), n,
@@ -1014,6 +1092,24 @@ def slot_layout_facts(state):
         for leaf in jax.tree.leaves(state)
         for shard in leaf.addressable_shards)
     return facts
+
+
+def slot_holds(params, state):
+    """What a slot of the dense ``state`` holds, for the books: the
+    model's blocks by kind (``block_kinds``) and a slot's bytes as
+    rows a position (all blocks, one position) and as fixed state."""
+    import collections
+
+    names = blocks.layer_names(blocks.arch_of(params),
+                               len(params["blocks"]))
+    slots = state["lengths"].shape[0]
+    rows = sum(leaf.nbytes // (slots * leaf.shape[-1])
+               for name in _kv_names(state) for leaf in state[name])
+    fixed = sum(leaf.nbytes // slots
+                for leaf in jax.tree.leaves(state.get(FIXED, {})))
+    return {"block_kinds": dict(collections.Counter(names)),
+            "slot_row_bytes_per_position": int(rows),
+            "slot_fixed_state_bytes": int(fixed)}
 
 
 def slot_attend_path(params, state):

@@ -58,17 +58,18 @@ def init_transformer_params(rng, n_blocks, embed, heads, vocab,
 # numerically equivalent by construction. GPT-2's helpers keep their
 # names here for the callers that import them from this module.
 
-def _forward(params, x, heads, seq_ax, sp_strategy):
-    """The plain full forward of whatever block ``params`` declare
-    (``blocks.arch_of``). Sequence parallelism is GPT-2's alone (it
-    has no position encoding to shard)."""
+def _forward(params, x, heads, seq_ax, sp_strategy, embed_table=None):
+    """The plain full forward of whatever blocks ``params`` declare
+    (``blocks.arch_of``, a kind a block). Sequence parallelism is
+    GPT-2's alone (it has no position encoding to shard).
+    ``embed_table`` is the head of a model that ties the two."""
     batch, t, embed = x.shape
     arch = blocks.arch_of(params)
     if seq_ax > 1:
         blocks.require_gpt2(params, "sequence-parallel training")
-    kind = blocks.attention_kind(arch)
     positions = jnp.broadcast_to(jnp.arange(t), (batch, t))
-    for blk in params["blocks"]:
+    for blk, kind in zip(params["blocks"], blocks.block_kinds(
+            arch, len(params["blocks"]))):
         if seq_ax > 1:
             q, rows = kind.project(arch, blk, x, heads, positions)
             spread = ring_attention if sp_strategy == "ring" \
@@ -77,8 +78,9 @@ def _forward(params, x, heads, seq_ax, sp_strategy):
             x = kind.out(blk, x, att.reshape(batch, t, embed))
             x, _ = blocks.ffn(arch, blk, x)
         else:
-            x, _ = blocks.block_forward(arch, blk, x, heads, positions)
-    return blocks.head(arch, params, x)
+            x, _ = blocks.block_forward(arch, blk, x, heads, positions,
+                                        kind=kind)
+    return blocks.head(arch, params, x, embed_table)
 
 
 def build_transformer_train_step(heads, mesh=None, learning_rate=0.1,

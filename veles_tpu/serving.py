@@ -501,6 +501,11 @@ class ServingHealth:
                        None) is not None:
                 # the routed experts' load, among the counters
                 snap["counters"].update(deploy.decoder.moe_counters())
+            holds = getattr(getattr(deploy, "decoder", None),
+                            "slot_holds", None)
+            if holds is not None:
+                # the blocks by kind and a slot's two kinds of state
+                snap["counters"].update(holds)
             paths = getattr(getattr(deploy, "decoder", None),
                             "attend_paths", None)
             if paths is not None:
@@ -838,16 +843,25 @@ class ContinuousDecoder:
         #: GPT-2's leaves refuses another kind by name, here, before
         #: anything is placed on the device
         arch = arch_of(params)
-        for asked, tier in ((paged, "paged=True (the page pool)"),
-                            (quantize not in (None, "none"),
-                             "quantize=%r" % (quantize,)),
-                            (mesh is not None, "mesh= (tensor-parallel "
-                             "serving)"),
-                            (aot is not None, "aot= (exported programs)"),
-                            (prefix_cache is not None, "prefix_cache= "
-                             "(prefix reuse over pages)")):
+        for asked, tier, lacks in (
+                (paged, "paged=True (the page pool)",
+                 "pages hold k/v rows of heads x head_dim: no latent "
+                 "row, no fewer K/V heads, no fixed state beside them"),
+                (quantize not in (None, "none"),
+                 "quantize=%r" % (quantize,),
+                 "quantize_params and the int8 cache know GPT-2's "
+                 "matrices and k/v leaves"),
+                (mesh is not None, "mesh= (tensor-parallel serving)",
+                 "slot_param_specs and slot_state_specs shard GPT-2's "
+                 "leaves over heads"),
+                (aot is not None, "aot= (exported programs)",
+                 "a bundle's geometry describes one k/v slab"),
+                (prefix_cache is not None,
+                 "prefix_cache= (prefix reuse over pages)",
+                 "a cached prefix is its pages: a fixed state would "
+                 "have to be snapshot with them")):
             if asked:
-                require_gpt2(params, tier)
+                require_gpt2(params, tier, lacks)
         #: quantize="int8" serves the W8A16 tier (weight matrices int8,
         #: dequant fused into the products via matmul_any);
         #: "int8-kv" additionally stores the SLOT KV cache as int8 with
@@ -982,9 +996,16 @@ class ContinuousDecoder:
         #: once per run, in /healthz and on the first decode.dispatch
         #: span. The page pool's is kv_pool's own affair.
         self.kv_layout = None
+        #: what a slot holds, among ``/healthz``'s counters: the
+        #: model's blocks by kind, and a slot's bytes as rows a
+        #: position and as fixed state (parallel/decode.py
+        #: slot_holds); None for the page pool
+        self.slot_holds = None
         if not self.paged:
-            from veles_tpu.parallel.decode import slot_layout_facts
+            from veles_tpu.parallel.decode import (slot_holds,
+                                                   slot_layout_facts)
             self.kv_layout = slot_layout_facts(self.state)
+            self.slot_holds = slot_holds(params, self.state)
         #: the routed experts' books (None for a model without): what
         #: the decode chunks counted (decode._slot_steps emits, beside
         #: the tokens, each expert block's assignments per expert at
