@@ -61,22 +61,42 @@ class TestMoeRowsSweep:
     def test_one_line_per_row_count_and_the_tilings_agree(self):
         """``python -m veles_tpu.scripts.moe_rows_sweep`` at toy
         lane-aligned sizes: the CPU's times mean nothing, the shape of
-        the answer and the two tilings' agreement do (the streamed
-        kernel runs interpreted here)."""
+        the answer and the three tilings' agreement do (the kernels
+        run interpreted here). The resident kernel stops where it is
+        told, the tiled one takes whole tiles only."""
         from veles_tpu.scripts.moe_rows_sweep import moe_rows_sweep
 
         out = moe_rows_sweep(rows=(32, 64), count=4, width=128,
-                             inner=128, top_k=2, steps=1, repeats=1)
+                             inner=128, top_k=2, tiles=(32, 64),
+                             resident_rows=32, steps=1, repeats=1)
         assert out["device"][0] == "cpu"
         assert [line["rows"] for line in out["rows"]] == [32, 64]
-        for line in out["rows"]:
-            assert set(line) == {"rows", "touched", "grouped_ms",
-                                 "streamed_ms", "streamed_gb_per_s",
-                                 "gap"}
+        kernels = (("streamed", "tiled_32"), ("tiled_32", "tiled_64"))
+        for line, names in zip(out["rows"], kernels):
+            want = {"rows", "touched", "grouped_ms"}
+            for name in names:
+                want |= {name + "_ms", name + "_gb_per_s", name + "_gap",
+                         name + "_tflops"}
+            assert set(line) == want
             assert line["touched"] == 4
             # tests/test_moe_streamed.py's bound, 2% of the widest
             # value, which is 2.8 and 3.1 at these sizes and seeds
-            assert line["gap"] < 0.02 * 2.5, line
+            for name in names:
+                assert line[name + "_gap"] < 0.02 * 2.5, line
+
+    def test_the_widths_are_arguments(self, capsys):
+        """The command line at another model's shape: one JSON line."""
+        import json
+
+        from veles_tpu.scripts import moe_rows_sweep
+
+        moe_rows_sweep.main(
+            "--count 2 --width 128 --inner 256 --top-k 1 --rows 32 "
+            "--tiles 16 --resident-rows 0".split())
+        out = json.loads(capsys.readouterr().out)
+        assert (out["count"], out["inner"], out["top_k"]) == (2, 256, 1)
+        assert "tiled_16_ms" in out["rows"][0]
+        assert "streamed_ms" not in out["rows"][0]
 
 
 class TestCompareSnapshots:

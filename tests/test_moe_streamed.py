@@ -1,9 +1,11 @@
-"""The routed experts' streaming kernel and the rule that chooses it.
+"""The routed experts' streaming kernels and the rule that chooses one.
 
 ``ops/moe.streamed_experts`` (a Pallas TPU kernel: each touched expert
-once, its matrices whole) in interpret mode, at lane-aligned toy
-widths, against ``jax.lax.ragged_dot`` (the other tiling of the same
-grouped products) and against a loop over the experts. The rule
+once, its matrices whole, rows and result resident) and
+``ops/moe.tiled_experts`` (the same with the rows and the result a
+tile at a time: any number of rows) in interpret mode, at lane-aligned
+toy widths, against ``jax.lax.ragged_dot`` (the third tiling of the
+same grouped products) and against a loop over the experts. The rule
 (``ops/moe.expert_path``) reads the platform and static shapes; the
 tests steer the platform (``moe.on_tpu``) and never the path itself,
 so what they run is what the TPU's rule picks, interpreted.
@@ -79,35 +81,45 @@ def _loop(h, chosen, weights, experts, held=None, live=None):
     return y
 
 
-def _both(h, chosen, weights, experts, monkeypatch, **kwargs):
+def _both(h, chosen, weights, experts, monkeypatch, path="streamed",
+          **kwargs):
     """``routed_experts`` as the CPU's rule runs it (``ragged_dot``)
     and as the TPU's rule does (the kernel, interpreted)."""
     rows = chosen.size
     assert moe.expert_path(rows, experts) == "grouped"
     grouped = moe.routed_experts(h, chosen, weights, experts, **kwargs)
     monkeypatch.setattr(moe, "on_tpu", lambda: True)
-    assert moe.expert_path(rows, experts) == "streamed"
+    assert moe.expert_path(rows, experts) == path
     streamed = moe.routed_experts(h, chosen, weights, experts, **kwargs)
     return grouped, streamed
 
 
-@pytest.mark.parametrize("case", ["uneven", "not_live", "rows_8",
-                                  "rows_256"])
+@pytest.mark.parametrize("case", [
+    "uneven", "not_live", "rows_8", "rows_256",
+    # over STREAM_MAX_ROWS, the tiled kernel: rows that are no whole
+    # number of tiles, an expert with every row and one with none,
+    # ``live`` leaving tokens out, a tile that every expert shares
+    "tiled_rows_602", "tiled_uneven", "tiled_not_live", "tiled_rows_1024"])
 def test_the_streamed_experts_agree_with_ragged_dot_and_the_loop(
         experts, monkeypatch, case):
     """(a) an expert with no row and one with every row; (b) ``live``
     leaving slots out; (d) 8 and 256 rows: the same ``y`` from the
     kernel, from ``ragged_dot`` and from the loop, the same ``load``."""
-    live = None
+    live, path = None, "tiled" if case.startswith("tiled") else "streamed"
     if case == "uneven":
         h, chosen, weights = _tokens(1, 41, never=3, always=5)
     elif case == "not_live":
         h, chosen, weights = _tokens(2, 6)
         live = jnp.asarray([True, False, True, True, False, False])
+    elif case == "tiled_uneven":
+        h, chosen, weights = _tokens(7, 290, never=3, always=5)
+    elif case == "tiled_not_live":
+        h, chosen, weights = _tokens(8, 300)
+        live = jnp.asarray(numpy.random.RandomState(8).rand(300) > 0.4)
     else:
-        h, chosen, weights = _tokens(3, int(case[5:]) // TOP_K)
+        h, chosen, weights = _tokens(3, int(case.split("_")[-1]) // TOP_K)
     (grouped, load), (streamed, load_streamed) = _both(
-        h, chosen, weights, experts, monkeypatch, live=live)
+        h, chosen, weights, experts, monkeypatch, path, live=live)
     want = _loop(h, chosen, weights, experts, live=live)
     numpy.testing.assert_allclose(numpy.asarray(streamed),
                                   numpy.asarray(want), **CLOSE)
@@ -120,18 +132,18 @@ def test_the_streamed_experts_agree_with_ragged_dot_and_the_loop(
     numpy.testing.assert_array_equal(
         numpy.asarray(load), numpy.bincount(
             numpy.asarray(chosen)[alive].ravel(), minlength=COUNT))
-    if case == "uneven":
-        assert load[3] == 0 and load[5] == 41
+    if case.endswith("uneven"):
+        assert load[3] == 0 and load[5] == len(h)
     if live is not None:
         assert not numpy.asarray(streamed)[~alive].any()
 
 
-@pytest.mark.parametrize("path", ["grouped", "streamed"])
+@pytest.mark.parametrize("path", ["grouped", "streamed", "tiled"])
 def test_the_held_quarters_add_up_to_the_layer(experts, monkeypatch,
                                                path):
     """(c) each quarter of the experts as a share of its own."""
-    h, chosen, weights = _tokens(4, 29)
-    if path == "streamed":
+    h, chosen, weights = _tokens(4, 290 if path == "tiled" else 29)
+    if path != "grouped":
         monkeypatch.setattr(moe, "on_tpu", lambda: True)
     whole, load = moe.routed_experts(h, chosen, weights, experts)
     total, loads = jnp.zeros_like(h), []
@@ -153,12 +165,16 @@ def test_the_held_quarters_add_up_to_the_layer(experts, monkeypatch,
                                      numpy.asarray(load))
 
 
-def test_bfloat16_operands_accumulate_and_gate_in_float32(on_the_chip):
+@pytest.mark.parametrize("path, tokens", [("streamed", 19),
+                                          ("tiled", 270)])
+def test_bfloat16_operands_accumulate_and_gate_in_float32(
+        on_the_chip, path, tokens):
     """As served: bfloat16 rows and matrices; the kernel's products
     stand as close to the float32 loop as ``ragged_dot``'s do."""
     experts = _experts(dtype=jnp.bfloat16)
-    h, chosen, weights = _tokens(5, 19)
+    h, chosen, weights = _tokens(5, tokens)
     h = h.astype(jnp.bfloat16)
+    assert moe.expert_path(chosen.size, experts) == path
     got, _ = moe.routed_experts(h, chosen, weights, experts)
     assert got.dtype == jnp.bfloat16
     want = _loop(h.astype(jnp.float32), chosen, weights,
@@ -169,17 +185,20 @@ def test_bfloat16_operands_accumulate_and_gate_in_float32(on_the_chip):
 
 
 @pytest.mark.parametrize("rows, calls", [
-    (moe.STREAM_MAX_ROWS, ("pallas_call", "ragged_dot")),
-    (moe.STREAM_MAX_ROWS + 1, ("ragged_dot", "pallas_call")),
+    (moe.STREAM_MAX_ROWS, ("moe_streamed_experts", "moe_tiled_experts")),
+    (moe.STREAM_MAX_ROWS + 1, ("moe_tiled_experts",
+                               "moe_streamed_experts")),
 ])
-def test_one_row_over_the_threshold_takes_ragged_dot(
+def test_one_row_over_the_threshold_takes_the_tiled_kernel(
         experts, on_the_chip, rows, calls):
-    """(d) the program that is traced holds the one and not the other,
-    and gives the loop's numbers either way."""
+    """(d) the program that is traced holds the one kernel and not the
+    other, never ``ragged_dot``, and gives the loop's numbers either
+    way."""
     h, chosen, weights = _tokens(6, rows, top_k=1)
     traced = str(jax.make_jaxpr(moe.routed_experts)(
         h, chosen, weights, experts))
     assert calls[0] in traced and calls[1] not in traced
+    assert "ragged_dot" not in traced
     got, load = moe.routed_experts(h, chosen, weights, experts)
     numpy.testing.assert_allclose(
         numpy.asarray(got),
@@ -190,10 +209,15 @@ def test_one_row_over_the_threshold_takes_ragged_dot(
 @pytest.mark.parametrize("platform, width, inner, rows, sharded, path", [
     ("cpu", 128, 256, 16, False, "grouped"),
     ("tpu", 128, 256, 16, False, "streamed"),
-    ("tpu", 128, 256, moe.STREAM_MAX_ROWS + 1, False, "grouped"),
+    ("tpu", 128, 256, moe.STREAM_MAX_ROWS + 1, False, "tiled"),
+    ("tpu", 128, 256, 32768, False, "tiled"),
+    ("cpu", 128, 256, 32768, False, "grouped"),
     ("tpu", 96, 256, 16, False, "grouped"),
     ("tpu", 128, 200, 16, False, "grouped"),
     ("tpu", 128, 256, 16, True, "grouped"),
+    ("tpu", 96, 256, 4096, False, "grouped"),
+    ("tpu", 128, 200, 4096, False, "grouped"),
+    ("tpu", 128, 256, 4096, True, "grouped"),
 ])
 def test_the_rule_reads_platform_widths_rows_and_sharding(
         monkeypatch, platform, width, inner, rows, sharded, path):
@@ -238,6 +262,70 @@ def test_the_visit_table_walks_the_touched_experts_once(load, n_rows):
         touched[-1] if touched else 0}
 
 
+@pytest.mark.parametrize("load, n_rows, tile", [
+    ([0, 3, 0, 0, 2, 0, 0, 1], 16, 16),
+    ([0, 0, 0, 0, 0, 0, 0, 0], 32, 16),
+    ([5, 5, 5, 5, 5, 5, 5, 5], 48, 16),
+    ([0, 0, 0, 0, 0, 0, 0, 64], 64, 16),
+    ([16, 16, 0, 32, 0, 0, 0, 0], 64, 16),
+    ([1, 40, 0, 0, 7, 0, 20, 0], 96, 32),
+    ([300, 0, 1, 0, 0, 0, 0, 211], 512, 128),
+])
+def test_the_tile_table_covers_each_experts_rows_tile_by_tile(
+        load, n_rows, tile):
+    """Each touched expert, in order, has one item for every tile its
+    rows reach and no other; an item's rows are its expert's; an item
+    past the last repeats its expert and tile and has no rows."""
+    ids, tiles, first, end = (numpy.asarray(a) for a in moe.tile_table(
+        jnp.asarray(load, jnp.int32), n_rows, tile))
+    assert len(ids) == n_rows // tile + min(len(load), n_rows) - 1
+    stops = numpy.cumsum(load)
+    want = [(e, t) for e, rows in enumerate(load) if rows
+            for t in range((stops[e] - rows) // tile,
+                           (stops[e] - 1) // tile + 1)]
+    live = len(want)
+    assert list(zip(ids[:live], tiles[:live])) == want
+    assert first[:live].tolist() == [stops[e] - load[e] for e, _ in want]
+    assert end[:live].tolist() == [stops[e] for e, _ in want]
+    assert not first[live:].any() and not end[live:].any()
+    assert set(zip(ids[live:], tiles[live:])) <= {
+        want[-1] if want else (len(load) - 1, 0)}
+    # every row of every expert lies in exactly one of its items
+    covered = numpy.zeros(n_rows, int)
+    for e, t, lo, hi in zip(ids, tiles, first, end):
+        covered[max(lo, t * tile):min(hi, (t + 1) * tile)] += 1
+    assert covered[:stops[-1]].tolist() == [1] * stops[-1]
+    assert not covered[stops[-1]:].any()
+
+
+@pytest.mark.parametrize("fault", [None, "boundary"])
+def test_a_boundary_tiles_rows_given_to_the_neighbour_are_caught(
+        experts, fault):
+    """The comparison the other tests rest on sees a tile that two
+    experts share cut at the wrong row: three rows of the one computed
+    by the other."""
+    rng = numpy.random.RandomState(9)
+    load = jnp.asarray([40, 0, 30, 58, 0, 0, 64, 0], jnp.int32)
+    rows = jnp.asarray(rng.randn(192, WIDTH), jnp.float32)
+    table = [numpy.array(a) for a in moe.tile_table(load, 192, 64)]
+    if fault:
+        ids, tiles, first, end = table
+        # the second item of a tile two experts share
+        at = next(i for i in range(1, len(ids)) if tiles[i] == tiles[i - 1]
+                  and ids[i] != ids[i - 1])
+        end[ids == ids[at - 1]] -= 3
+        first[ids == ids[at]] -= 3
+    got = moe.tiled_experts(rows, tuple(jnp.asarray(a) for a in table),
+                            experts, tile=64)
+    want = moe.grouped_experts(rows, load, experts)
+    gap = numpy.abs(numpy.asarray(got) - numpy.asarray(want)).max(-1)
+    if fault:
+        assert (gap > 0.1).sum() == 3 and (gap[37:40] > 0.1).all(), gap
+    else:
+        numpy.testing.assert_allclose(numpy.asarray(got),
+                                      numpy.asarray(want), **CLOSE)
+
+
 # -- the decoder books the path of every dispatch ------------------------------
 
 def _reference():
@@ -278,7 +366,7 @@ def test_the_decoder_books_the_path_of_every_dispatch(toy_model,
     """On the CPU every dispatch is grouped. With the platform steered
     and the threshold at this toy's chunk (4 slots x top-4 = 16 rows),
     every chunk streams, every admission (a bucket of 16 tokens or
-    more) stays grouped, and the tokens are the same."""
+    more) takes the tiled kernel, and the tokens are the same."""
     config, (params, table) = toy_model
     rng = numpy.random.RandomState(12)
     prompts = [rng.randint(0, config["vocab_size"], n).tolist()
@@ -287,7 +375,8 @@ def test_the_decoder_books_the_path_of_every_dispatch(toy_model,
     decoder, want = _serve(params, table, config, prompts)
     counts = decoder.dispatch_counts
     assert decoder.moe_counters()["moe_expert_path"] == {
-        "streamed": 0, "grouped": counts["admit"] + counts["chunk"]}
+        "streamed": 0, "tiled": 0,
+        "grouped": counts["admit"] + counts["chunk"]}
     monkeypatch.setattr(moe, "on_tpu", lambda: True)
     monkeypatch.setattr(moe, "STREAM_MAX_ROWS",
                         4 * config["num_experts_per_tok"])
@@ -299,7 +388,8 @@ def test_the_decoder_books_the_path_of_every_dispatch(toy_model,
     counts = decoder.dispatch_counts
     assert counts["admit"] >= 2 and counts["chunk"] >= 2
     assert decoder.moe_counters()["moe_expert_path"] == {
-        "streamed": counts["chunk"], "grouped": counts["admit"]}
+        "streamed": counts["chunk"], "tiled": counts["admit"],
+        "grouped": 0}
     assert got == want
 
 
@@ -328,12 +418,22 @@ def observability(tmp_path, monkeypatch):
     registry.enabled = was_metered
 
 
-def test_healthz_metrics_and_spans_say_the_path(toy_model, observability,
-                                                tmp_path):
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_healthz_metrics_and_spans_say_the_path(
+        toy_model, observability, tmp_path, monkeypatch, platform):
+    """Where the rule cannot take a kernel every dispatch says
+    ``grouped``; with the platform steered and the threshold at this
+    toy's chunk (16 rows), an admission says ``tiled`` and a chunk
+    ``streamed``, in ``/healthz``, in ``/metrics`` and on the spans."""
     from veles_tpu.observe.trace_export import export_chrome_trace
     from veles_tpu.serving import GenerateAPI
 
     config, (params, table) = toy_model
+    if platform == "tpu":
+        monkeypatch.setattr(moe, "on_tpu", lambda: True)
+        monkeypatch.setattr(moe, "STREAM_MAX_ROWS",
+                            4 * config["num_experts_per_tok"])
+    jax.clear_caches()
     api = GenerateAPI(params, table, config["n_head"], slots=4,
                       max_len=64, n_tokens=5, chunk=2, port=0)
     api.start()
@@ -352,11 +452,19 @@ def test_healthz_metrics_and_spans_say_the_path(toy_model, observability,
         counts = dict(api.decoder.dispatch_counts)
     finally:
         api.stop()
+        jax.clear_caches()
+    by_span = {"decode.admit": "tiled", "decode.dispatch": "streamed"} \
+        if platform == "tpu" else dict.fromkeys(
+            ("decode.admit", "decode.dispatch"), "grouped")
+    want = {"streamed": 0, "tiled": 0, "grouped": 0}
+    want[by_span["decode.admit"]] += counts["admit"]
+    want[by_span["decode.dispatch"]] += counts["chunk"]
+    assert counts["admit"] and counts["chunk"]
     said = health["counters"]["moe_expert_path"]
-    assert said == {"streamed": 0,
-                    "grouped": counts["admit"] + counts["chunk"]}
-    assert 'veles_moe_expert_dispatches_total{path="grouped"} %d' \
-        % said["grouped"] in metrics
+    assert said == want
+    for path, n in want.items():
+        assert ('veles_moe_expert_dispatches_total{path="%s"} %d'
+                % (path, n) in metrics) == bool(n)
     # the three numbers of a moe_by_lanes row stay what they were
     assert all(len(row) == 3
                for row in health["counters"]["moe_by_lanes"].values())
@@ -367,4 +475,5 @@ def test_healthz_metrics_and_spans_say_the_path(toy_model, observability,
                  if e["name"] in ("decode.admit", "decode.dispatch")]
     assert {e["name"] for e in spans} == {"decode.admit",
                                           "decode.dispatch"}
-    assert all(e["args"]["moe_expert_path"] == "grouped" for e in spans)
+    assert all(e["args"]["moe_expert_path"] == by_span[e["name"]]
+               for e in spans)

@@ -90,7 +90,11 @@ def _rule_cases():
         ("experts-decode-rows", "moe", True,
          experts(256, 2048, 768), "streamed"),
         ("experts-prefill-rows", "moe", True,
+         experts(1024, 2048, 768), "tiled"),
+        ("experts-prefill-rows-cpu", "moe", False,
          experts(1024, 2048, 768), "grouped"),
+        ("experts-prefill-off-lane", "moe", True,
+         experts(1024, 2000, 768), "grouped"),
         ("experts-off-lane", "moe", True,
          experts(256, 2048, 200), "grouped"),
         ("experts-cpu", "moe", False,

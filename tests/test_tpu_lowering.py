@@ -99,6 +99,21 @@ def kernel_cases():
              _sds((count, width, inner), "bfloat16"),
              _sds((count, width, inner), "bfloat16"),
              _sds((count, inner, width), "bfloat16"))))
+    # the tiled kernel at the largest part of an admission of each: 4
+    # prompts of 1,024 tokens x top-8 and x top-4; and at the smallest
+    # that takes it
+    for count, inner, many in ((256, 768, 32768), (256, 768, 1024),
+                               (32, 1792, 16384)):
+        cases.append((
+            "moe_tiled_experts_r%d_e%d_2048x%d" % (many, count, inner),
+            lambda r, load, gate, up, down: moe.tiled_experts(
+                r, moe.tile_table(load, r.shape[0]),
+                {"w_gate": gate, "w_up": up, "w_down": down},
+                interpret=False),
+            (_sds((many, width), "bfloat16"), _sds((count,), "int32"),
+             _sds((count, width, inner), "bfloat16"),
+             _sds((count, width, inner), "bfloat16"),
+             _sds((count, inner, width), "bfloat16"))))
     # the dense slab's ragged-length attend at the serving cell's
     # shapes: 16 slots, 16 heads of 64, lanes of 1,024, a span of 896
     from veles_tpu.ops import slab_attention
@@ -302,7 +317,7 @@ def test_chunk_program_uses_the_slab_in_place_on_v5e():
         + 0.01 * slab, result
 
 
-# -- the routed experts: streamed in the chunk, grouped in an admission -------
+# -- the routed experts: streamed in the chunk, tiled in an admission ---------
 
 _EXPERTS_CHILD = """
 import json, os, sys
@@ -352,15 +367,16 @@ def _compile_off_the_chip(config, programs, out):
         json.loads(line) for line in lines if line.startswith("{"))}
 
 
-def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
+def test_chunk_streams_the_experts_and_an_admission_tiles_them_on_v5e(
         tmp_path):
     """The benchmark's expert model at its published widths, compiled
     for a described v5e: the chunk program (32 slots x top-8 = 256
     rows an expert layer) holds the streaming kernel, once an expert
     layer, and no ``ragged-dot`` custom call; the smallest admission
-    (one prompt of 128 tokens: 1,024 rows) still holds the compiler's
-    grouped kernel and no other. The chunk's temporaries stay far
-    under one expert layer's matrices: no copy of the experts."""
+    (one prompt of 128 tokens: 1,024 rows) holds the tiled kernel once
+    an expert layer and neither of the others. Neither program's
+    temporaries come near one expert layer's matrices: no copy of the
+    experts."""
     said = _compile_off_the_chip("joyai-llm-flash",
                                  ["step:8:1408", "admit:128:1"], tmp_path)
     chunk = (tmp_path / "step_8_1408.txt").read_text()
@@ -374,9 +390,15 @@ def test_chunk_streams_the_experts_and_an_admission_groups_them_on_v5e(
         'mlp/moe.experts/moe_streamed_experts/pallas_call"') \
         == expert_layers
     assert "moe_streamed_experts" not in admit
-    assert admit.count('op_name="ragged-dot-none"') == 3 * expert_layers
+    assert "ragged-dot" not in admit
+    assert admit.count('custom_call_target="tpu_custom_call"') \
+        == expert_layers
+    assert admit.count(
+        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        == expert_layers
     one_layer = 3 * 256 * 2048 * 768 * 2
     assert said["step:8:1408"]["temp_bytes"] < 0.1 * one_layer, said
+    assert said["admit:128:1"]["temp_bytes"] < 0.1 * one_layer, said
 
 
 def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
@@ -388,8 +410,8 @@ def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
     an expert layer and nothing else of its kind; the slab's leaves
     are those of the three attention blocks alone and the chunk copies
     none of them; the tied head reads the embedding table where it
-    lies; an admission of four prompts of 1,024 tokens (~500 rows an
-    expert) holds the compiler's grouped kernel."""
+    lies; an admission of four prompts of 1,024 tokens (512 rows an
+    expert) holds the tiled kernel once an expert layer."""
     said = _compile_off_the_chip(
         "lfm2-8b-a1b", ["step:8:2048", "admit:1024:4"], tmp_path)
     chunk = (tmp_path / "step_8_2048.txt").read_text()
@@ -402,7 +424,10 @@ def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
         'mlp/moe.experts/moe_streamed_experts/pallas_call"') \
         == expert_layers
     assert "moe_streamed_experts" not in admit
-    assert admit.count('op_name="ragged-dot-none"') == 3 * expert_layers
+    assert "ragged-dot" not in admit
+    assert admit.count(
+        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        == expert_layers
     for scope in ("attn.qkv/conv.in", "attn.attend/conv.mix",
                   "attn.out/conv.out", "cache.append/cache.state",
                   "attn.qkv/gqa.norm", "attn.qkv/gqa.rope"):

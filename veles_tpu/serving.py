@@ -1020,7 +1020,8 @@ class ContinuousDecoder:
         self.moe_load = None
         if expert_blocks(params):
             self.moe_load = {"assignments": None, "by_lanes": {},
-                             "paths": {"streamed": 0, "grouped": 0}}
+                             "paths": {"streamed": 0, "tiled": 0,
+                                       "grouped": 0}}
         #: the dense slab's decode dispatches (chunks and single
         #: steps) by how their program attends the cache: ``kernel``
         #: (each slot over its own length, ops/slab_attention.py) or
