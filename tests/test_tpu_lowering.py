@@ -132,6 +132,19 @@ def kernel_cases():
             "slab_attend_s16_h16_d64_t1024_%s" % dtype, slab,
             (_sds((16, 1, HEADS, 64), dtype), leaf, leaf,
              _sds((16,), "int32"))))
+    # the retention state's decode step at the serving cell's shapes:
+    # 16 slots, 8 K/V heads of 128 with five query heads each, 8,320
+    # products a head, float32
+    from veles_tpu.ops import retention
+
+    wide = retention.features(128)
+    cases.append((
+        "retention_step_s16_g8_r5_d128",
+        lambda small, held, norm: retention._step_call(
+            small, held, norm, interpret=False, claim=64 << 20),
+        (_sds((16, 8, 5 + 3, 128), "float32"),
+         _sds((16, 8, 128, wide), "float32"),
+         _sds((16, 8, wide), "float32"))))
     return cases
 
 
@@ -448,3 +461,124 @@ def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
     for row in said.values():
         assert row["argument_bytes"] + row["temp_bytes"] < 15.75e9, row
         assert row["argument_bytes"] > weights + slab
+
+
+# -- a model with no row a position: the state held once -----------------------
+
+_RETENTION_CHILD = """
+import functools, json, os, re, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, %(repo)r)
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as exc:
+    print("NO-TOPOLOGY %%s" %% exc)
+    sys.exit(0)
+jax.config.update("jax_enable_compilation_cache", False)
+# this process sees the CPU: the rule and the kernel are told that the
+# programs compiled here are the chip's
+from veles_tpu.ops import retention
+retention.on_tpu = lambda: True
+retention.device_kind = lambda: topo.devices[0].device_kind
+retention.pallas_interpret = lambda: False
+from benchmark.harness import common
+from veles_tpu.parallel import blocks, decode
+chip = SingleDeviceSharding(topo.devices[0])
+config = common.load_json("benchmark/configs/brumby-14b-base.json")
+reference = common.load_module(config["reference"])
+serving = config["serving"]
+heads, slots = config["n_head"], serving["slots"]
+def on_chip(tree):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=chip), tree)
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+params, table = jax.eval_shape(
+    functools.partial(reference.init_params, 0, config))
+arch = blocks.arch_of(params)
+params, table = on_chip((params, table))
+e, v = table.shape[1], table.shape[0]
+state = on_chip(jax.eval_shape(functools.partial(
+    decode.init_slot_state, len(params["blocks"]), slots,
+    serving["max_len"], heads, e // heads, v, dtype=table.dtype,
+    arch=arch)))
+# the place as decode.slot_fns pins it for a state without K/V leaves
+place = dict.fromkeys(decode.CONTROL_LEAVES + (decode.FIXED,), chip)
+admit, _, chunk = decode._build_slot_fns(place)
+leaf = state[decode.FIXED]["S"][0]
+shape = ",".join(str(n) for n in leaf.shape)
+out = {"state_bytes": sum(
+    a.size * 4 for a in jax.tree.leaves(state[decode.FIXED]))}
+for name, lowered in (
+        ("step", chunk.__wrapped__.lower(
+            params, table, heads, state, spec((slots,), jnp.bool_), 8,
+            spec((), jnp.float32), False, 0, 0)),
+        ("admit", admit.__wrapped__.lower(
+            params, table, heads, state, spec((16,), jnp.int32),
+            spec((16, 1024, e), table.dtype),
+            on_chip(jax.eval_shape(
+                lambda: jax.random.split(jax.random.key(0), 16))),
+            spec((16,), jnp.int32)))):
+    compiled = lowered.compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    out[name] = {
+        "temp": memory.temp_size_in_bytes,
+        "arguments": memory.argument_size_in_bytes,
+        "alias": memory.alias_size_in_bytes,
+        "kernels": len(re.findall(
+            r"%%retention_step\\S* = .*custom_call_target=.tpu_custom_call",
+            text)),
+        # a whole state leaf made by a copy, or by a fusion that is no
+        # row's write in place: the state held twice
+        "whole_leaf_ops": [
+            line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= f32\\[%%s\\]\\S* (copy|fusion)\\(" %% shape, line)
+            and "dynamic-update-slice" not in line.split(" = ")[0]]}
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_the_retention_state_is_held_once_on_v5e():
+    """Compiled for a described v5e at the serving cell's sizes
+    (``brumby-14b-base``: 8 layers, 16 slots, 4.4 GB of float32 state
+    beside 8.4 GB of weights), with the place pinned as the decoder
+    pins it: the chunk program holds the state's kernel once a layer
+    and every state leaf is the result's buffer (donated, carried
+    through the scan, aliased: no copy at its entry or exit); the
+    largest admission writes each admitted row where the leaf lies (no
+    op of its own makes a whole leaf) and, with its temporaries, fits
+    a v5e's 16 GiB beside the embedding table. Skips where no TPU
+    compiler is installed."""
+    import json
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c", _RETENTION_CHILD % {"repo": REPO}],
+            env=env, capture_output=True, text=True, timeout=600,
+            cwd=REPO)
+    except subprocess.TimeoutExpired:
+        pytest.skip("the compile-only TPU client did not answer here")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    if any(line.startswith("NO-TOPOLOGY") for line in lines):
+        pytest.skip("no compile-only TPU topology here: %s" % lines[0])
+    (result,) = [json.loads(line[len("RESULT "):]) for line in lines
+                 if line.startswith("RESULT ")]
+    state = result["state_bytes"]
+    step, admit = result["step"], result["admit"]
+    assert step["kernels"] == 8, result
+    # every state leaf aliased (and the control leaves with them)
+    assert step["alias"] >= state and admit["alias"] >= state, result
+    assert not step["whole_leaf_ops"], step["whole_leaf_ops"][:3]
+    assert not admit["whole_leaf_ops"], admit["whole_leaf_ops"][:3]
+    assert step["temp"] < 0.25 * state, result
+    # weights + state (the arguments), the admission's temporaries and
+    # the embedding table it does not take: under the allocator's limit
+    table = 151936 * 5120 * 2
+    assert admit["arguments"] + admit["temp"] + table < 16.9e9, result

@@ -37,6 +37,16 @@ def pallas_interpret():
         "JAX_PLATFORMS to tpu or cpu" % backend)
 
 
+#: VMEM of a TensorCore by the device's kind, in MiB (Pallas's own
+#: table, ``jax._src.pallas.mosaic.tpu_info``, which answers for the
+#: default device only). A rule that sizes a kernel's claim reads it
+#: through its own module's ``device_kind`` (the seam a test steers);
+#: a kind that is not here has no kernel whose fit depends on VMEM:
+#: no rule guesses a chip's.
+VMEM_MIB = {"TPU v5 lite": 128, "TPU v5e": 128, "TPU v6 lite": 128,
+            "TPU v6e": 128, "TPU v5": 64, "TPU v5p": 64, "TPU7x": 64}
+
+
 def device_kind():
     """The default device's kind as JAX names it (``"TPU v5 lite"``,
     ``"cpu"``): what a rule reads where a kernel's fit depends on the

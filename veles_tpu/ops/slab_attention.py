@@ -61,7 +61,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from veles_tpu.ops.platform import device_kind, on_tpu, pallas_interpret
+from veles_tpu.ops.platform import (VMEM_MIB, device_kind, on_tpu,
+                                    pallas_interpret)
 
 #: positions a visit takes from each leaf. Swept on the v5e at the
 #: benchmark's serving shapes (16 slots, 16 heads of 64, bfloat16,
@@ -73,14 +74,6 @@ TILE = 256
 #: a positions-minor leaf can take)
 _PIECE = 128
 assert TILE % _PIECE == 0
-
-#: VMEM of a TensorCore by the device's kind, in MiB (Pallas's own
-#: table, ``jax._src.pallas.mosaic.tpu_info``, which answers for the
-#: default device only). A kind that is not here keeps
-#: ``_cache_attend``: the rule does not guess a chip's VMEM.
-_VMEM_MIB = {"TPU v5 lite": 128, "TPU v5e": 128, "TPU v6 lite": 128,
-             "TPU v6e": 128, "TPU v5": 64, "TPU v5p": 64, "TPU7x": 64}
-
 
 def vmem_claim():
     """VMEM the call claims, in bytes: 25/32 of the chip's (100 of a
@@ -98,7 +91,7 @@ def vmem_claim():
     32: on the v5e the step and the weights' waits read the same at
     this claim and at 64 MiB; at 120 MiB the weights come in line and
     the step is 13% slower)."""
-    mib = _VMEM_MIB.get(device_kind())
+    mib = VMEM_MIB.get(device_kind())
     return mib and (mib << 20) * 25 // 32
 
 

@@ -37,7 +37,11 @@ leaves at ``kv_heads * head_dim`` a position, a group's query heads
 attending their one K/V head as it lies. ``"conv"`` is a gated short
 convolution: it keeps no row a position but the last
 ``conv_taps - 1`` gated inputs of a slot, which a step reads and
-rewrites. The feed-forward kind is read off the block's own leaves:
+rewrites. ``"ret"`` is power retention (``ops/retention.py``):
+grouped-query attention's projection and a gate a K/V head, the score
+``(q·k)² / d`` under a learned decay in place of the softmax, so that
+a slot's whole past is a fixed float32 state a K/V head and no row a
+position at all. The feed-forward kind is read off the block's own leaves:
 ``w1`` GELU MLP, ``w_gate`` SwiGLU, ``router`` the routed experts of
 ``ops/moe.py`` (with a shared expert where the block has a ``shared``
 leaf).
@@ -46,7 +50,8 @@ The named scopes are the ones the per-layer readers know
 (``attn.qkv``, ``attn.attend``, ``attn.out``, ``mlp``, ``head``,
 ``cache.append``), with the kinds' own nested under them (``mla.q``,
 ``mla.kv``, ``mla.rope``, ``mla.absorb``, ``gqa.norm``, ``gqa.rope``,
-``conv.in``, ``conv.mix``, ``conv.out``, ``cache.state``, ``moe.*``).
+``conv.in``, ``conv.mix``, ``conv.out``, ``cache.state``, ``ret.gate``,
+``ret.phi``, ``ret.state``, ``ret.chunk``, ``moe.*``).
 """
 
 import dataclasses
@@ -54,7 +59,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from veles_tpu.ops import moe, slab_attention
+from veles_tpu.ops import moe, retention, slab_attention
 from veles_tpu.ops.attention import attention
 from veles_tpu.ops.quant import int8_cache_attend, matmul_any
 
@@ -75,6 +80,8 @@ class Arch:
     kv_heads: int = 0
     # gated short convolution: taps of the depthwise kernel
     conv_taps: int = 3
+    # power retention: the degree of the score (2 is the one there is)
+    power: int = 2
     # latent attention
     kv_rank: int = 0
     nope_dim: int = 0
@@ -142,18 +149,37 @@ def attend_path(params, state, sharding):
     return "xla"
 
 
-def require_gpt2(params, what, lacks=None):
+def state_path(params, state, sharding):
+    """How a decode step takes the fixed state of the retention blocks
+    through the chip (``ops/retention.state_path``: ``"kernel"`` or
+    ``"xla"``), ``state`` the slot state (arrays, tracers or shapes)
+    whose fixed leaves lie as ``sharding`` says; None for a model
+    without such a block. Asked like :func:`attend_path`: by the trace
+    of a step program, and by the decoder for its books."""
+    held = state.get("fixed", {}).get(Retention.leaf)
+    if not held:
+        return None
+    return retention.state_path(held[0], sharding)
+
+
+def require_gpt2(params, what, lacks=None, tier=None):
     """Refuse by name what only GPT-2's block has yet; ``lacks`` says
-    what the tier would need for another kind."""
+    what the tier would need for another kind, and a kind that knows
+    what ``tier`` (``"paged"``, ``"prefix"``, ``"int8"``, ``"mesh"``)
+    lacks for it says so itself (``Kind.lacks``)."""
     arch = arch_of(params)
     if arch != GPT2 or expert_blocks(params):
+        names = sorted(set(layer_names(arch, len(params["blocks"]))))
+        own = ["%r: %s" % (name, KINDS[name].lacks[tier])
+               for name in names if tier in _kind(name).lacks]
         raise ValueError(
             "%s is built for GPT-2's block (fused qkv, k/v leaves of "
             "heads x head_dim, GELU MLP) and this model declares "
-            "%s%s: that tier has no such kind yet%s" % (
+            "%s%s: that tier has no such kind yet%s%s" % (
                 what, kinds_said(arch),
                 " with routed experts" if expert_blocks(params) else "",
-                " (%s)" % lacks if lacks else ""))
+                " (%s)" % lacks if lacks else "",
+                "".join("; for kind " + text for text in own)))
 
 
 # -- norms ---------------------------------------------------------------------
@@ -291,6 +317,10 @@ class Kind:
     #: the first of the leaf names it declares (kinds that share it
     #: share their leaves' tuples in the slot state: ``leaf_ordinals``)
     leaf = "k"
+    #: what a tier that is built on GPT-2's leaves lacks for this kind,
+    #: by the tier's key (:func:`require_gpt2`), where the kind has
+    #: more to say than the tier's own refusal
+    lacks = {}
 
     @staticmethod
     def keep(arch, rows, live):
@@ -298,6 +328,12 @@ class Kind:
         position, all of them (the slab is written to the bucket's
         end, and a sequence's own appends overwrite the padding)."""
         return rows
+
+    @staticmethod
+    def put(leaf, slots, value):
+        """A fixed-state ``leaf`` (S, ...) with the state of ``slots``
+        (B,) set whole to ``value`` (B, ...): an admission's write."""
+        return leaf.at[slots].set(value)
 
 
 class FusedQKV(Kind):
@@ -523,19 +559,28 @@ class Grouped(Kind):
         return FusedQKV.leaves(arch, arch.kv_heads, head_dim, dtype)
 
     @staticmethod
-    def project(arch, blk, x, heads, positions):
+    def normed_qkv(arch, blk, x, heads, positions):
+        """``(h, q, k, v)``: the normed input, and its three
+        projections with the head norms and RoPE on q and k. Under
+        ``attn.qkv`` (the caller's)."""
         batch, t, _ = x.shape
+        h = rms_norm(x, blk["attn_norm"], arch.eps)
+        q = (h @ blk["wq"]).reshape(batch, t, heads, -1)
+        k = (h @ blk["wk"]).reshape(batch, t, arch.kv_heads, -1)
+        v = (h @ blk["wv"]).reshape(batch, t, arch.kv_heads, -1)
+        with jax.named_scope("gqa.norm"):
+            q = rms_norm(q, blk["q_norm"], arch.eps)
+            k = rms_norm(k, blk["k_norm"], arch.eps)
+        with jax.named_scope("gqa.rope"):
+            q = rope(q, positions, arch.rope_theta)
+            k = rope(k, positions, arch.rope_theta)
+        return h, q, k, v
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
         with jax.named_scope("attn.qkv"):
-            h = rms_norm(x, blk["attn_norm"], arch.eps)
-            q = (h @ blk["wq"]).reshape(batch, t, heads, -1)
-            k = (h @ blk["wk"]).reshape(batch, t, arch.kv_heads, -1)
-            v = (h @ blk["wv"]).reshape(batch, t, arch.kv_heads, -1)
-            with jax.named_scope("gqa.norm"):
-                q = rms_norm(q, blk["q_norm"], arch.eps)
-                k = rms_norm(k, blk["k_norm"], arch.eps)
-            with jax.named_scope("gqa.rope"):
-                q = rope(q, positions, arch.rope_theta)
-                k = rope(k, positions, arch.rope_theta)
+            _, q, k, v = Grouped.normed_qkv(arch, blk, x, heads,
+                                            positions)
             return q, {"k": k, "v": v}
 
     @staticmethod
@@ -657,11 +702,13 @@ class ShortConv(Kind):
         return {"conv": rows["conv"].astype(state["conv"][0].dtype)}
 
     @staticmethod
-    def step(arch, blk, q, rows, fixed, active):
+    def step(arch, blk, q, rows, fixed, active, sharding=None):
         """One new position a slot: ``(att (S, 1, E), fixed)``. The
         taps see what the slot carries (``fixed["conv"]`` (S,
         (taps-1)·E)) and the new gated input; the state rolls by one,
-        and a lane that is not ``active`` keeps what it had."""
+        and a lane that is not ``active`` keeps what it had.
+        ``sharding`` (where the state lies) is for kinds whose step
+        has a kernel to choose."""
         held, gated = fixed["conv"], rows["conv"][:, 0]
         back = arch.conv_taps - 1
         with jax.named_scope("attn.attend"), jax.named_scope("conv.mix"):
@@ -681,9 +728,114 @@ class ShortConv(Kind):
             return x + att.astype(x.dtype) @ blk["w_out"]
 
 
+class Retention(Kind):
+    """``"ret"``: power retention of degree 2 (``ops/retention.py``).
+    The projection is grouped-query attention's (``Grouped.normed_qkv``:
+    the same leaves ``attn_norm``, ``wq``, ``wk``, ``wv``, ``q_norm``,
+    ``k_norm``, ``wout``, the same scopes) plus a gate a K/V head,
+    ``log g = log sigmoid(h . wg + bg)`` in float32 (``wg`` (E, H_kv),
+    ``bg`` (H_kv,) float32). It keeps no row a position: a slot's
+    state is ``S`` ``(H_kv, D, D')`` and ``z`` ``(H_kv, D')``, float32
+    whatever the serving type (the recurrence sums thousands of
+    terms), ``D' = retention.features(D)``."""
+    fixed = True
+    leaf = "S"
+    lacks = {
+        "paged": "a page holds the k/v rows of some positions and the "
+                 "page table says which; this kind keeps no row a "
+                 "position, only a state a slot, which no table indexes",
+        "prefix": "a cached prefix is its pages; this kind's prefix is "
+                  "the state after it (S and z, 34 MB a slot a layer at "
+                  "head_dim 128), which would have to be snapshot when "
+                  "the prefix ends and copied into the slot on a hit",
+        "int8": "quantize_params knows wqkv, w1, w2 and the int8 cache "
+                "k/v rows; this kind has wq, wk, wv, wg and a float32 "
+                "state that a recurrence sums into, which int8 rows "
+                "cannot hold",
+        "mesh": "slot_state_specs shards k/v leaves over heads; this "
+                "kind's S and z would shard over K/V heads, and the "
+                "state's kernel (ops/retention.py) is a bare "
+                "pallas_call that lies on one device",
+    }
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        if arch.power != 2:
+            raise ValueError(
+                "power retention of degree %r: the feature map of "
+                "ops/retention.py is degree 2's (phi(q).phi(k) = "
+                "(q.k)^2); another degree has another map and another "
+                "state" % (arch.power,))
+        wide = retention.features(head_dim)
+        return {"S": ((arch.kv_heads, head_dim, wide), jnp.float32),
+                "z": ((arch.kv_heads, wide), jnp.float32)}
+
+    @staticmethod
+    def project(arch, blk, x, heads, positions):
+        """``(q, {"k", "v", "log_g"})``: ``log_g`` (B, T, H_kv)
+        float32, what a position leaves of the state before it."""
+        with jax.named_scope("attn.qkv"):
+            h, q, k, v = Grouped.normed_qkv(arch, blk, x, heads,
+                                            positions)
+            with jax.named_scope("ret.gate"):
+                gate = jnp.einsum(
+                    "bte,eg->btg", h, blk["wg"],
+                    preferred_element_type=jnp.float32) + blk["bg"]
+                return q, {"k": k, "v": v,
+                           "log_g": jax.nn.log_sigmoid(gate)}
+
+    @staticmethod
+    def attend_prompt(arch, blk, q, rows):
+        """The chunked form over right-padded rows (causal: a row's
+        padding changes nothing before it)."""
+        with jax.named_scope("attn.attend"), jax.named_scope("ret.chunk"):
+            return retention.prompt(q, rows["k"], rows["v"],
+                                    rows["log_g"])
+
+    @staticmethod
+    def keep(arch, rows, live):
+        """The state after each row's TRUE length (``live`` (B, T)
+        marks its own positions; None: all of them)."""
+        with jax.named_scope("attn.attend"), jax.named_scope("ret.state"):
+            return retention.state_after(rows["k"], rows["v"],
+                                         rows["log_g"], live)
+
+    @staticmethod
+    def columns(state, rows):
+        return {name: rows[name].astype(state[name][0].dtype)
+                for name in ("S", "z")}
+
+    @staticmethod
+    def put(leaf, slots, value):
+        """Row by row, each a write of its own where the leaf lies. A
+        scatter of rows this size (34 MB at ``head_dim`` 128) compiles
+        to a select over the WHOLE leaf, every slot's state read and
+        written to admit one (compiled for a v5e: 545 MB a layer)."""
+        def one(j, leaf):
+            at = (slots[j],) + (0,) * (leaf.ndim - 1)
+            return jax.lax.dynamic_update_slice(
+                leaf, jax.lax.dynamic_slice_in_dim(value, j, 1, 0), at)
+
+        return jax.lax.fori_loop(0, slots.shape[0], one, leaf)
+
+    @staticmethod
+    def step(arch, blk, q, rows, fixed, active, sharding=None):
+        """One new position a slot through the recurrence: ``(att (S,
+        1, H.D), fixed)``; a lane that is not ``active`` keeps its
+        state. The state's write is part of ``ret.state``."""
+        with jax.named_scope("attn.attend"):
+            att, held, norm = retention.step(
+                q[:, 0], rows["k"][:, 0], rows["v"][:, 0],
+                rows["log_g"][:, 0], fixed["S"], fixed["z"], active,
+                sharding)
+            return att.astype(q.dtype)[:, None], {"S": held, "z": norm}
+
+    out = Grouped.out
+
+
 #: a block's kind by the name a model declares for it (``Arch.layers``)
 KINDS = {"mha": FusedQKV, "mla": Latent, "gqa": Grouped,
-         "conv": ShortConv}
+         "conv": ShortConv, "ret": Retention}
 
 
 def _kind(name):

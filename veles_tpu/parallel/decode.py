@@ -134,7 +134,7 @@ def _prefill_forward(params, x, heads, length=None):
     themselves (``prefill``, the paged pool): ``(last_logits, k_all,
     v_all, cache_len)`` with ``k_all``/``v_all`` stacked
     (L, B, T, H, D)."""
-    blocks.require_gpt2(params, "a cache of k/v leaves")
+    blocks.require_gpt2(params, "a cache of k/v leaves", tier="paged")
     logits, rows, cache_len = _prompt_forward(params, x, heads, length)
     return (logits, jnp.stack([r["k"] for r in rows]),
             jnp.stack([r["v"] for r in rows]), cache_len)
@@ -245,7 +245,8 @@ def quantize_params(params):
     vocab head become ``{"q8": int8, "scale": f32}`` leaves that
     ``matmul_any`` dequantizes inside the product. Norms, biases and
     the caller's embed table stay in the serving float dtype."""
-    blocks.require_gpt2(params, "the int8 tiers' quantize_params")
+    blocks.require_gpt2(params, "the int8 tiers' quantize_params",
+                        tier="int8")
     qblocks = []
     for blk in params["blocks"]:
         qblk = dict(blk)
@@ -598,13 +599,16 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
             for kind, i, rows in zip(kinds, blocks.leaf_ordinals(kinds),
                                      rows_all):
                 if kind.fixed:      # a slot's state, set whole
-                    held, source, where = fixed, state[FIXED], (slots,)
-                else:               # positions [0, t) of a slot's lane
-                    held, source = fresh, state
-                    where = (slots, Ellipsis, slice(None, t))
-                for name, value in sorted(kind.columns(source,
+                    for name, value in sorted(kind.columns(
+                            state[FIXED], rows).items()):
+                        fixed[name][i] = kind.put(fixed[name][i], slots,
+                                                  value)
+                    continue
+                # positions [0, t) of a slot's lane
+                where = (slots, Ellipsis, slice(None, t))
+                for name, value in sorted(kind.columns(state,
                                                        rows).items()):
-                    held[name][i] = held[name][i].at[where].set(value)
+                    fresh[name][i] = fresh[name][i].at[where].set(value)
             new.update({name: tuple(leaves)
                         for name, leaves in fresh.items()})
             if fixed:
@@ -665,12 +669,22 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     arch = blocks.arch_of(params)
     kinds = blocks.block_kinds(arch, len(params["blocks"]))
     ordinals = blocks.leaf_ordinals(kinds)
-    max_len = state[names[0]][0].shape[-1]  # positions are minor
-    if span is None or span > max_len:
-        span = max_len
+    head_dim = embed_table.shape[1] // heads
+    # a model whose blocks all carry a fixed state has no row a
+    # position: no window to attend, no span, no column to stage
+    if names:
+        max_len = state[names[0]][0].shape[-1]  # positions are minor
+        if span is None or span > max_len:
+            span = max_len
+    else:
+        span = 0
     before = state["lengths"]
     ragged = blocks.attend_path(
-        params, state, place and place[names[0]].sharding) == "kernel"
+        params, state,
+        place[names[0]].sharding if place and names else None) == "kernel"
+    # where the fixed state lies, for the kinds whose step has a
+    # kernel to choose (blocks.state_path asks the same of the same)
+    fixed_place = place and place.get(FIXED)
     # what a slot has cached is what it held when the chunk began: said
     # as lengths where each slot attends over its own (an idle lane's
     # answer is no one's, so it reads nothing), else as a mask over the
@@ -750,7 +764,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                 # and rewrites it (an idle lane's stays)
                 att, new = kind.step(
                     arch, blk, q, rows,
-                    {name: fixed[name][i] for name in rows}, active)
+                    {name: fixed[name][i] for name in kind.leaves(
+                        arch, heads, head_dim, x.dtype)},
+                    active, fixed_place)
                 for name, value in new.items():
                     fixed[name][i] = value
             else:
@@ -910,12 +926,13 @@ def slot_fns(state):
     The check-then-insert is LOCKED: two tiers of the same place built
     concurrently (a breaker rebuild racing a new API) must share one
     jit object, not compile twice."""
-    lead = state[_kv_names(state)[0]][0]
+    names = _kv_names(state)
+    # (a model with no row a position: the control leaves say where)
+    lead = state[names[0]][0] if names else state["lengths"]
     concrete = isinstance(lead, jax.Array) \
         and not isinstance(lead, jax.core.Tracer)
-    key = (tuple((name, state[name][0].format)
-                 for name in _kv_names(state)),
-           FIXED in state) if concrete else None
+    key = (tuple((name, state[name][0].format) for name in names),
+           FIXED in state, lead.sharding) if concrete else None
     with _SLOT_FNS_LOCK:
         fns = _SLOT_FNS.get(key)
     if fns is not None:
@@ -1034,6 +1051,8 @@ def decide_slot_formats(params, embed_table, heads, state, n, span,
                               SingleDeviceSharding)
 
     names = _kv_names(state)
+    if not names:           # no row a position: no layout to decide
+        return {}
     if mesh is not None:
         specs = slot_state_specs(len(state[names[0]]),
                                  "k_scale" in state, axis=mesh_axis)
@@ -1117,8 +1136,18 @@ def slot_attend_path(params, state):
     :func:`slot_fns` builds for ``state`` (arrays) attend the cache.
     ``blocks.attend_path`` asked what ``_slot_steps`` asks it, with
     the place the programs are told: the one the K/V leaves lie in."""
+    names = _kv_names(state)
     return blocks.attend_path(
-        params, state, state[_kv_names(state)[0]][0].sharding)
+        params, state, state[names[0]][0].sharding if names else None)
+
+
+def slot_state_path(params, state):
+    """``"kernel"``, ``"xla"`` or None (no such block): how the step
+    programs that :func:`slot_fns` builds for ``state`` (arrays) take
+    the retention blocks' fixed state through the chip.
+    ``blocks.state_path`` asked what ``_slot_steps`` asks it, with the
+    place the programs are told: the one the fixed state lies in."""
+    return blocks.state_path(params, state, state["lengths"].sharding)
 
 
 def dispatch_program(fn, default):
@@ -1504,7 +1533,8 @@ def shard_slot_params(params, embed_table, heads, mesh, axis="model"):
     ``(params, embed_table)``; validates divisibility first."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    blocks.require_gpt2(params, "tensor-parallel serving (mesh=)")
+    blocks.require_gpt2(params, "tensor-parallel serving (mesh=)",
+                        tier="mesh")
     validate_slot_mesh(mesh, heads, params, embed_table, axis=axis)
     params = shard_slot_tree(params, mesh, slot_param_specs(params, axis))
     return params, jax.device_put(embed_table, NamedSharding(mesh, P()))

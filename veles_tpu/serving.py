@@ -511,6 +511,11 @@ class ServingHealth:
             if paths is not None:
                 # the dense slab's decode dispatches by how they attend
                 snap["counters"]["attend_path"] = dict(paths)
+            paths = getattr(getattr(deploy, "decoder", None),
+                            "state_paths", None)
+            if paths is not None:
+                # ... and by how they take the retention state through
+                snap["counters"]["state_path"] = dict(paths)
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -843,25 +848,26 @@ class ContinuousDecoder:
         #: GPT-2's leaves refuses another kind by name, here, before
         #: anything is placed on the device
         arch = arch_of(params)
-        for asked, tier, lacks in (
+        for asked, tier, lacks, which in (
                 (paged, "paged=True (the page pool)",
                  "pages hold k/v rows of heads x head_dim: no latent "
-                 "row, no fewer K/V heads, no fixed state beside them"),
+                 "row, no fewer K/V heads, no fixed state beside them",
+                 "paged"),
                 (quantize not in (None, "none"),
                  "quantize=%r" % (quantize,),
                  "quantize_params and the int8 cache know GPT-2's "
-                 "matrices and k/v leaves"),
+                 "matrices and k/v leaves", "int8"),
                 (mesh is not None, "mesh= (tensor-parallel serving)",
                  "slot_param_specs and slot_state_specs shard GPT-2's "
-                 "leaves over heads"),
+                 "leaves over heads", "mesh"),
                 (aot is not None, "aot= (exported programs)",
-                 "a bundle's geometry describes one k/v slab"),
+                 "a bundle's geometry describes one k/v slab", None),
                 (prefix_cache is not None,
                  "prefix_cache= (prefix reuse over pages)",
                  "a cached prefix is its pages: a fixed state would "
-                 "have to be snapshot with them")):
+                 "have to be snapshot with them", "prefix")):
             if asked:
-                require_gpt2(params, tier, lacks)
+                require_gpt2(params, tier, lacks, which)
         #: quantize="int8" serves the W8A16 tier (weight matrices int8,
         #: dequant fused into the products via matmul_any);
         #: "int8-kv" additionally stores the SLOT KV cache as int8 with
@@ -1031,6 +1037,21 @@ class ContinuousDecoder:
         #: whose attend is ``paged_kernel``'s affair.
         self.attend_paths = None if self.paged \
             else {"kernel": 0, "xla": 0}
+        #: the same books for the fixed state of the retention blocks
+        #: (``parallel/decode.slot_state_path``: the Pallas kernel of
+        #: ops/retention.py or ``jax.numpy``); None for a model
+        #: without such a block
+        self.state_paths = None
+        #: whether any block keeps a row a position. A model whose
+        #: blocks all carry a fixed state has no window to attend: one
+        #: step program whatever its slots hold, and no overshoot
+        self._has_rows = self.paged
+        if not self.paged:
+            from veles_tpu.parallel.decode import (_kv_names,
+                                                   slot_state_path)
+            self._has_rows = bool(_kv_names(self.state))
+            if slot_state_path(params, self.state) is not None:
+                self.state_paths = {"kernel": 0, "xla": 0}
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1450,6 +1471,9 @@ class ContinuousDecoder:
             said = self._book_moe_path(
                 len(rows) * bucket // prefill_parts(
                     arch_of(self.params), len(rows), bucket))
+            if self.state_paths is not None:
+                # the path the state this admission sets will take
+                said["state_path"] = self._state_path()
             # span entered OUTSIDE the timed window: the span's own
             # begin/end writes (file I/O when tracing) must not inflate
             # the host-overhead attribution they exist to explain
@@ -1876,7 +1900,10 @@ class ContinuousDecoder:
         """Static attended span for the next dispatch: the longest
         LIVE sequence plus the ``extra`` positions the dispatch will
         append, rounded up to the tile (one compiled program per tile
-        count) and clamped to ``max_len``."""
+        count) and clamped to ``max_len``; 0 for a model that keeps
+        no row a position (nothing is attended: one program)."""
+        if not self._has_rows:
+            return 0
         longest = max(self._slot_len[s] for s in self._slot_req)
         span = -(-(longest + extra) // self.tile) * self.tile
         return int(min(span, self.max_len))
@@ -1893,6 +1920,8 @@ class ContinuousDecoder:
         from veles_tpu.parallel.decode import (
             page_overshoot_tokens, span_overshoot_tokens,
             tile_pad_tokens)
+        if not self._has_rows:      # no window, nothing past its end
+            return 0
         if self.paged_kernel:
             return tile_pad_tokens(lens, self.page_size, chunk)
         if self.paged:
@@ -2155,7 +2184,24 @@ class ContinuousDecoder:
             "veles_decode_attend_dispatches_total", 1,
             labels={"path": path}, help="decode dispatches of the dense "
             "slab (chunks, steps) by how the program attends the cache")
-        return {"attend_path": path}
+        said = {"attend_path": path}
+        if self.state_paths is not None:
+            said["state_path"] = self._state_path()
+            self.state_paths[said["state_path"]] += 1
+            self.metrics.incr(
+                "veles_decode_state_dispatches_total", 1,
+                labels={"path": said["state_path"]},
+                help="decode dispatches (chunks, steps) by how the "
+                "program takes the retention blocks' state through "
+                "the chip")
+        return said
+
+    def _state_path(self):
+        """``kernel`` or ``xla``: how this decoder's step programs
+        take the retention blocks' fixed state through the chip."""
+        from veles_tpu.parallel.decode import slot_state_path
+
+        return slot_state_path(self.params, self.state)
 
     def moe_load_max_over_mean(self):
         """The busiest expert's assignments over the mean expert's, of
