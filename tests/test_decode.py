@@ -442,3 +442,103 @@ def test_tensor_parallel_rejects_indivisible(model):
     run = make_tp_generate(mesh, HEADS, n_tokens=3)
     with pytest.raises(ValueError):
         run(params, table, jnp.zeros((1, 4), jnp.int32))
+
+
+# -- the chunk's block write: the Pallas call against the loop ----------------
+
+def _reference_model(name):
+    """A benchmark model's reference at its rehearsal sizes, float32:
+    ``(params, table, heads)``."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        name.replace("-", "_"),
+        os.path.join(root, "benchmark/references/%s.py" % name))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    with open(os.path.join(root, "benchmark/configs/%s.json" % name)) \
+            as fin:
+        config = json.load(fin)
+    small = dict(config["rehearsal"])
+    config["serving"] = dict(config["serving"], **small.pop("serving"))
+    config.update(small)
+    params, table = jax.tree.map(lambda a: a.astype(jnp.float32),
+                                 reference.init_params(5, config))
+    return params, table, config["n_head"]
+
+
+def _two_chunks(params, table, heads):
+    """Prompts of 5 and 125 tokens in slots 0 and 1, a chunk of 4, one
+    of 126 admitted into slot 2 (its next chunk straddles a 128-lane
+    boundary; slot 3 stays idle), a second chunk: ``(the write path,
+    tokens (8, S), state)``."""
+    from veles_tpu.parallel import blocks
+    from veles_tpu.parallel.decode import (init_slot_state, slot_admit,
+                                           slot_step_many,
+                                           slot_write_path, split_emitted)
+
+    rng = numpy.random.RandomState(4)
+    vocab = table.shape[0]
+    state = init_slot_state(len(params["blocks"]), 4, 256, heads,
+                            table.shape[1] // heads, vocab,
+                            dtype=table.dtype, arch=blocks.arch_of(params))
+    tokens = []
+    for slots in ((0, 1), (2,)):
+        for slot in slots:
+            prompt = rng.randint(0, vocab, (1, (5, 125, 126)[slot]))
+            state = slot_admit(params, table, heads, state,
+                               jnp.int32(slot), table[jnp.asarray(prompt)])
+        active = jnp.arange(4) <= max(slots)
+        state, emitted = slot_step_many(params, table, heads, state,
+                                        active, 4, span=256)
+        tokens.append(numpy.asarray(split_emitted(emitted)[0]))
+    return slot_write_path(state, 4), numpy.concatenate(tokens), state
+
+
+@pytest.mark.parametrize("kind", ["mha", "latent", "gqa"])
+def test_the_write_kernel_leaves_the_loops_state(kind, model, monkeypatch):
+    """GPT-2's block, latent attention (JoyAI's) and grouped-query
+    attention beside short convolutions (LFM2's), each at a toy size:
+    two chunks with an admission between them give the same tokens and
+    a state equal bit for bit, whether the chunk's blocks go to the
+    slab by ``ops/slab_write``'s kernel (interpreted, the platform
+    steered) or by the loop."""
+    from veles_tpu.ops import slab_write
+
+    if kind == "mha":
+        params, table = model
+        heads = HEADS
+    else:
+        params, table, heads = _reference_model(
+            "joyai-llm-flash" if kind == "latent" else "lfm2-8b-a1b")
+    jax.clear_caches()
+    path, want, loop_state = _two_chunks(params, table, heads)
+    assert path == "loop"
+    monkeypatch.setattr(slab_write, "on_tpu", lambda: True)
+    monkeypatch.setattr(slab_write, "device_kind", lambda: "TPU v5 lite")
+    jax.clear_caches()
+    try:
+        path, got, state = _two_chunks(params, table, heads)
+    finally:
+        jax.clear_caches()
+    assert path == "kernel"
+    numpy.testing.assert_array_equal(got, want)
+    want_bits, got_bits = _bits(loop_state), _bits(state)
+    assert len(got_bits) == len(want_bits)
+    for one, other in zip(got_bits, want_bits):
+        assert one.shape == other.shape
+        assert numpy.array_equal(one, other)
+
+
+def _bits(state):
+    """Every leaf of a slot state as its bytes (the sampling keys as
+    their key data)."""
+    def raw(leaf):
+        if jnp.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+            leaf = jax.random.key_data(leaf)
+        return numpy.asarray(leaf).view(numpy.uint8)
+
+    return [raw(leaf) for leaf in jax.tree.leaves(state)]

@@ -309,6 +309,7 @@ def test_the_state_holds_no_row_a_position(config, model):
         == 2 * config["num_key_value_heads"] * wide * (d + 1) * 4
     assert decode.slot_attend_path(params, state) == "xla"
     assert decode.slot_state_path(params, state) == "xla"
+    assert decode.slot_write_path(state, 8) is None
     assert decode.decide_slot_formats(params, table, config["n_head"],
                                       state, 8, 64) == {}
     assert set(decode.slot_layout_facts(state)) == {"state_device_bytes"}
@@ -393,6 +394,9 @@ def test_admit_chunk_collect_retire_and_readmit_into_the_same_slot(
     assert decoder.attend_paths == {
         "kernel": 0, "xla": decoder.dispatch_counts["chunk"]}
     assert decoder.state_paths == decoder.attend_paths
+    # no block to write: no books, and a dispatch's span says none
+    assert decoder.write_paths is None
+    assert "block_write_path" not in decoder._book_attend_path(4)
     assert decoder.kv_layout.keys() == {"state_device_bytes"}
     assert step_many._cache_size() == programs + 1
 

@@ -517,6 +517,37 @@ def test_the_decoder_books_how_every_dispatch_attends(
     assert waste[booked] > 0 and waste[spared] == 0
     assert decoder.scope.debug_snapshot()["dispatches"][-1][1] == \
         ("kernel" if path == "kernel" else "dense")
+    # (the chunk's blocks go to the slab by the loop: the write's rule
+    # is not steered here)
+    assert health["counters"]["block_write_path"] == {
+        "kernel": 0, "loop": chunks}
+
+
+def test_the_decoder_books_how_every_chunk_writes_its_blocks(
+        toy, observability, tmp_path, monkeypatch):
+    """``/healthz`` and the ``decode.dispatch`` span say
+    ``block_write_path``, once a chunk: with the write's rule steered
+    onto the chip every chunk's blocks go by ``ops/slab_write``'s
+    kernel (interpreted here), and the answer is the loop's."""
+    from veles_tpu.ops import slab_write
+
+    jax.clear_caches()
+    want = _serve(toy, tmp_path, observability)[0]
+    monkeypatch.setattr(slab_write, "on_tpu", lambda: True)
+    monkeypatch.setattr(slab_write, "device_kind", lambda: "TPU v5 lite")
+    jax.clear_caches()
+    try:
+        tokens, health, _, spans, decoder, _ = _serve(
+            toy, tmp_path, observability)
+    finally:
+        jax.clear_caches()
+    chunks = decoder.dispatch_counts["chunk"]
+    assert chunks >= 2 and tokens == want
+    assert health["counters"]["block_write_path"] == {
+        "kernel": chunks, "loop": 0}
+    # (the recorder holds the first server's spans too: the loop's)
+    assert [e["args"]["block_write_path"] for e in spans] \
+        == ["loop"] * (len(spans) - chunks) + ["kernel"] * chunks
 
 
 def test_the_page_pool_has_no_attend_path_books(toy):
@@ -526,4 +557,4 @@ def test_the_page_pool_has_no_attend_path_books(toy):
     decoder = ContinuousDecoder(params, table, HEADS, slots=2, max_len=32,
                                 n_tokens=4, paged=True, page_size=8)
     assert decoder.attend_paths is None
-    assert decoder._book_attend_path() == {}
+    assert decoder._book_attend_path(4) == {}

@@ -132,6 +132,26 @@ def kernel_cases():
             "slab_attend_s16_h16_d64_t1024_%s" % dtype, slab,
             (_sds((16, 1, HEADS, 64), dtype), leaf, leaf,
              _sds((16,), "int32"))))
+    # the chunk's block write at the serving cells' leaves (two leaves
+    # a call: the kernel unrolls a leaf's code, the count only repeats
+    # it): GPT-2's k/v, JoyAI's latent kv, LFM2's grouped k/v
+    from veles_tpu.ops import slab_write
+
+    def write(before, leaves, staged):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(slab_write, "device_kind", lambda: "TPU v5 lite")
+            return slab_write.write_blocks(leaves, staged, before,
+                                           interpret=False)
+
+    for slots, width, max_len in ((16, 1024, 1024), (32, 576, 2048),
+                                  (64, 512, 2048)):
+        for dtype in ("bfloat16", "float32"):
+            cases.append((
+                "slab_write_s%d_w%d_t%d_%s" % (slots, width, max_len,
+                                               dtype), write,
+                (_sds((slots,), "int32"),
+                 [_sds((slots, width, max_len), dtype)] * 2,
+                 [_sds((slots, width, 8), dtype)] * 2)))
     # the retention state's decode step at the serving cell's shapes:
     # 16 slots, 8 K/V heads of 128 with five query heads each, 8,320
     # products a head, float32
@@ -173,8 +193,9 @@ print("KIND %%s" %% topo.devices[0].device_kind)
 on_chip = SingleDeviceSharding(topo.devices[0])
 import test_tpu_lowering
 for name, fn, args in test_tpu_lowering.kernel_cases():
-    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip)
-            for a in args]
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        args)
     try:
         jax.jit(fn).lower(*args).compile()
         print("COMPILED %%s" %% name)
@@ -227,6 +248,10 @@ from veles_tpu.ops import slab_attention
 slab_attention.on_tpu = lambda: True
 slab_attention.device_kind = lambda: topo.devices[0].device_kind
 slab_attention.pallas_interpret = lambda: False
+from veles_tpu.ops import slab_write
+slab_write.on_tpu = lambda: True
+slab_write.device_kind = lambda: topo.devices[0].device_kind
+slab_write.pallas_interpret = lambda: False
 from veles_tpu.parallel import decode
 chip = SingleDeviceSharding(topo.devices[0])
 E, HEADS, LAYERS, HIDDEN, VOCAB, MAX_LEN, SLOTS = %(sizes)r
@@ -274,6 +299,11 @@ prefetched = [line.strip()[:200] for line in text.splitlines()
 print("RESULT " + json.dumps({
     "slab_attend_calls": len(re.findall(
         r"%%slab_attend\\S* = .*custom_call_target=.tpu_custom_call", text)),
+    "slab_write_calls": len(re.findall(
+        r"%%slab_write\\S* = .*custom_call_target=.tpu_custom_call", text)),
+    # loops: the scan over the chunk's steps, and any other (a loop
+    # over the slots of per-slot appends would be one a leaf)
+    "whiles": text.count(" while("),
     "leaf_prefetches": prefetched,
     "custom_calls": text.count('custom_call_target="tpu_custom_call"'),
     "widened_windows": window,
@@ -288,11 +318,16 @@ print("RESULT " + json.dumps({
 """
 
 
-def test_chunk_program_uses_the_slab_in_place_on_v5e():
+@pytest.mark.parametrize("slots", [16, 32])
+def test_chunk_program_uses_the_slab_in_place_on_v5e(slots):
     """Compiled for a described v5e at gpt2-medium's sizes and 16 slots
-    (the benchmark's serving cell), the chunk program of
+    (the benchmark's serving cell; and 32, the size a re-sized cell
+    would hold: no whole-leaf copy there either, where
+    ``benchmark/configs/gpt2-medium.json`` ``assumed`` records 115 of an
+    older program), the chunk program of
     ``slot_step_many`` with the layout the decoder would pin: it holds
-    the ragged-length attend kernel once a block and no float32 window
+    the ragged-length attend kernel once a block, the block write's
+    kernel once (and no loop of per-slot writes), no float32 window
     of every slot at the span, its temporaries stay under 5% of the
     slab, no op of its own produces a whole K/V leaf (a copy of a
     layer, which a kernel's operand in another layout would cost) and
@@ -300,7 +335,7 @@ def test_chunk_program_uses_the_slab_in_place_on_v5e():
     compiler is installed."""
     import json
 
-    sizes = (1024, 16, 24, 4096, 50257, 1024, 16)
+    sizes = (1024, 16, 24, 4096, 50257, 1024, slots)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
         proc = subprocess.run(
@@ -318,7 +353,11 @@ def test_chunk_program_uses_the_slab_in_place_on_v5e():
     slab = result["slab_bytes"]
     layers = sizes[2]
     assert result["slab_attend_calls"] == layers, result
-    assert result["custom_calls"] == layers, result
+    # the chunk's blocks of all 48 leaves: one call, and no loop of
+    # per-slot writes beside the scan over the steps
+    assert result["slab_write_calls"] == 1, result
+    assert result["custom_calls"] == layers + 1, result
+    assert result["whiles"] == 1, result
     assert not result["leaf_prefetches"], result["leaf_prefetches"][:3]
     assert not result["widened_windows"], result["widened_windows"][:3]
     assert result["temp_bytes"] < 0.05 * slab, result

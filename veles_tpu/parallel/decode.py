@@ -30,6 +30,7 @@ from jax import lax
 from veles_tpu.ops.quant import (int8_cache_attend, matmul_any,
                                  quantize_int8)
 from veles_tpu.observe.xla_stats import instrument
+from veles_tpu.ops import slab_write
 from veles_tpu.parallel import blocks
 # ONE copy of the sublayer math, shared with the training-side full
 # forward — the equivalence the module contract promises is structural.
@@ -654,8 +655,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     what was cached before the chunk, and the staged columns up to its
     own, and only when the steps are done does each slot's block of
     ``n`` columns go to the leaf at the length the slot had when the
-    chunk began: one write per slot and leaf a chunk, from a loop over
-    the slots, so the program holds one such write a leaf.
+    chunk began: as :func:`block_write_path` says, one Pallas call
+    over the leaves (``ops/slab_write.write_blocks``), or one write per
+    slot and leaf from a loop over the slots.
 
     ``place`` is where the state lies, leaf name -> ``Format``, as
     :func:`slot_fns` pins it on the program it builds around this
@@ -679,9 +681,8 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     else:
         span = 0
     before = state["lengths"]
-    ragged = blocks.attend_path(
-        params, state,
-        place[names[0]].sharding if place and names else None) == "kernel"
+    where = place[names[0]].sharding if place and names else None
+    ragged = blocks.attend_path(params, state, where) == "kernel"
     # where the fixed state lies, for the kinds whose step has a
     # kernel to choose (blocks.state_path asks the same of the same)
     fixed_place = place and place.get(FIXED)
@@ -808,17 +809,35 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     if fixed:
         new_state[FIXED] = fixed
     with jax.named_scope("cache.append"):
+        write = slab_write.write_blocks \
+            if block_write_path(state, where, n) == "kernel" \
+            else slab_write.write_blocks_loop
+        written = iter(write(
+            [leaf for name in names for leaf in state[name]],
+            [block for name in names for block in staged[name]], before))
         for name in names:
-            def put(s, leaf, block):
-                at = (s,) + (0,) * (leaf.ndim - 2) + (before[s],)
-                return lax.dynamic_update_slice(
-                    leaf, lax.dynamic_slice_in_dim(block, s, 1, 0), at)
-
-            new_state[name] = tuple(
-                lax.fori_loop(0, slots,
-                              functools.partial(put, block=block), leaf)
-                for leaf, block in zip(state[name], staged[name]))
+            new_state[name] = tuple(next(written) for _ in state[name])
     return new_state, emitted
+
+
+def block_write_path(state, sharding, n):
+    """How a chunk of ``n`` steps over the slot state ``state``
+    (arrays, tracers or shapes), whose K/V leaves lie as ``sharding``
+    says (None: nobody knows), writes its staged blocks to the leaves:
+    ``"kernel"`` where the rule (``ops/slab_write.use_write_kernel``)
+    takes every leaf that holds a row a position, else ``"loop"``; the
+    int8-KV tier (leaves and scales alike) keeps the loop. None for a
+    state with no such leaf. The ONE question: :func:`_slot_steps`
+    asks it when a program is traced for a place, the decoder asks it
+    of the state it holds for its books."""
+    names = _kv_names(state)
+    if not names:
+        return None
+    if "k_scale" not in state and all(
+            slab_write.use_write_kernel(state[name][0], sharding, n)
+            for name in names):
+        return "kernel"
+    return "loop"
 
 
 def split_emitted(emitted):
@@ -1139,6 +1158,17 @@ def slot_attend_path(params, state):
     names = _kv_names(state)
     return blocks.attend_path(
         params, state, state[names[0]][0].sharding if names else None)
+
+
+def slot_write_path(state, n):
+    """``"kernel"``, ``"loop"`` or None (no row a position): how the
+    step programs that :func:`slot_fns` builds for ``state`` (arrays)
+    write a chunk of ``n`` steps' staged blocks to the slab.
+    :func:`block_write_path` asked what ``_slot_steps`` asks it, with
+    the place the programs are told: the one the K/V leaves lie in."""
+    names = _kv_names(state)
+    return block_write_path(
+        state, state[names[0]][0].sharding if names else None, n)
 
 
 def slot_state_path(params, state):

@@ -516,6 +516,11 @@ class ServingHealth:
             if paths is not None:
                 # ... and by how they take the retention state through
                 snap["counters"]["state_path"] = dict(paths)
+            paths = getattr(getattr(deploy, "decoder", None),
+                            "write_paths", None)
+            if paths is not None:
+                # ... and by how they write the chunk's blocks
+                snap["counters"]["block_write_path"] = dict(paths)
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -1042,6 +1047,12 @@ class ContinuousDecoder:
         #: ops/retention.py or ``jax.numpy``); None for a model
         #: without such a block
         self.state_paths = None
+        #: the same books by how the program writes a chunk's staged
+        #: blocks to the slab (``parallel/decode.slot_write_path``:
+        #: ``kernel``, ops/slab_write.py, or ``loop``, a write per slot
+        #: and leaf); None for the page pool and for a model with no
+        #: row a position
+        self.write_paths = None
         #: whether any block keeps a row a position. A model whose
         #: blocks all carry a fixed state has no window to attend: one
         #: step program whatever its slots hold, and no overshoot
@@ -1050,6 +1061,8 @@ class ContinuousDecoder:
             from veles_tpu.parallel.decode import (_kv_names,
                                                    slot_state_path)
             self._has_rows = bool(_kv_names(self.state))
+            if self._has_rows:
+                self.write_paths = {"kernel": 0, "loop": 0}
             if slot_state_path(params, self.state) is not None:
                 self.state_paths = {"kernel": 0, "xla": 0}
         self._layout_said = False
@@ -1977,7 +1990,7 @@ class ContinuousDecoder:
             self._slot_len[slot] += 1
         self.dispatch_counts["step"] += 1
         self._book_moe_path(self.slots)
-        slab_kernel = self._book_attend_path().get(
+        slab_kernel = self._book_attend_path(1).get(
             "attend_path") == "kernel"
         self.flight.note("step", rids=list(snapshot.values()))
         ledger_aot = None
@@ -2170,13 +2183,15 @@ class ContinuousDecoder:
             "admissions) by the tiling of the routed experts' products")
         return {"moe_expert_path": path}
 
-    def _book_attend_path(self):
-        """Book one decode dispatch of the dense slab by how its
-        program attends the cache; returns what the dispatch's span
-        says of it (nothing for the page pool)."""
+    def _book_attend_path(self, n):
+        """Book one decode dispatch of ``n`` steps of the dense slab
+        by how its program attends the cache and writes the chunk's
+        blocks to it; returns what the dispatch's span says of it
+        (nothing for the page pool)."""
         if self.attend_paths is None:
             return {}
-        from veles_tpu.parallel.decode import slot_attend_path
+        from veles_tpu.parallel.decode import (slot_attend_path,
+                                               slot_write_path)
 
         path = slot_attend_path(self.params, self.state)
         self.attend_paths[path] += 1
@@ -2185,6 +2200,9 @@ class ContinuousDecoder:
             labels={"path": path}, help="decode dispatches of the dense "
             "slab (chunks, steps) by how the program attends the cache")
         said = {"attend_path": path}
+        if self.write_paths is not None:
+            said["block_write_path"] = slot_write_path(self.state, n)
+            self.write_paths[said["block_write_path"]] += 1
         if self.state_paths is not None:
             said["state_path"] = self._state_path()
             self.state_paths[said["state_path"]] += 1
@@ -2248,7 +2266,7 @@ class ContinuousDecoder:
             said = {"kv_layout": json.dumps(self.kv_layout,
                                             sort_keys=True)}
         said.update(self._book_moe_path(self.slots))
-        said.update(self._book_attend_path())
+        said.update(self._book_attend_path(chunk))
         slab_kernel = said.get("attend_path") == "kernel"
         # span writes stay outside the timed window (see decode.admit)
         with self._span("paged.dispatch" if self.paged
