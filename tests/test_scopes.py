@@ -562,6 +562,47 @@ def test_the_program_writes_its_new_spans_when_tracing(traced, tmp_path,
             "_slot_admit_many"} <= noted
 
 
+def test_a_capture_leaves_the_handlers_wait_out(traced, tmp_path,
+                                                monkeypatch):
+    """In a profiler capture the annotated spans are the driver's: the
+    handler's ``serve.request``, which waits out the whole request,
+    would cover every gap the driver leaves. It is still recorded."""
+    from veles_tpu.core import logger as logger_mod
+
+    annotated = []
+
+    class Annotation:
+        def __init__(self, name):
+            annotated.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    recorder = EventRecorder()
+    recorder.open(str(tmp_path / "events.jsonl"))
+    monkeypatch.setattr(logger_mod, "_event_recorder", recorder)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    api = small_api()
+    api.start()
+    traced.enabled = traced.annotate_device = True
+    try:
+        post("http://127.0.0.1:%d/generate" % api.port,
+             {"tokens": [1, 2, 3]})
+    finally:
+        traced.enabled = traced.annotate_device = False
+        api.stop()
+        recorder.close()
+    names = {json.loads(line)["name"]
+             for line in open(str(tmp_path / "events.jsonl"))}
+    assert "serve.request" in names
+    assert "serve.request" not in annotated
+    assert {"decode.dispatch", "decode.collect",
+            "serve.drive_books"} <= set(annotated)
+
+
 # -- the repairs ---------------------------------------------------------
 
 def test_the_tick_programs_book_their_compiles(traced):

@@ -62,10 +62,13 @@ RECORD_PATH_FUNCTIONS = {
                               "FleetScope.book_update"},
     # the serving goodput observatory: every note_* sits on the
     # serving driver's per-dispatch hot path (and inject_waste on the
-    # chaos monkey's before_step, same thread); incident writes live
+    # chaos monkey's before_step, same thread); _on_gc runs inside
+    # any thread's garbage collection; incident writes live
     # in ServeScope.autopsy_tick, NOT declared
-    "observe/servescope.py": {"ServeScope._mark",
+    "observe/servescope.py": {"ServeScope._second",
+                              "ServeScope._mark",
                               "ServeScope.note_idle",
+                              "_on_gc",
                               "ServeScope.note_admit",
                               "ServeScope.note_dispatch",
                               "ServeScope.note_collect",

@@ -17,7 +17,7 @@ Design constraints, in order:
   Mounting ``/metrics`` on any HTTP surface enables it, so a bench or
   training run that never starts a server pays nothing;
 - **bridges, not rewrites**: the existing state holders
-  (``ServingHealth``, ``ContinuousDecoder.dispatch_counts``/``timings``,
+  (``ServingHealth``, ``ContinuousDecoder.dispatch_counts``,
   ``Loader`` epoch counters, ``Server.fleet_status()``) stay the source
   of truth; :func:`bridge` registers a weakly-referenced collector that
   re-publishes their snapshots into the registry at SCRAPE time — a
@@ -485,7 +485,7 @@ def get_metrics_registry():
 
 def publish_serving_health(registry, health):
     """ServingHealth.snapshot() -> veles_serving_* families."""
-    snap = health.snapshot()
+    snap = health.snapshot(seconds=False)
     name = snap.get("name", "serving")
     registry.set("veles_serving_ready", int(bool(snap.get("ready"))),
                  labels={"api": name},
@@ -515,17 +515,12 @@ def publish_serving_health(registry, health):
 
 
 def publish_decoder(registry, decoder):
-    """ContinuousDecoder dispatch/timing state -> veles_decode_*."""
+    """ContinuousDecoder dispatch and slot state -> veles_decode_*."""
     for kind, value in decoder.dispatch_counts.items():
         registry.counter_set(
             "veles_decode_dispatches_total", value,
             labels={"kind": kind},
             help="jitted dispatches on the slot path by call family")
-    for phase, seconds in decoder.timings.items():
-        registry.counter_set(
-            "veles_decode_host_seconds_total", seconds,
-            labels={"phase": phase.replace("_s", "")},
-            help="host-blocking wall seconds per slot call family")
     registry.set("veles_decode_slots_free", len(decoder._free),
                  help="slot-pool lanes currently free")
     registry.set("veles_decode_queue_depth", len(decoder._queue),

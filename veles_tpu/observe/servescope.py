@@ -42,6 +42,17 @@ the serving plane):
   trace: ``veles_tpu observe serve-trace [ARTIFACT | --live URL]`` +
   ``GET /debug/serve`` — ONE ROW PER SLOT, request lifetimes as spans,
   slot spans parented to their request's row so the chains connect.
+- the per-second books — the same notes also book into a row of the
+  whole wall-clock second they end in (``int(time.time())``, the
+  clock a load generator stamps requests with), :data:`SECOND_FIELDS`
+  in a ring of :data:`SECONDS_CAPACITY` seconds: admissions and the
+  prompts they carry, lane-steps dispatched and live, answer tokens
+  delivered, and the driver's milliseconds by what it was doing. An
+  interval that crosses a second's boundary is booked whole to the
+  second it ends in. :func:`watch_gc` adds Python's garbage
+  collections to the same rows. ``/healthz`` ``counters.serve_seconds``
+  carries the ring; the benchmark's ``scheduler.*`` readers sum a
+  window's whole seconds.
 
 Record-path discipline (``veles_tpu/analyze/registry.py`` declares
 these): every ``note_*`` method and :meth:`ServeScope.inject_waste`
@@ -61,12 +72,28 @@ tests/test_servescope.py (``make servescope``).
 """
 
 import collections
+import gc
 import json
 import os
 import time
 
 #: per-dispatch accounting ring capacity (drop-oldest)
 DISPATCH_RING_CAPACITY = 1024
+
+#: whole seconds the per-second books keep (drop-oldest)
+SECONDS_CAPACITY = 256
+
+#: a per-second row: admission dispatches, the live requests and the
+#: padded rows they carried; decode lane-steps dispatched (slots x
+#: chunk) and those of live lanes; answer tokens kept at collect; the
+#: driver's milliseconds admitting, enqueueing chunks, blocked on a
+#: chunk's readback, idle on an empty queue, and in the host gaps
+#: between those; garbage-collection pauses (any thread's) and how
+#: many; and the longest collect-to-dispatch gap of the driver
+SECOND_FIELDS = ("admits", "admitted", "admit_rows", "lane_steps",
+                 "live_lane_steps", "delivered", "admit_ms",
+                 "dispatch_ms", "device_wait_ms", "idle_ms", "host_ms",
+                 "gc_ms", "gc_count", "worst_pass_ms")
 
 #: completed slot-occupancy entries kept (drop-oldest)
 SLOT_RING_CAPACITY = 1024
@@ -145,6 +172,10 @@ class ServeScope:
         self.collects = 0
         self.injected = 0
         self._last_mark = None
+        #: end of the last collect, until the next dispatch starts
+        self._collect_end = None
+        #: whole wall second -> its row of SECOND_FIELDS, oldest first
+        self._books = {}
         #: per-dispatch ring: admit/dispatch/inject rows, drop-oldest
         self._ring = collections.deque(maxlen=DISPATCH_RING_CAPACITY)
         #: rid -> open occupancy entry; bounded drop-oldest
@@ -162,18 +193,38 @@ class ServeScope:
         self._breach_by_cause = {}
 
     # -- wall accounting helpers (record path) ----------------------------
-    def _mark(self, now, elapsed, component):
+    def _second(self):
+        """This wall second's row of the per-second books, made (and
+        the oldest second dropped past :data:`SECONDS_CAPACITY`) on
+        its first booking. ``setdefault`` keeps a row a collection
+        made meanwhile (:func:`watch_gc` books from any thread)."""
+        second = int(time.time())
+        row = self._books.get(second)
+        if row is None:
+            row = self._books.setdefault(
+                second, dict.fromkeys(SECOND_FIELDS, 0))
+            if len(self._books) > SECONDS_CAPACITY:
+                self._books.pop(next(iter(self._books)), None)
+        return row
+
+    def _mark(self, now, elapsed, component, field):
         """Book ``elapsed`` seconds ending at ``now`` into
         ``component`` and the gap since the previous mark into host
         time (the dispatch→collect / collect→dispatch bookkeeping
-        wall the driver spends between device-facing calls)."""
+        wall the driver spends between device-facing calls); the same
+        in milliseconds into this second's ``field`` and ``host_ms``.
+        Returns the second's row."""
         start = now - elapsed
+        row = self._second()
         if self._last_mark is not None:
             gap = start - self._last_mark
             if gap > 0:
                 self.seconds["host"] += gap
+                row["host_ms"] += gap * 1e3
         self.seconds[component] += elapsed
+        row[field] += elapsed * 1e3
         self._last_mark = now
+        return row
 
     def note_idle(self, waited, now=None):
         """The driver's queue-empty wait (record path): ``waited``
@@ -182,14 +233,8 @@ class ServeScope:
             return
         if now is None:
             now = time.monotonic()
-        waited = max(0.0, float(waited))
-        start = now - waited
-        if self._last_mark is not None:
-            gap = start - self._last_mark
-            if gap > 0:
-                self.seconds["host"] += gap
-        self.seconds["idle"] += waited
-        self._last_mark = now
+        self._mark(now, max(0.0, float(waited)), "idle", "idle_ms")
+        self._collect_end = None
 
     # -- dispatch accounting (record path) --------------------------------
     def note_admit(self, kind, bucket, group, rows, live_tokens,
@@ -202,7 +247,11 @@ class ServeScope:
             return
         if now is None:
             now = time.monotonic()
-        self._mark(now, float(elapsed), "prefill_compute")
+        row = self._mark(now, float(elapsed), "prefill_compute",
+                         "admit_ms")
+        row["admits"] += 1
+        row["admitted"] += int(group)
+        row["admit_rows"] += int(rows)
         self.admits += 1
         self.useful["prefill"] += int(live_tokens)
         self.waste["bucket_pad"] += int(pad_tokens)
@@ -224,11 +273,19 @@ class ServeScope:
             return
         if now is None:
             now = time.monotonic()
-        self._mark(now, float(elapsed), "decode_compute")
+        elapsed = float(elapsed)
+        row = self._mark(now, elapsed, "decode_compute", "dispatch_ms")
+        if self._collect_end is not None:
+            gap = (now - elapsed - self._collect_end) * 1e3
+            if gap > row["worst_pass_ms"]:
+                row["worst_pass_ms"] = gap
+            self._collect_end = None
         self.dispatches += 1
         chunk = int(chunk)
         active = int(active)
         slots = int(slots)
+        row["lane_steps"] += slots * chunk
+        row["live_lane_steps"] += active * chunk
         dead = max(0, slots - active) * chunk
         self.waste["dead_slot"] += dead
         self.waste["tile_pad" if kernel
@@ -247,12 +304,16 @@ class ServeScope:
     def note_collect(self, live_steps, kept, elapsed, now=None):
         """One chunk readback: ``live_steps`` lane-steps were
         dispatched live, ``kept`` tokens were delivered — the rest is
-        ``discard`` waste (record path)."""
+        ``discard`` waste; ``elapsed`` is the wait on the device, the
+        readback's own books land in the host gap (record path)."""
         if not self.enabled:
             return
         if now is None:
             now = time.monotonic()
-        self._mark(now, float(elapsed), "decode_compute")
+        row = self._mark(now, float(elapsed), "decode_compute",
+                         "device_wait_ms")
+        row["delivered"] += int(kept)
+        self._collect_end = now
         self.collects += 1
         self.useful["decode"] += int(kept)
         self.waste["discard"] += max(0, int(live_steps) - int(kept))
@@ -368,6 +429,14 @@ class ServeScope:
             out["dominant_cause"] = cause
         return out
 
+    def second_rows(self):
+        """The per-second books as ``/healthz`` ``counters.serve_seconds``
+        has them: ``{second: {field: value}}``, the second a string of
+        the whole wall second, milliseconds rounded to 0.01."""
+        return {str(second): {field: round(value, 2)
+                              for field, value in list(row.items())}
+                for second, row in list(self._books.items())}
+
     def slot_rows(self):
         """Completed + still-open occupancy entries (dict copies)."""
         rows = [dict(entry) for entry in list(self._slots)]
@@ -407,6 +476,8 @@ class ServeScope:
         self.collects = 0
         self.injected = 0
         self._last_mark = None
+        self._collect_end = None
+        self._books.clear()
         self._ring.clear()
         self._open.clear()
         self._slots.clear()
@@ -574,6 +645,52 @@ def get_serve_scope():
     """The process-global serving goodput observatory (fed by every
     ContinuousDecoder; breaker rebuilds keep accounting here)."""
     return _serve_scope
+
+
+#: the start of the collection in progress and its profiler annotation
+_gc_open = [None, None]
+#: the process tracer and ``jax.profiler``, once :func:`watch_gc` has
+#: run
+_tracer = _profiler = None
+
+
+def _on_gc(phase, _info, _clock=time.perf_counter):
+    """The ``gc.callbacks`` hook: times a collection start → stop into
+    the process scope's per-second books (``gc_ms``, ``gc_count``),
+    from whichever thread collected; collections never
+    overlap, so each field has one writer at a time. While a profiler
+    capture writes the spans (``Tracer.annotate_device``) the pause is
+    also a ``host.gc`` annotation, on the device ops' clock."""
+    if phase == "start":
+        if _serve_scope.enabled:
+            _gc_open[0] = _clock()
+            if _tracer.annotate_device:
+                _gc_open[1] = _profiler.TraceAnnotation("host.gc")
+                _gc_open[1].__enter__()
+        return
+    started, annotation = _gc_open
+    if started is None:
+        return
+    elapsed = _clock() - started
+    _gc_open[0] = _gc_open[1] = None
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    row = _serve_scope._second()
+    row["gc_ms"] += elapsed * 1e3
+    row["gc_count"] += 1
+
+
+def watch_gc():
+    """Time every garbage collection of this process into the process
+    scope (idempotent: one hook a process, installed by the first
+    serving decoder)."""
+    global _tracer, _profiler
+    if _on_gc not in gc.callbacks:
+        import jax.profiler
+
+        from veles_tpu.observe.tracing import get_tracer
+        _tracer, _profiler = get_tracer(), jax.profiler
+        gc.callbacks.append(_on_gc)
 
 
 def ensure_serve_rules(history):

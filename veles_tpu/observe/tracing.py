@@ -99,18 +99,22 @@ class Span:
     monotonic stamp (``mono`` — what the Chrome exporter orders by),
     and the recording thread (``tid``)."""
 
-    __slots__ = ("tracer", "name", "label", "trace_id", "span_id",
-                 "parent_id", "attrs", "_token", "_finished",
+    __slots__ = ("tracer", "name", "label", "capture", "trace_id",
+                 "span_id", "parent_id", "attrs", "_token", "_finished",
                  "_annotation", "_t0_mono")
 
     def __init__(self, tracer, name, trace_id, parent_id, label=None,
-                 **attrs):
+                 capture=True, **attrs):
         self.tracer = tracer
         self.name = name
         #: what the TraceAnnotation is called in a profiler capture
         #: (the recorded events keep ``name``): ``unit.run`` is one
         #: event name for every unit, ``unit.run.<unit>`` in a capture
         self.label = label or name
+        #: False keeps the span out of a profiler capture (it is still
+        #: recorded): a span that only waits would cover the gaps the
+        #: threads doing the work leave
+        self.capture = capture
         self.trace_id = trace_id
         self.span_id = _new_id()
         self.parent_id = parent_id
@@ -161,7 +165,7 @@ class Span:
 
     def __enter__(self):
         self._token = _current.set(self)
-        if self.tracer.annotate_device:
+        if self.capture and self.tracer.annotate_device:
             # put the span on the device trace's clock: a
             # TraceAnnotation named after it (``label``) is an event
             # of the capture's /host:CPU plane (--profile-dir)
@@ -225,8 +229,9 @@ class Tracer:
         ``(trace_id, span_id)`` pair (from a header/frame/holder), a
         Span, or None to inherit from this thread's current span.
         ``label=`` names the span's TraceAnnotation in a profiler
-        capture where that should say more than ``name``; it is no
-        attribute of the recorded events."""
+        capture where that should say more than ``name``, and
+        ``capture=False`` leaves the span out of the capture; neither
+        is an attribute of the recorded events."""
         if not self.enabled:
             return NULL_SPAN
         if parent is None:

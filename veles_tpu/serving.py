@@ -49,7 +49,7 @@ from veles_tpu.observe.metrics import (bridge, get_metrics_registry,
                                        publish_serving_health)
 from veles_tpu.observe.history import get_metric_history
 from veles_tpu.observe.reqledger import get_request_ledger
-from veles_tpu.observe.servescope import get_serve_scope
+from veles_tpu.observe.servescope import get_serve_scope, watch_gc
 from veles_tpu.observe.slo import get_slo_engine, observe_request
 from veles_tpu.observe.tracing import (NULL_SPAN, TRACE_HEADER,
                                        current_context,
@@ -470,7 +470,13 @@ class ServingHealth:
         return {"p50": round(p50 * 1000.0, 3),
                 "p95": round(p95 * 1000.0, 3), "count": n}
 
-    def snapshot(self):
+    def snapshot(self, seconds=True):
+        """The ``/healthz`` payload. ``seconds=False`` leaves out the
+        goodput observatory's per-second books: ``/metrics``
+        (``publish_serving_health``) takes its gauges from this
+        snapshot at every scrape, the metric history's sampler's once
+        a second among them, and has no use for a 256-second ring
+        copied and rounded each time."""
         with self._lock:
             snap = {"name": self.name, "ready": self._ready,
                     "breaker": self._breaker,
@@ -530,6 +536,11 @@ class ServingHealth:
             summary = scope.summary()
             if summary is not None:
                 snap["servescope"] = summary
+            if seconds:
+                # the driver's books by wall second (servescope.py
+                # SECOND_FIELDS), what the benchmark's scheduler.*
+                # readers sum over a window
+                snap["counters"]["serve_seconds"] = scope.second_rows()
         if slo is not None:
             summary = slo.summary()
             if summary is not None:
@@ -1143,11 +1154,6 @@ class ContinuousDecoder:
             # stay byte-identical for dense artifacts)
             self.dispatch_counts["admit_tail"] = 0
             self.dispatch_counts["admit_hit"] = 0
-        #: host-blocking wall seconds per call family (admit dispatches,
-        #: chunk dispatches, chunk readbacks) — feeds the bench's
-        #: prefill-ms and host-overhead keys
-        self.timings = {"admit_s": 0.0, "dispatch_s": 0.0,
-                        "collect_s": 0.0}
         #: set to a list to trace the dispatch/collect interleaving:
         #: entries ("admit", bucket, group), ("dispatch", chunk),
         #: ("collect", chunk) — the lag-1 pipelining assert hook
@@ -1168,8 +1174,10 @@ class ContinuousDecoder:
         #: one flag check per dispatch (the flight-ring discipline);
         #: breaker-rebuilt decoders keep accounting into the same
         #: scope (rids carry over, so the slot timeline never
-        #: cross-talks)
+        #: cross-talks); the process's garbage collections book into
+        #: its per-second rows beside the driver's
         self.scope = get_serve_scope()
+        watch_gc()
         #: request-truth plane (observe/reqledger.py): when a ledger is
         #: attached (GenerateAPI wires the process ledger; rebuilds
         #: re-attach via _decoder_kwargs), every dispatch books its
@@ -1500,7 +1508,6 @@ class ContinuousDecoder:
                     req_keys,
                     jnp.asarray([len(r[1]) for r in rows], jnp.int32))
                 elapsed = time.perf_counter() - t0
-            self.timings["admit_s"] += elapsed
             self.metrics.observe(
                 "veles_decode_admit_seconds", elapsed,
                 buckets=DECODE_BUCKETS,
@@ -1548,7 +1555,7 @@ class ContinuousDecoder:
 
     def _book_admit(self, kind, elapsed, group, bucket, rows=None,
                     lens=None):
-        """Shared admission bookkeeping: timings, metrics, flight ring,
+        """Shared admission bookkeeping: metrics, flight ring,
         dispatch log, the goodput observatory's waste decomposition
         (``rows`` = padded group size, ``lens`` = live prompt/tail
         lengths; a hit admission dispatches zero tokens) — one copy
@@ -1557,7 +1564,6 @@ class ContinuousDecoder:
         self._note_scope_admit(kind, bucket, len(group),
                                rows if rows is not None
                                else len(group), lens, elapsed)
-        self.timings["admit_s"] += elapsed
         self.metrics.observe(
             "veles_decode_admit_seconds", elapsed,
             buckets=DECODE_BUCKETS, labels={"kind": kind},
@@ -2070,7 +2076,6 @@ class ContinuousDecoder:
             if load is not None:
                 span.annotate(**self._book_moe_load(numpy.asarray(load),
                                                     len(snapshot)))
-        self.timings["collect_s"] += elapsed
         self.metrics.observe(
             "veles_decode_collect_seconds", elapsed,
             buckets=DECODE_BUCKETS,
@@ -2309,7 +2314,6 @@ class ContinuousDecoder:
                                      pages=pb,
                                      kernel=self.paged_kernel
                                      or slab_kernel)
-        self.timings["dispatch_s"] += elapsed
         self.metrics.observe(
             "veles_decode_dispatch_seconds", elapsed,
             buckets=DECODE_BUCKETS,
@@ -3663,8 +3667,11 @@ class GenerateAPI:
                 # gauges per tenant
                 tenant = str(self.headers.get("X-Veles-Tenant")
                              or "").strip()[:64]
-                with get_tracer().span("serve.request",
-                                       parent=parent) as req_span:
+                # the handler waits on its event for the whole request,
+                # so in a profiler capture its span would cover every
+                # gap the driver leaves: it stays out of the capture
+                with get_tracer().span("serve.request", parent=parent,
+                                       capture=False) as req_span:
                     self._serve_admitted(prompt, budget, deadline_s,
                                          req_span, tenant,
                                          parent[0] if parent else None)
