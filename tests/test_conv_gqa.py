@@ -441,8 +441,8 @@ def test_one_kind_for_every_block_is_a_model_of_one_kind():
         (blocks.FusedQKV, blocks.Grouped, blocks.Latent)) == (0, 1, 0)
     with pytest.raises(ValueError, match="for 3 blocks and has 2"):
         blocks.block_kinds(mixed, 2)
-    with pytest.raises(ValueError, match="no block kind 'swa'"):
-        blocks.block_kinds(blocks.Arch(layers=("swa",)), 1)
+    with pytest.raises(ValueError, match="no block kind 'lin'"):
+        blocks.block_kinds(blocks.Arch(layers=("lin",)), 1)
 
 
 @pytest.mark.parametrize("tier, named, lacks", [

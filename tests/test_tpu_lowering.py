@@ -17,7 +17,8 @@ Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
 128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows;
 the routed experts' streaming kernel at 256 rows over 256 experts of
 2048 x 768 and over 32 of 2048 x 1792; the dense slab's attend at 16 slots, 16 heads of 64 and
-lanes of 1,024.
+lanes of 1,024, and at 48 slots of 128 query heads over 8 K/V heads of 128 (a ring of 4,096 and
+rows of 8,704), with the ring's block write.
 """
 
 import os
@@ -132,16 +133,33 @@ def kernel_cases():
             "slab_attend_s16_h16_d64_t1024_%s" % dtype, slab,
             (_sds((16, 1, HEADS, 64), dtype), leaf, leaf,
              _sds((16,), "int32"))))
+
+    # and of grouped heads, the window cell's: 48 slots, 128 query heads
+    # over 8 K/V heads of 128, a ring of 4,096 and rows to 8,704
+    def grouped(q, k, v, lengths, *ring):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(slab_attention, "device_kind",
+                          lambda: "TPU v5 lite")
+            return slab_attention.slab_attend(
+                q, k, v, lengths, k.shape[-1], interpret=False,
+                ring=ring or None)
+
+    for name, length, ring in (("ring", 4096, 2), ("rows", 8704, 0)):
+        leaf = _sds((48, 8 * 128, length), "bfloat16")
+        cases.append((
+            "slab_attend_%s_s48_h128_g8_d128_t%d" % (name, length), grouped,
+            (_sds((48, 1, 128, 128), "bfloat16"), leaf, leaf)
+            + (_sds((48,), "int32"),) * (1 + ring)))
     # the chunk's block write at the serving cells' leaves (two leaves
     # a call: the kernel unrolls a leaf's code, the count only repeats
     # it): GPT-2's k/v, JoyAI's latent kv, LFM2's grouped k/v
     from veles_tpu.ops import slab_write
 
-    def write(before, leaves, staged):
+    def write(before, leaves, staged, rings=None):
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr(slab_write, "device_kind", lambda: "TPU v5 lite")
             return slab_write.write_blocks(leaves, staged, before,
-                                           interpret=False)
+                                           interpret=False, rings=rings)
 
     for slots, width, max_len in ((16, 1024, 1024), (32, 576, 2048),
                                   (64, 512, 2048)):
@@ -152,6 +170,14 @@ def kernel_cases():
                 (_sds((slots,), "int32"),
                  [_sds((slots, width, max_len), dtype)] * 2,
                  [_sds((slots, width, 8), dtype)] * 2)))
+    # the window cell's rings: a block written at the length modulo the
+    # ring, split where it wraps
+    cases.append((
+        "slab_write_ring_s48_w1024_t4096_bfloat16",
+        lambda before, leaves, staged: write(before, leaves, staged,
+                                             rings=[True, True]),
+        (_sds((48,), "int32"), [_sds((48, 1024, 4096), "bfloat16")] * 2,
+         [_sds((48, 1024, 8), "bfloat16")] * 2)))
     # the retention state's decode step at the serving cell's shapes:
     # 16 slots, 8 K/V heads of 128 with five query heads each, 8,320
     # products a head, float32

@@ -61,6 +61,74 @@ def use_flash(q, k):
             and q.shape[-1] % 128 == 0)
 
 
+#: the most bytes of float32 scores XLA's form of a grouped prompt
+#: attention may hold (``B x H x T x T x 4``); past them the splash
+#: kernel takes the call on the TPU. At 128 query heads a 256-position
+#: row holds 32 MiB of them, a 1,024-position row 512 MiB and an
+#: 8,192-position row 32 GiB
+SCORE_BYTES = 64 << 20
+#: the splash kernel's name in a compiled program (and a trace's ops)
+SPLASH_NAME = "splash_mqa_fwd"
+
+
+def grouped_attention(q, k, v, window=0):
+    """Causal attention of ``q`` (B, T, H, D) over ``k``/``v`` (B, T,
+    H_kv, D), query head ``i`` on K/V head ``i // (H / H_kv)``; with
+    ``window``, the query at ``t`` sees positions ``(t - window, t]``.
+    As :func:`prompt_path` says: XLA's form, which holds the scores,
+    or the splash kernel (``jax.experimental.pallas.ops.tpu.
+    splash_attention``, one MQA call a K/V head and row), which holds
+    none."""
+    batch, t, heads, head_dim = q.shape
+    scale = 1.0 / math.sqrt(head_dim)
+    if prompt_path(batch, t, heads, head_dim) == "kernel":
+        return _splash(q, k, v, window, scale)
+    local = (window - 1, 0) if 0 < window < t else None
+    return jax.nn.dot_product_attention(q, k, v, scale=scale,
+                                        is_causal=True,
+                                        local_window_size=local)
+
+
+def prompt_path(batch, t, heads, head_dim):
+    """``"kernel"`` or ``"xla"``: how :func:`grouped_attention` attends
+    ``batch`` prompts of ``t`` positions with ``heads`` query heads of
+    ``head_dim``. The kernel on the TPU where XLA's scores would pass
+    ``SCORE_BYTES``, ``head_dim`` is whole lane tiles and ``t`` whole
+    blocks of 128; XLA's form elsewhere. Read off shapes and the
+    platform, so the decoder books it as the program takes it."""
+    if on_tpu() and head_dim % 128 == 0 and t % 128 == 0 \
+            and batch * heads * t * t * 4 > SCORE_BYTES:
+        return "kernel"
+    return "xla"
+
+
+def _splash(q, k, v, window, scale):
+    """The splash kernel over every (row, K/V head): the group's query
+    heads against their one K/V head, blocks of up to 512 positions,
+    blocks the mask leaves empty skipped."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+
+    from veles_tpu.ops.platform import pallas_interpret
+
+    batch, t, heads, head_dim = q.shape
+    groups = k.shape[2]
+    mask = masks.LocalMask((t, t), (window - 1, 0), 0) \
+        if 0 < window < t else masks.CausalMask((t, t))
+    block = min(512, t)
+    kernel = splash.make_splash_mqa(
+        masks.MultiHeadMask([mask] * (heads // groups)),
+        block_sizes=splash.BlockSizes(block_q=block, block_kv=block,
+                                      block_kv_compute=block),
+        head_shards=1, q_seq_shards=1, interpret=pallas_interpret())
+    # (B, T, H, D) -> (B, H_kv, H / H_kv, T, D); K/V (B, H_kv, T, D)
+    qs = jnp.moveaxis((q * scale).astype(q.dtype).reshape(
+        batch, t, groups, heads // groups, head_dim), 1, 3)
+    out = jax.vmap(jax.vmap(kernel))(qs, jnp.moveaxis(k, 1, 2),
+                                     jnp.moveaxis(v, 1, 2))
+    return jnp.moveaxis(out, 3, 1).reshape(q.shape)
+
+
 def attention_block(x, w_qkv, b_qkv, w_out, b_out, heads, causal,
                     residual=False, precision_level=None):
     """The complete self-attention block — fused qkv projection →

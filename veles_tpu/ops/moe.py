@@ -36,7 +36,9 @@ record each constant was read from: PERF.md §5, the rows sweep,
 - ``"grouped"`` (``jax.lax.ragged_dot``: the compiler's own grouped
   kernel, 512 x 256 weight tiles): every platform but the TPU (the
   plain forward in tests), widths that are not lane multiples, leaves
-  sharded over a mesh.
+  sharded over a mesh, and experts whose three matrices, twice (the
+  one multiplied and the next on its way), do not fit the chip's VMEM
+  beside the rows (a 4096 x 4096 expert is 96 MB of them).
 
 The layer is told which experts it holds (``held = (first, count)``):
 it routes over all of them and computes the part of ``y`` that the
@@ -56,7 +58,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from veles_tpu.ops.platform import on_tpu, pallas_interpret
+from veles_tpu.ops.platform import (VMEM_MIB, device_kind, on_tpu,
+                                    pallas_interpret)
 
 #: the most assignments (rows: tokens x ``top_k``) a call may have and
 #: still keep rows and result resident in VMEM (the streaming kernel);
@@ -90,12 +93,15 @@ def route(h, router, bias, top_k, scale, eps=0.0):
     """``(chosen (N, top_k) int32, weights (N, top_k) float32)`` for
     tokens ``h`` (N, E): scores, bias add and top-k in float32 at full
     precision (a bfloat16 score ties where a float32 one does not).
-    ``eps`` (static) is what a model adds to the normalising sum."""
+    ``eps`` (static) is what a model adds to the normalising sum;
+    ``bias`` None: a model whose choice takes no bias."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             h.astype(jnp.float32), router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        _, chosen = lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32),
+            top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         total = jnp.sum(picked, -1, keepdims=True)
         weights = picked / (total + eps if eps else total) * scale
@@ -130,9 +136,25 @@ def expert_path(n_rows, experts):
     w_gate = experts["w_gate"]
     _, width, inner = w_gate.shape
     if on_tpu() and width % 128 == 0 and inner % 128 == 0 \
-            and not _sharded(w_gate):
+            and not _sharded(w_gate) and _fits(n_rows, experts):
         return "streamed" if n_rows <= STREAM_MAX_ROWS else "tiled"
     return "grouped"
+
+
+def _fits(n_rows, experts):
+    """Whether the kernel that ``n_rows`` assignments take claims no
+    more VMEM than the chip has (a chip whose VMEM is not known: as
+    the kernels always did)."""
+    mib = VMEM_MIB.get(device_kind())
+    if mib is None:
+        return True
+    rows = jax.ShapeDtypeStruct((0,), experts["w_gate"].dtype)
+    if n_rows <= STREAM_MAX_ROWS:
+        claim = _vmem_claim(rows, -(-n_rows // _ROW_TILE) * _ROW_TILE,
+                            experts, _ROW_TILE)
+    else:
+        claim = _vmem_claim(rows, ROW_TILE, experts, ROW_TILE)
+    return claim <= mib << 20
 
 
 def _whole_tiles(source, tile):
@@ -427,14 +449,22 @@ def routed_experts(h, chosen, weights, experts, held=None, live=None):
     return y.astype(h.dtype), load
 
 
-def expert_layer(h, p, top_k, scale, held=None, live=None, eps=0.0):
+def expert_layer(h, p, top_k, scale, held=None, live=None, eps=0.0,
+                 shared_scale=1.0):
     """The whole layer for tokens ``h`` (N, E): the held experts' part
-    plus the shared expert, where the layer has one (a ``shared``
-    leaf). Returns ``(y, load)``."""
-    chosen, weights = route(h, p["router"], p["router_bias"], top_k, scale,
-                            eps)
+    plus the shared experts, where the layer has them (a ``shared``
+    leaf: one SwiGLU, or several side by side as one of their summed
+    width, whose output is their sum), that sum times ``shared_scale``
+    (``1 / n`` where a model averages ``n``). A layer without a
+    ``router_bias`` leaf chooses by the scores alone. Returns ``(y,
+    load)``."""
+    chosen, weights = route(h, p["router"], p.get("router_bias"), top_k,
+                            scale, eps)
     y, load = routed_experts(h, chosen, weights, p["experts"], held, live)
     if "shared" in p:
         with jax.named_scope("moe.shared"):
-            y = y + swiglu(h, p["shared"])
+            shared = swiglu(h, p["shared"])
+            if shared_scale != 1.0:
+                shared = shared * jnp.asarray(shared_scale, shared.dtype)
+            y = y + shared
     return y, load

@@ -53,6 +53,7 @@ on the CPU patches this module's ``on_tpu`` and ``device_kind``; the
 kernel then runs interpreted.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -157,14 +158,21 @@ def _block_diagonal(heads, width):
         == lax.broadcasted_iota(jnp.int32, (heads, width), 0)
 
 
-def _walk_kernel(slot_ref, at_ref, total_ref, len_ref, q_ref, k_hbm, v_hbm,
-                 acc_out, m_out, l_out, k_buf, v_buf, sem, acc_ref, m_ref,
-                 l_ref, *, scale):
-    """All live tiles, in turn. ``q_ref`` (S, 1, H·D) and the three
-    results are whole in VMEM; ``k_hbm``/``v_hbm`` stay where they
-    lie and each visit's ``(H·D, tile)`` windows are copied, as far
-    as the slot's length reaches into them, into one of two buffers
-    while the other is multiplied."""
+def _walk_kernel(slot_ref, at_ref, total_ref, len_ref, *refs, scale,
+                 ring):
+    """All live tiles, in turn. ``refs``: in a ``ring`` two more
+    scalars a slot, where its ring starts (``before mod T``) and the
+    least age an entry it sees has (:func:`slab_attend`); then
+    ``q_ref`` (S, 1, H·D) or, grouped, a slot's query heads laid out
+    by their K/V head (S, H, H_kv·D), and the three results, whole in
+    VMEM; ``k_hbm``/``v_hbm`` stay where they lie and each visit's
+    ``(H_kv·D, tile)`` windows are copied, as far as the slot's length
+    reaches into them, into one of two buffers while the other is
+    multiplied."""
+    if ring:
+        start_ref, low_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, acc_out, m_out, l_out, k_buf, v_buf, sem,
+     acc_ref, m_ref, l_ref) = refs
     heads, width = acc_ref.shape
     tile = TILE
     total = total_ref[0]
@@ -221,18 +229,29 @@ def _walk_kernel(slot_ref, at_ref, total_ref, len_ref, q_ref, k_hbm, v_hbm,
             m_ref[...] = jnp.full_like(m_ref, -1e30)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        q = q_ref[s]                                        # (1, H·D)
+        q = q_ref[s]                            # (1, H·D) or (H, H_kv·D)
         k, v = k_buf[buf], v_buf[buf]                       # (H·D, tile)
-        diagonal = _block_diagonal(heads, width)
-        # (the select in float32: Mosaic has no bfloat16 mask layout)
-        q_heads = jnp.where(
-            diagonal, jnp.broadcast_to(q.astype(jnp.float32),
-                                       (heads, width)), 0.0).astype(q.dtype)
+        grouped = q.shape[0] > 1
+        if grouped:
+            q_heads = q
+        else:
+            diagonal = _block_diagonal(heads, width)
+            # (the select in float32: Mosaic has no bfloat16 mask layout)
+            q_heads = jnp.where(
+                diagonal, jnp.broadcast_to(q.astype(jnp.float32),
+                                           (heads, width)),
+                0.0).astype(q.dtype)
         scores = jnp.dot(q_heads, k,
                          preferred_element_type=jnp.float32) * scale
         position = at * tile + lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
-        scores = jnp.where(position < length, scores, -1e30)
+        seen = position < length
+        if ring:
+            # an entry's age: how far past the ring's start it lies
+            age = position - start_ref[s]
+            age = jnp.where(age < 0, age + k_hbm.shape[-1], age)
+            seen &= age >= low_ref[s]
+        scores = jnp.where(seen, scores, -1e30)
         # the online merge; a live tile holds a visible position, so
         # -1e30 underflows to an exact zero against the running max
         m_prev = m_ref[...]
@@ -249,10 +268,23 @@ def _walk_kernel(slot_ref, at_ref, total_ref, len_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when((at + 1) * tile >= length)
         def _emit():
-            # head h's sum is columns h·D.. of row h
-            acc_out[s] = jnp.sum(
-                jnp.where(diagonal, acc_ref[...], 0.0), axis=0,
-                keepdims=True)
+            if grouped:
+                # query head h's sum is columns c·D.. of row h, c its
+                # K/V head
+                head_dim = acc_out.shape[-1]
+                group = heads * head_dim // width
+                mine = lax.broadcasted_iota(
+                    jnp.int32, (heads, head_dim), 0) // group
+                acc_out[s] = sum(
+                    jnp.where(mine == c,
+                              acc_ref[:, c * head_dim:(c + 1) * head_dim],
+                              0.0)
+                    for c in range(width // head_dim))
+            else:
+                # head h's sum is columns h·D.. of row h
+                acc_out[s] = jnp.sum(
+                    jnp.where(diagonal, acc_ref[...], 0.0), axis=0,
+                    keepdims=True)
             m_out[s] = jnp.broadcast_to(m_ref[...], m_out.shape[1:])
             l_out[s] = jnp.broadcast_to(l_ref[...], l_out.shape[1:])
         return carry
@@ -260,21 +292,44 @@ def _walk_kernel(slot_ref, at_ref, total_ref, len_ref, q_ref, k_hbm, v_hbm,
     lax.fori_loop(0, total, visit_one, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "claim"))
-def _walk(visits, lengths, q, k, v, interpret, claim):
+@functools.partial(jax.jit, static_argnames=("interpret", "claim", "scope"))
+def _walk(visits, lengths, q, k, v, interpret, claim, ring=(), scope=None):
     """The ``pallas_call`` over :func:`visit_table`'s ``visits``. A
     function jitted on its own, so that a program that attends in
     every block lowers the kernel once and calls it: lowered once a
     block, 24 lowerings cost a chunk program 3.5 s of every set-up,
-    compile cache warm or not (PERF.md §6, PR 32)."""
+    compile cache warm or not (PERF.md §6). Grouped heads (the
+    leaves' ``H_kv·D`` less than the queries' ``H·D``) come laid out
+    by their K/V head: row ``h`` holds query head ``h`` at the columns
+    of its K/V head, zeros elsewhere. ``scope`` is the caller's own
+    scope, named again inside the jit: a reader of the scope table
+    knows an op by the innermost names of its op_name, and ``_walk``
+    would be one of them (None: no name)."""
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        return _walk_call(visits, lengths, q, k, v, interpret, claim,
+                          ring)
+
+
+def _walk_call(visits, lengths, q, k, v, interpret, claim, ring):
+    """:func:`_walk`'s body."""
     slots, _, heads, head_dim = q.shape
-    width = heads * head_dim
+    width = k.shape[1]
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     stats = jax.ShapeDtypeStruct((slots, heads, _STAT_LANES), jnp.float32)
+    if width == heads * head_dim:
+        rows, summed = q.reshape(slots, 1, width), (slots, 1, width)
+    else:
+        kv = width // head_dim
+        mine = jnp.arange(heads)[:, None] // (heads // kv) \
+            == jnp.arange(kv)[None, :]
+        rows = jnp.where(mine[None, :, :, None], q[:, 0, :, None, :],
+                         0).astype(q.dtype).reshape(slots, heads, width)
+        summed = (slots, heads, head_dim)
     acc, m, l = pl.pallas_call(
-        functools.partial(_walk_kernel, scale=head_dim ** -0.5),
+        functools.partial(_walk_kernel, scale=head_dim ** -0.5,
+                          ring=bool(ring)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(),
+            num_scalar_prefetch=4 + len(ring), grid=(),
             in_specs=[whole, pl.BlockSpec(memory_space=pltpu.HBM),
                       pl.BlockSpec(memory_space=pltpu.HBM)],
             out_specs=[whole, whole, whole],
@@ -284,29 +339,38 @@ def _walk(visits, lengths, q, k, v, interpret, claim):
                             pltpu.VMEM((heads, width), jnp.float32),
                             pltpu.VMEM((heads, 1), jnp.float32),
                             pltpu.VMEM((heads, 1), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((slots, 1, width), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(summed, jnp.float32),
                    stats, stats],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=claim),
         name="slab_attend",
         interpret=interpret,
-    )(*visits, lengths, q.reshape(slots, 1, width), k, v)
+    )(*visits, lengths, *ring, rows, k, v)
     return acc.reshape(slots, heads, head_dim), m[..., 0], l[..., 0]
 
 
-def slab_attend(q, k, v, lengths, span, interpret=None):
+def slab_attend(q, k, v, lengths, span, interpret=None, ring=None,
+                scope=None):
     """One query a slot against the first ``lengths[s]`` cached
     positions of slot ``s``. ``q`` (S, 1, H, D); ``k``, ``v`` one
-    block's leaves (S, H·D, T), whole; ``lengths`` (S,) int32, at most
-    ``span`` counted (static: the longest a slot can be). Returns the
-    softmax's parts, float32: ``(acc (S, H, D), m (S, H), l (S, H))``
-    with ``acc = sum_p exp(score_p - m) · v_p`` and ``l`` the same sum
-    without ``v``; a slot of length 0 gives ``(0, -1e30, 0)``.
-    ``interpret=None`` resolves from the platform."""
+    block's leaves (S, H·D, T), or of grouped heads (S, H_kv·D, T),
+    query head ``h`` on K/V head ``h // (H / H_kv)``; whole;
+    ``lengths`` (S,) int32, at most ``span`` counted (static: the
+    longest a slot can be). A ``ring`` (``(start, low)``, (S,) int32
+    each) sees of its first ``lengths[s]`` entries those whose age,
+    ``(entry - start) mod T``, is at least ``low``
+    (``parallel/decode.ring_visible``). Returns the softmax's parts,
+    float32: ``(acc (S, H, D), m (S, H), l (S, H))`` with ``acc =
+    sum_p exp(score_p - m) · v_p`` and ``l`` the same sum without
+    ``v``; a slot of length 0 gives ``(0, -1e30, 0)``. ``scope`` names
+    the kernel's ops once more inside its jit (the caller's innermost
+    scope). ``interpret=None`` resolves from the platform."""
     if interpret is None:
         interpret = pallas_interpret()
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, span)
     return _walk(visit_table(lengths, span), lengths, q, k, v,
-                 interpret=interpret, claim=vmem_claim())
+                 interpret=interpret, claim=vmem_claim(),
+                 ring=tuple(part.astype(jnp.int32) for part in ring or ()),
+                 scope=scope)
 
 
 def join_tail(q, parts, k_tail, v_tail, visible):

@@ -37,8 +37,16 @@ lane tiles, ``n`` at most a piece, known to lie on one device.
 Everything else keeps the loop. A test that wants the kernel on the
 CPU patches this module's ``on_tpu`` and ``device_kind``; the kernel
 then runs interpreted.
+
+A leaf may be a RING (a window layer's, ``parallel/blocks.Windowed``):
+position ``p`` lies at ``p mod T``, and a block goes to ``before[s] mod
+T``. Where it wraps, the kernel's second piece is the leaf's first
+(``T`` is whole pieces, so a piece never wraps), and the loop writes
+its columns each where it lies. The ring's write is ``cache.ring``
+inside ``cache.append``.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -111,7 +119,8 @@ def _block_start(before, max_len, n):
     """Where a slot's block of ``n`` columns starts: at ``before``,
     clamped onto the lane's end as ``lax.dynamic_update_slice`` clamps
     it (only a sequence past its budget, whose tokens the host
-    discards, stands there)."""
+    discards, stands there). A ring's starts at ``before mod
+    max_len``."""
     return jnp.clip(before, 0, max_len - n)
 
 
@@ -121,14 +130,15 @@ def _straddles(off, n):
     return off + n > _PIECE
 
 
-def _write_kernel(before_ref, *refs, count, n):
+def _write_kernel(before_ref, *refs, count, n, ring):
     """Slot ``program_id(0)``'s block of every leaf. ``refs``: the
     ``count`` leaves where they lie, the staged blocks of this slot in
     VMEM ``(count, 1, n, W)`` (the columns as rows, as the chunk's
     carry holds them), the leaves again as the results (the same
     buffers), then scratch: the pieces ``(count, W, 128)``, the blocks
     set at their lanes ``(count, W, 128)``, a ``(W, 128)`` pad and the
-    DMA semaphores ``(2, count)``. A leaf's DMAs are code of their own;
+    DMA semaphores ``(2, count)``. In a ``ring`` the piece after the
+    last is the first. A leaf's DMAs are code of their own;
     its arithmetic is one body in a loop over the leaves (a body a
     leaf, unrolled, is a kernel that Mosaic takes seconds to compile
     again in every program that holds it: PERF.md §6, PR 38)."""
@@ -137,14 +147,18 @@ def _write_kernel(before_ref, *refs, count, n):
     piece_buf, placed, pad, sem = refs[2 * count + 1:]
     s = pl.program_id(0)
     max_len = leaves[0].shape[-1]
-    start = _block_start(before_ref[s], max_len, n)
+    start = before_ref[s] % max_len if ring \
+        else _block_start(before_ref[s], max_len, n)
     base = start // _PIECE * _PIECE
     off = start - base
 
     def copies(p, back):
         """The DMAs of the ``p``-th piece from ``base`` of every leaf:
         into ``piece_buf`` or, ``back``, out of it."""
-        window = pl.ds(pl.multiple_of(base + p * _PIECE, _PIECE), _PIECE)
+        at = base + p * _PIECE
+        if ring:
+            at = at % max_len
+        window = pl.ds(pl.multiple_of(at, _PIECE), _PIECE)
         return [pltpu.make_async_copy(
             *((piece_buf.at[leaf], out[leaf].at[s, :, window]) if back
               else (leaves[leaf].at[s, :, window], piece_buf.at[leaf])),
@@ -193,12 +207,13 @@ def _write_kernel(before_ref, *refs, count, n):
             copy.wait()
         return carry
 
-    # (start + n <= T: a second piece lies inside the leaf)
+    # (start + n <= T: a second piece lies inside the leaf; in a ring
+    # it may be the first)
     lax.fori_loop(0, 1 + _straddles(off, n).astype(jnp.int32), piece, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "claim"))
-def _write(before, leaves, staged, interpret, claim):
+@functools.partial(jax.jit, static_argnames=("interpret", "claim", "ring"))
+def _write(before, leaves, staged, interpret, claim, ring=False):
     """The ``pallas_call`` over leaves of one shape and type, their
     staged columns stacked as rows ``(count, S, n, W)``. A function
     jitted on its own, so that a program lowers the kernel once
@@ -210,7 +225,7 @@ def _write(before, leaves, staged, interpret, claim):
     block = pl.BlockSpec((count, 1, n, width),
                          lambda s, before: (0, s, 0, 0))
     call = pl.pallas_call(
-        functools.partial(_write_kernel, count=count, n=n),
+        functools.partial(_write_kernel, count=count, n=n, ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(slots,),
             in_specs=[where] * count + [block],
@@ -230,17 +245,24 @@ def _write(before, leaves, staged, interpret, claim):
     # the scope again, inside the jit: a reader of the scope table
     # knows an op by the innermost names of its op_name, and
     # ``jit(_write)`` would be one of them
-    with jax.named_scope("cache.append"):
+    with jax.named_scope("cache.append"), _ring_scope(ring):
         return tuple(call(before, *leaves, staged))
 
 
-def write_blocks(leaves, staged, before, interpret=None):
+def _ring_scope(ring):
+    """``cache.ring`` around a ring's write, nothing around another."""
+    return jax.named_scope("cache.ring") if ring \
+        else contextlib.nullcontext()
+
+
+def write_blocks(leaves, staged, before, interpret=None, rings=None):
     """Slot ``s``'s ``n`` staged columns of each leaf, written at
     ``min(before[s], T - n)``: ``leaves`` (S, W, T) and ``staged``
     (S, W, n) sequences of the same length, leaf by leaf; ``before``
     (S,) int32. Returns the leaves written, in order. One call for the
     leaves of a shape and type, or as many as the claim's half holds
-    at once (one at every shape served). ``interpret=None`` resolves
+    at once (one at every shape served). ``rings`` says of each leaf
+    whether it is a ring (None: none is). ``interpret=None`` resolves
     from the platform."""
     if interpret is None:
         interpret = pallas_interpret()
@@ -249,9 +271,11 @@ def write_blocks(leaves, staged, before, interpret=None):
     before = before.astype(jnp.int32)
     groups = {}
     for i, leaf in enumerate(leaves):
-        groups.setdefault((leaf.shape, jnp.dtype(leaf.dtype)), []).append(i)
+        ring = bool(rings and rings[i])
+        groups.setdefault((leaf.shape, jnp.dtype(leaf.dtype), ring),
+                          []).append(i)
     out = list(leaves)
-    for kept in groups.values():
+    for (_, _, ring), kept in groups.items():
         n = staged[kept[0]].shape[-1]
         many = max(1, budget // _leaf_vmem(leaves[kept[0]], n)) \
             if budget else len(kept)
@@ -264,16 +288,17 @@ def write_blocks(leaves, staged, before, interpret=None):
                     before, [leaves[i] for i in part],
                     jnp.stack([jnp.swapaxes(staged[i], 1, 2)
                                for i in part]),
-                    interpret=interpret, claim=claim)):
+                    interpret=interpret, claim=claim, ring=ring)):
                 out[i] = leaf
     return out
 
 
-def write_blocks_loop(leaves, staged, before):
+def write_blocks_loop(leaves, staged, before, rings=None):
     """:func:`write_blocks` as XLA ops: for each leaf, a loop over the
     slots of one ``dynamic_update_slice`` each (which clamps the start
-    onto the lane's end). The path of everything the rule does not
-    give the kernel."""
+    onto the lane's end); a ring's block (``rings``) a write of its
+    columns each at its place. The path of everything the rule does
+    not give the kernel."""
     slots = before.shape[0]
 
     def put(s, leaf, block):
@@ -281,6 +306,15 @@ def write_blocks_loop(leaves, staged, before):
         return lax.dynamic_update_slice(
             leaf, lax.dynamic_slice_in_dim(block, s, 1, 0), at)
 
-    return [lax.fori_loop(0, slots, functools.partial(put, block=block),
-                          leaf)
-            for leaf, block in zip(leaves, staged)]
+    def put_ring(s, leaf, block):
+        at = (before[s] + jnp.arange(block.shape[-1])) % leaf.shape[-1]
+        return leaf.at[s, ..., at].set(jnp.moveaxis(block[s], -1, 0))
+
+    out = []
+    for i, (leaf, block) in enumerate(zip(leaves, staged)):
+        ring = bool(rings and rings[i])
+        with _ring_scope(ring):
+            out.append(lax.fori_loop(
+                0, slots, functools.partial(put_ring if ring else put,
+                                            block=block), leaf))
+    return out

@@ -41,17 +41,32 @@ rewrites. ``"ret"`` is power retention (``ops/retention.py``):
 grouped-query attention's projection and a gate a K/V head, the score
 ``(q·k)² / d`` under a learned decay in place of the softmax, so that
 a slot's whole past is a fixed float32 state a K/V head and no row a
-position at all. The feed-forward kind is read off the block's own leaves:
-``w1`` GELU MLP, ``w_gate`` SwiGLU, ``router`` the routed experts of
-``ops/moe.py`` (with a shared expert where the block has a ``shared``
-leaf).
+position at all. ``"swa"`` and ``"nope"`` are grouped-query attention
+with no head norm: ``"swa"`` rotates q and k (RoPE) and attends the last
+``Arch.window`` positions, the query's own included, from leaves
+``k_ring``/``v_ring`` that are a RING of that many positions a slot
+(position ``p`` at ``p mod window``), so that its cache stops growing
+at the window; ``"nope"`` takes no position encoding and attends the
+whole sequence from rows a position (``k_all``/``v_all``). The
+feed-forward kind is read off the block's own leaves: ``w1`` GELU MLP,
+``w_gate`` SwiGLU, ``router`` the routed experts of ``ops/moe.py``
+(with shared experts where the block has a ``shared`` leaf, their sum
+scaled by ``Arch.shared_scale``).
+
+A block is sequential, ``x + attn(n(x))`` and then ``x + ffn(n'(x))``
+of that sum, unless its model says ``Arch.parallel``: one norm of the
+block's input, which attention and the feed-forward both read, ``x +
+attn(n(x)) + ffn(n(x))`` (:func:`block_rest`). ``Arch.norm`` names the
+norm of every sublayer and of the head: RMSNorm, or LayerNorm with a
+gain and no bias.
 
 The named scopes are the ones the per-layer readers know
 (``attn.qkv``, ``attn.attend``, ``attn.out``, ``mlp``, ``head``,
 ``cache.append``), with the kinds' own nested under them (``mla.q``,
 ``mla.kv``, ``mla.rope``, ``mla.absorb``, ``gqa.norm``, ``gqa.rope``,
 ``conv.in``, ``conv.mix``, ``conv.out``, ``cache.state``, ``ret.gate``,
-``ret.phi``, ``ret.state``, ``ret.chunk``, ``moe.*``).
+``ret.phi``, ``ret.state``, ``ret.chunk``, ``swa.rope``, ``swa.ring``,
+``nope.attend``, ``cache.ring``, ``moe.*``).
 """
 
 import dataclasses
@@ -60,7 +75,8 @@ import jax
 import jax.numpy as jnp
 
 from veles_tpu.ops import moe, retention, slab_attention
-from veles_tpu.ops.attention import attention
+from veles_tpu.ops.attention import (attention, grouped_attention,
+                                     prompt_path)
 from veles_tpu.ops.quant import int8_cache_attend, matmul_any
 
 
@@ -78,6 +94,9 @@ class Arch:
     eps: float = 1e-5
     # grouped-query attention (RoPE's ``rope_theta`` is below)
     kv_heads: int = 0
+    #: the width of a head of ``"swa"``/``"nope"`` where it is not the
+    #: hidden size over the heads (0: it is)
+    head_dim: int = 0
     # gated short convolution: taps of the depthwise kernel
     conv_taps: int = 3
     # power retention: the degree of the score (2 is the one there is)
@@ -97,6 +116,20 @@ class Arch:
     #: prompt tokens a block takes at once in an admission (rows of a
     #: group beyond that go through in turn); 0: the whole group
     prefill_tokens: int = 0
+    #: prompt positions (rows x bucket) one admission takes at most; a
+    #: larger group of a bucket is admitted in several (0: no limit)
+    admit_tokens: int = 0
+    #: positions a ``"swa"`` block attends, the query's own included
+    window: int = 0
+    #: one norm of the block's input read by attention and the
+    #: feed-forward alike, both added to the input
+    parallel: bool = False
+    #: the norm of every sublayer and of the head: ``"rms"`` or
+    #: ``"layer"`` (LayerNorm with a gain and no bias)
+    norm: str = "rms"
+    #: what the shared experts' output is scaled by (1 / their number
+    #: where a model averages them)
+    shared_scale: float = 1.0
 
     def __post_init__(self, attention):
         layers = self.layers if attention is None else attention
@@ -141,12 +174,30 @@ def attend_path(params, state, sharding):
     (``attend_cached`` over the rectangular window). The ONE question:
     ``decode._slot_steps`` asks it when a program is traced for a
     place, the decoder asks it of the state it holds for its books."""
-    kinds = set(block_kinds(arch_of(params), len(params["blocks"])))
-    if all(kind.fixed or hasattr(kind, "attend_ragged") for kind in kinds) \
-            and "k" in state and "k_scale" not in state \
-            and slab_attention.use_slab_kernel(state["k"][0], sharding):
+    rows = [kind for kind in set(block_kinds(
+        arch_of(params), len(params["blocks"]))) if not kind.fixed]
+    if rows and all(hasattr(kind, "attend_ragged") for kind in rows) \
+            and "k_scale" not in state \
+            and all(slab_attention.use_slab_kernel(state[kind.leaf][0],
+                                                   sharding)
+                    for kind in rows):
         return "kernel"
     return "xla"
+
+
+def prompt_attend_path(params, batch, t, heads):
+    """How a block of grouped heads with no head norm (``"swa"``,
+    ``"nope"``) attends ``batch`` prompts of ``t`` positions at once
+    (``ops/attention.prompt_path``: ``"kernel"`` or ``"xla"``); None
+    for a model without such a block. Asked by the trace of an admit
+    program, through ``grouped_attention``, and by the decoder for its
+    books."""
+    for blk, kind in zip(params["blocks"], block_kinds(
+            arch_of(params), len(params["blocks"]))):
+        if issubclass(kind, Global):
+            return prompt_path(batch, t, heads,
+                               blk["wq"].shape[-1] // heads)
+    return None
 
 
 def state_path(params, state, sharding):
@@ -195,6 +246,23 @@ def rms_norm(x, w, eps):
     wide = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(wide * wide, -1, keepdims=True) + eps)
     return (wide * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, w, eps):
+    """``(x - mean x) / sqrt(var x + eps) * w``, no bias, the statistics
+    in float32."""
+    wide = x.astype(jnp.float32)
+    centred = wide - jnp.mean(wide, -1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(centred * centred, -1, keepdims=True)
+                          + eps)
+    return (centred * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def norm(arch, x, w):
+    """The model's norm (``Arch.norm``) of ``x`` with the gain ``w``."""
+    if arch.norm == "layer":
+        return layer_norm(x, w, arch.eps)
+    return rms_norm(x, w, arch.eps)
 
 
 # -- position encoding ---------------------------------------------------------
@@ -321,6 +389,15 @@ class Kind:
     #: by the tier's key (:func:`require_gpt2`), where the kind has
     #: more to say than the tier's own refusal
     lacks = {}
+    #: whether its rows a position lie in a ring (position ``p`` at
+    #: ``p mod`` the leaf's length) and not at ``p``
+    ring = False
+
+    @staticmethod
+    def positions(arch, max_len):
+        """The length of its leaves' position axis in a slab of
+        ``max_len`` positions a slot."""
+        return max_len
 
     @staticmethod
     def keep(arch, rows, live):
@@ -545,6 +622,25 @@ class Latent(Kind):
             return x + att.astype(x.dtype) @ blk["wout"]
 
 
+def _grouped_cached(arch, q, k, v, k_staged, v_staged, mask, mask_staged):
+    """Grouped heads' attend of one query a slot, ``q`` (S, 1, H, D),
+    over the leaves' window ``k``/``v`` (S, H_kv·D, T) and the staged
+    columns (S, H_kv·D, n) as they lie: ``(S, 1, H·D)``."""
+    slots, _, heads, head_dim = q.shape
+    groups = arch.kv_heads
+
+    def apart(leaf):
+        return leaf.reshape((slots, groups, -1, leaf.shape[-1]))
+
+    # (S, 1, H, D) -> (S, H // groups, groups, D)
+    q_all = jnp.swapaxes(
+        q.reshape(slots, groups, heads // groups, head_dim), 1, 2)
+    att = _cache_attend(q_all, apart(k), apart(v), mask,
+                        tail=(apart(k_staged), apart(v_staged),
+                              mask_staged))
+    return jnp.swapaxes(att, 1, 2).reshape(slots, 1, -1)
+
+
 class Grouped(Kind):
     """``"gqa"``: ``heads`` query heads over ``arch.kv_heads`` K/V
     heads (query head ``i`` attends K/V head ``i // (heads //
@@ -608,21 +704,10 @@ class Grouped(Kind):
         columns. A group's query heads stand where ``_cache_attend``
         has its queries and the K/V heads where it has its heads: each
         K/V row is read once, as it lies, for the whole group."""
-        slots, _, heads, head_dim = q.shape
-        groups = arch.kv_heads
-
-        def apart(leaf):
-            return leaf.reshape((slots, groups, -1, leaf.shape[-1]))
-
         with jax.named_scope("attn.attend"):
-            # (S, 1, H, D) -> (S, H // groups, groups, D)
-            q_all = jnp.swapaxes(
-                q.reshape(slots, groups, heads // groups, head_dim), 1, 2)
-            att = _cache_attend(
-                q_all, apart(read["k"]), apart(read["v"]), mask,
-                tail=(apart(staged["k"]), apart(staged["v"]),
-                      mask_staged))
-            return jnp.swapaxes(att, 1, 2).reshape(slots, 1, -1)
+            return _grouped_cached(arch, q, read["k"], read["v"],
+                                   staged["k"], staged["v"], mask,
+                                   mask_staged)
 
     @staticmethod
     def out(blk, x, att):
@@ -833,9 +918,146 @@ class Retention(Kind):
     out = Grouped.out
 
 
+class Global(Kind):
+    """``"nope"``: grouped-query attention (``heads`` query heads over
+    ``arch.kv_heads`` K/V heads) with no head norm and no position
+    encoding, over the whole sequence: rows a position in leaves of its
+    own (``names``), ``kv_heads * head_dim`` wide. Leaves of a block:
+    ``attn_norm``, ``wq`` (E, H·D), ``wk``/``wv`` (E, H_kv·D), ``wout``
+    (H·D, E); no bias."""
+    leaf = "k_all"
+    names = ("k_all", "v_all")
+    #: the scope of its attend, inside ``attn.attend``
+    scope = "nope.attend"
+    rotate = False
+    lacks = {
+        "paged": "pages hold k/v rows of every block alike; this model's "
+                 "window blocks keep a ring of their own length beside "
+                 "its global rows, which no page table indexes",
+        "prefix": "a cached prefix is its pages; a window block's ring "
+                  "holds only the last window of positions, which a "
+                  "prefix would have to carry as a snapshot",
+        "int8": "quantize_params knows wqkv, w1, w2 and GPT-2's k/v "
+                "leaves; this kind has wq, wk, wv, and a ring beside "
+                "its rows, which the int8 cache does not lay out",
+        "mesh": "slot_state_specs shards the k/v leaves over heads; "
+                "this kind's leaves and the window's rings would "
+                "shard over K/V heads, and the routed experts are "
+                "held here whole",
+    }
+
+    @classmethod
+    def leaves(cls, arch, heads, head_dim, dtype, quantized=False):
+        return dict.fromkeys(
+            cls.names, ((arch.kv_heads * (arch.head_dim or head_dim),),
+                        dtype))
+
+    @classmethod
+    def project(cls, arch, blk, x, heads, positions):
+        batch, t, _ = x.shape
+        with jax.named_scope("attn.qkv"):
+            h = norm(arch, x, blk["attn_norm"])
+            q = (h @ blk["wq"]).reshape(batch, t, heads, -1)
+            k = (h @ blk["wk"]).reshape(batch, t, arch.kv_heads, -1)
+            v = (h @ blk["wv"]).reshape(batch, t, arch.kv_heads, -1)
+            if cls.rotate:
+                with jax.named_scope("swa.rope"):
+                    q = rope(q, positions, arch.rope_theta)
+                    k = rope(k, positions, arch.rope_theta)
+            return q, dict(zip(cls.names, (k, v)))
+
+    @classmethod
+    def columns(cls, state, rows):
+        """GPT-2's fold of new rows ``(..., T, H_kv, D)`` into ``(...,
+        H_kv·D, T)``, behind ``Grouped.columns``' barrier (the same
+        compiler fault waits for a rotated ``k``)."""
+        rows = jax.lax.optimization_barrier(rows)
+        dtype = state[cls.names[0]][0].dtype
+        return {name: _positions_last(rows[name]).astype(dtype).reshape(
+            rows[name].shape[:-3] + (-1, rows[name].shape[-3]))
+            for name in cls.names}
+
+    @classmethod
+    def window(cls, arch):
+        """Positions a query attends, its own included; 0: all before
+        it."""
+        return 0
+
+    @classmethod
+    def attend_prompt(cls, arch, blk, q, rows):
+        """Causal attention over the prompt (within the window), the
+        K/V heads as they are (``ops/attention.grouped_attention``)."""
+        k, v = cls.names
+        with jax.named_scope("attn.attend"), jax.named_scope(cls.scope):
+            att = grouped_attention(q, rows[k], rows[v],
+                                    window=cls.window(arch))
+            return att.reshape(att.shape[:2] + (-1,))
+
+    @classmethod
+    def attend_cached(cls, arch, blk, q, read, staged, mask, mask_staged):
+        """Grouped heads over the leaves' window and the staged columns
+        (``_grouped_cached``), ``mask`` saying which of the window's
+        positions each slot sees."""
+        k, v = cls.names
+        with jax.named_scope("attn.attend"), jax.named_scope(cls.scope):
+            return _grouped_cached(arch, q, read[k], read[v], staged[k],
+                                   staged[v], mask, mask_staged)
+
+    @classmethod
+    def attend_ragged(cls, q, leaves, staged, lengths, span, mask_staged,
+                      ring=None):
+        """:meth:`attend_cached` where :func:`attend_path` says
+        ``kernel``: FusedQKV's, each K/V head's rows read once for its
+        group of query heads (``ops/slab_attention.slab_attend``), a
+        ring's entries as ``ring`` says; the staged columns, a K/V head
+        repeated for its group, joined in one softmax."""
+        slots, _, heads, head_dim = q.shape
+        k, v = cls.names
+        groups = leaves[k].shape[1] // head_dim
+
+        def apart(leaf):
+            return jnp.repeat(leaf.reshape(slots, groups, head_dim, -1),
+                              heads // groups, axis=1)
+
+        with jax.named_scope("attn.attend"), jax.named_scope(cls.scope):
+            att = slab_attention.join_tail(
+                q, slab_attention.slab_attend(q, leaves[k], leaves[v],
+                                              lengths, span, ring=ring,
+                                              scope=cls.scope),
+                apart(staged[k]), apart(staged[v]), mask_staged)
+            return att.reshape(slots, 1, -1)
+
+    out = Grouped.out
+
+
+class Windowed(Global):
+    """``"swa"``: ``"nope"``'s projection with RoPE on q and k
+    (``swa.rope``, at ``Arch.rope_theta``), attending the last
+    ``Arch.window`` positions, the query's own included. Its leaves are
+    a RING of ``min(window, max_len)`` positions a slot: position ``p``
+    lies at ``p mod`` that length, a chunk's block is written there
+    (split where it wraps: ``ops/slab_write.py``), and a step sees an
+    entry only while it holds a position inside the window
+    (``decode.ring_visible``)."""
+    leaf = "k_ring"
+    names = ("k_ring", "v_ring")
+    scope = "swa.ring"
+    rotate = True
+    ring = True
+
+    @staticmethod
+    def positions(arch, max_len):
+        return min(arch.window, max_len)
+
+    @classmethod
+    def window(cls, arch):
+        return arch.window
+
+
 #: a block's kind by the name a model declares for it (``Arch.layers``)
 KINDS = {"mha": FusedQKV, "mla": Latent, "gqa": Grouped,
-         "conv": ShortConv, "ret": Retention}
+         "conv": ShortConv, "ret": Retention, "swa": Windowed,
+         "nope": Global}
 
 
 def _kind(name):
@@ -881,6 +1103,19 @@ def leaf_ordinals(kinds):
 
 # -- feed-forward and head -----------------------------------------------------
 
+def _ffn_out(arch, blk, h, live):
+    """The feed-forward's output for the normed tokens ``h``:
+    ``(y, load)``."""
+    if "router" not in blk:
+        return moe.swiglu(h, blk), None
+    flat = h.reshape(-1, h.shape[-1])
+    y, load = moe.expert_layer(
+        flat, blk, arch.top_k, arch.route_scale, held=arch.held,
+        live=None if live is None else live.reshape(-1),
+        eps=arch.route_eps, shared_scale=arch.shared_scale)
+    return y.reshape(h.shape), load
+
+
 def ffn(arch, blk, x, live=None):
     """The block's residual feed-forward, its kind read off the
     block's leaves. ``live`` (B, T) bool marks the tokens that are
@@ -890,15 +1125,23 @@ def ffn(arch, blk, x, live=None):
     if "w1" in blk:
         return _mlp(blk, x), None
     with jax.named_scope("mlp"):
-        h = rms_norm(x, blk["ffn_norm"], arch.eps)
-        if "router" not in blk:
-            return x + moe.swiglu(h, blk), None
-        flat = h.reshape(-1, h.shape[-1])
-        y, load = moe.expert_layer(
-            flat, blk, arch.top_k, arch.route_scale, held=arch.held,
-            live=None if live is None else live.reshape(-1),
-            eps=arch.route_eps)
-        return x + y.reshape(x.shape), load
+        y, load = _ffn_out(arch, blk, norm(arch, x, blk["ffn_norm"]), live)
+        return x + y, load
+
+
+def block_rest(arch, blk, kind, x, att, live=None):
+    """The block after its attend, ``x`` the block's input and ``att``
+    what the attend gave: ``(x, load)`` as :func:`ffn` says. A
+    sequential block adds the attention's output and then the
+    feed-forward of the sum; a parallel one (``Arch.parallel``) adds
+    both to ``x``, the feed-forward reading the attention's norm of
+    ``x`` (``attn_norm``: the block has no other)."""
+    if not arch.parallel:
+        return ffn(arch, blk, kind.out(blk, x, att), live)
+    with jax.named_scope("mlp"):
+        y, load = _ffn_out(arch, blk, norm(arch, x, blk["attn_norm"]),
+                           live)
+    return kind.out(blk, x, att) + y, load
 
 
 def head(arch, params, x, embed_table=None):
@@ -908,7 +1151,7 @@ def head(arch, params, x, embed_table=None):
     if "lnf_w" in params:
         return _head(params, x)
     with jax.named_scope("head"):
-        h = rms_norm(x, params["norm_w"], arch.eps)
+        h = norm(arch, x, params["norm_w"])
         if "head" in params:
             return h @ params["head"]
         return jnp.einsum("...e,ve->...v", h, embed_table)
@@ -922,5 +1165,6 @@ def block_forward(arch, blk, x, heads, positions, live=None, kind=None):
     if kind is None:
         kind, = block_kinds(arch, 1)
     q, rows = kind.project(arch, blk, x, heads, positions)
-    x = kind.out(blk, x, kind.attend_prompt(arch, blk, q, rows))
-    return ffn(arch, blk, x, live)[0], kind.keep(arch, rows, live)
+    x, _ = block_rest(arch, blk, kind, x,
+                      kind.attend_prompt(arch, blk, q, rows), live)
+    return x, kind.keep(arch, rows, live)

@@ -80,6 +80,14 @@ def prefill_parts(arch, batch, t):
     return parts
 
 
+def admit_rows(arch, t):
+    """The most prompts of ``t`` positions one admission takes
+    (``Arch.admit_tokens``); None: every prompt of the bucket waiting."""
+    if not arch.admit_tokens:
+        return None
+    return max(1, arch.admit_tokens // t)
+
+
 def _prompt_forward(params, x, heads, length=None, embed_table=None):
     """The prompt forward pass shared by every prefill surface: run
     ``x`` (B, T, E) through all blocks once and return ``(last_logits,
@@ -388,6 +396,18 @@ def generate(params, embed_table, prompt_tokens, heads, n_tokens,
 #: compile-bounding trick as the prompt buckets. 128 = the TPU lane
 #: width — T is the lane dimension of the int8-KV head-major layout.
 SLOT_SPAN_TILE = 128
+#: the most rungs the span ladder of a slab takes: past this many tiles
+#: of ``SLOT_SPAN_TILE`` positions a rung is as many tiles as keep the
+#: ladder to it (one compiled chunk program a rung)
+SPAN_RUNGS = 16
+
+
+def span_tile(max_len):
+    """The span tile of a slab of ``max_len`` positions a slot:
+    ``SLOT_SPAN_TILE`` up to ``SPAN_RUNGS`` tiles (2,048 positions),
+    and whole tiles that make at most ``SPAN_RUNGS`` rungs past it."""
+    tiles = -(-max_len // (SPAN_RUNGS * SLOT_SPAN_TILE))
+    return max(1, tiles) * SLOT_SPAN_TILE
 
 
 #: the state's control leaves. Beside them a slot holds two kinds of
@@ -409,6 +429,49 @@ def _kv_names(state):
                   if name not in CONTROL_LEAVES and name != FIXED)
 
 
+def _ring_names(params):
+    """The names of the leaves that are rings (``blocks.Kind.ring``):
+    position ``p`` at ``p mod`` the leaf's length."""
+    kinds = blocks.block_kinds(blocks.arch_of(params),
+                               len(params["blocks"]))
+    return sorted({name for kind in kinds if kind.ring
+                   for name in kind.names})
+
+
+def ring_window(before, length, j):
+    """``(start, low)``, (S,) each: what step ``j`` of a chunk sees of
+    a ring of ``length`` positions, the chunk having begun where each
+    slot's sequence stood, ``before`` (S,). Entry ``r`` holds position
+    ``before - length + age``, its age ``(r - start) mod length``: seen
+    where that position is one (``>= 0``) and lies inside the window of
+    the step's own, ``before + j``, which takes the last ``length``
+    positions with its own: where ``age >= low``. The entries this
+    chunk's staged columns will replace are the ones the window has
+    left by then."""
+    return before % length, jnp.maximum(j + 1, length - before)
+
+
+def ring_visible(before, length, read, j):
+    """``(S, read)`` bool: which of the first ``read`` entries of the
+    ring step ``j`` sees (:func:`ring_window`)."""
+    start, low = ring_window(before, length, j)
+    age = (jnp.arange(read)[None, :] - start[:, None]) % length
+    return age >= low[:, None]
+
+
+def ring_of(columns, lengths, length):
+    """What a ring of ``length`` positions holds of a prompt's
+    ``columns`` (B, ..., T), ``lengths`` (B,) the rows' true lengths:
+    entry ``r`` takes the last position ``p < length_b`` with ``p mod
+    length == r`` (none where the prompt is shorter: an entry no step
+    sees). ``(B, ..., length)``."""
+    last = lengths[:, None] - 1
+    at = last - (last - jnp.arange(length)[None, :]) % length
+    at = jnp.maximum(at, 0).reshape(
+        (at.shape[0],) + (1,) * (columns.ndim - 2) + (length,))
+    return jnp.take_along_axis(columns, at, axis=-1)
+
+
 def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
                     dtype=jnp.float32, quantized=False, mesh=None,
                     mesh_axis="model", paged=False, pages=None,
@@ -423,6 +486,10 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
     and ``v`` of ``(S, kv_heads * head_dim, T)``; GPT-2's as follows),
     and fixed state a slot ``(S, ...)`` under ``FIXED`` (the short
     convolution's ``(S, (taps - 1) * E)``).
+
+    A kind whose rows lie in a ring (``"swa"``: ``k_ring`` and
+    ``v_ring``) has leaves of its own length, ``kind.positions``: a
+    window layer's ring beside the global layers' ``max_len``.
 
     The slab is ONE K and ONE V leaf per block, ``state["k"]`` and
     ``state["v"]`` tuples of ``n_blocks`` arrays ``(S, H·D, T)``:
@@ -495,8 +562,10 @@ def init_slot_state(n_blocks, slots, max_len, heads, head_dim, vocab,
                 fixed.setdefault(name, []).append(
                     jax.ShapeDtypeStruct((slots,) + row, leaf_dtype))
             else:
+                # a ring kind's leaves are its own length (its window)
                 leaves.setdefault(name, []).append(jax.ShapeDtypeStruct(
-                    (slots,) + row + (max_len,), leaf_dtype))
+                    (slots,) + row + (kind.positions(arch, max_len),),
+                    leaf_dtype))
     # where each leaf lives: every leaf is committed to its place, so
     # the first dispatch and every later one (whose state is a
     # program's output) are the same call to the same program
@@ -555,7 +624,9 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
     and scatter what each block keeps of them into slots ``slots``
     (B,) int32 of the block's leaves: the K/V rows of positions
     [0, T), or the fixed state after each row's TRUE length, set
-    whole (nothing of a retired occupant's state stays).
+    whole (nothing of a retired occupant's state stays). A ring
+    shorter than the bucket takes each row's last positions, each at
+    its place in the ring (:func:`ring_of`).
 
     The prefill cost scales with the BUCKET (T), not ``max_len``: only
     positions [0, T) of each slot lane are written. Stale positions
@@ -606,10 +677,17 @@ def _slot_admit_many(params, embed_table, heads, state, slots,
                                                   value)
                     continue
                 # positions [0, t) of a slot's lane
-                where = (slots, Ellipsis, slice(None, t))
                 for name, value in sorted(kind.columns(state,
                                                        rows).items()):
-                    fresh[name][i] = fresh[name][i].at[where].set(value)
+                    length = fresh[name][i].shape[-1]
+                    where = (slots, Ellipsis, slice(None, min(t, length)))
+                    if t <= length:
+                        fresh[name][i] = fresh[name][i].at[where].set(
+                            value)
+                        continue
+                    with jax.named_scope("cache.ring"):
+                        fresh[name][i] = fresh[name][i].at[where].set(
+                            ring_of(value, lengths, length))
             new.update({name: tuple(leaves)
                         for name, leaves in fresh.items()})
             if fixed:
@@ -657,7 +735,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     ``n`` columns go to the leaf at the length the slot had when the
     chunk began: as :func:`block_write_path` says, one Pallas call
     over the leaves (``ops/slab_write.write_blocks``), or one write per
-    slot and leaf from a loop over the slots.
+    slot and leaf from a loop over the slots. A ring's block goes to
+    that length modulo the ring's, split where it wraps, and a step
+    sees of a ring what :func:`ring_visible` says.
 
     ``place`` is where the state lies, leaf name -> ``Format``, as
     :func:`slot_fns` pins it on the program it builds around this
@@ -674,8 +754,10 @@ def _slot_steps(params, embed_table, heads, state, active, n,
     head_dim = embed_table.shape[1] // heads
     # a model whose blocks all carry a fixed state has no row a
     # position: no window to attend, no span, no column to stage
+    rings = _ring_names(params)
     if names:
-        max_len = state[names[0]][0].shape[-1]  # positions are minor
+        # positions are minor; a ring is no longer than the others
+        max_len = max(state[name][0].shape[-1] for name in names)
         if span is None or span > max_len:
             span = max_len
     else:
@@ -728,6 +810,15 @@ def _slot_steps(params, embed_table, heads, state, active, n,
             mask = None if ragged else masks(cached)
             mask_staged = masks(jnp.broadcast_to(
                 jnp.arange(n)[None, :] <= j, (slots, n)))
+            if rings:
+                # a ring's read is its first min(span, length) entries:
+                # all of it once a sequence may have wrapped
+                length = state[rings[0]][0].shape[-1]
+                if ragged:
+                    ring_at = ring_window(before, length, j)
+                else:
+                    mask_ring = masks(ring_visible(before, length,
+                                                   min(span, length), j))
         staged = {name: list(staged[name]) for name in names}
         fixed = {name: list(fixed[name]) for name in fixed}
 
@@ -743,6 +834,12 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                         staged[name][i], cols, at)
             leaves = {name: state[name][i] for name in mine}
             columns = {name: staged[name][i] for name in mine}
+            if ragged and kind.ring:
+                # a ring's entries are live up to its length
+                length = state[rings[0]][0].shape[-1]
+                return kind.attend_ragged(
+                    q, leaves, columns, jnp.minimum(cached, length),
+                    min(span, length), mask_staged, ring=ring_at)
             if ragged:
                 return kind.attend_ragged(q, leaves, columns, cached, span,
                                           mask_staged)
@@ -750,9 +847,10 @@ def _slot_steps(params, embed_table, heads, state, active, n,
             # attend from the leaf where it lies; never the leaf at
             # max_len
             with jax.named_scope("cache.read"):
-                read = {name: leaf[..., :span]
+                read = {name: leaf[..., :min(span, leaf.shape[-1])]
                         for name, leaf in leaves.items()}
-            return kind.attend_cached(arch, blk, q, read, columns, mask,
+            return kind.attend_cached(arch, blk, q, read, columns,
+                                      mask_ring if kind.ring else mask,
                                       mask_staged)
 
         loads = []
@@ -772,9 +870,14 @@ def _slot_steps(params, embed_table, heads, state, active, n,
                     fixed[name][i] = value
             else:
                 att = attend(blk, kind, i, q, rows)
-            x = kind.out(blk, x, att)
-            # an idle slot's lane is computed, but routed to no expert
-            x, load = blocks.ffn(arch, blk, x, active[:, None])
+            if arch.parallel:
+                x, load = blocks.block_rest(arch, blk, kind, x, att,
+                                            active[:, None])
+            else:
+                x = kind.out(blk, x, att)
+                # an idle slot's lane is computed, but routed to no
+                # expert
+                x, load = blocks.ffn(arch, blk, x, active[:, None])
             if load is not None:
                 loads.append(load)
         logits = blocks.head(arch, params, x[:, 0],
@@ -814,7 +917,9 @@ def _slot_steps(params, embed_table, heads, state, active, n,
             else slab_write.write_blocks_loop
         written = iter(write(
             [leaf for name in names for leaf in state[name]],
-            [block for name in names for block in staged[name]], before))
+            [block for name in names for block in staged[name]], before,
+            rings=[name in rings for name in names
+                   for _ in state[name]]))
         for name in names:
             new_state[name] = tuple(next(written) for _ in state[name])
     return new_state, emitted
@@ -1135,19 +1240,26 @@ def slot_layout_facts(state):
 def slot_holds(params, state):
     """What a slot of the dense ``state`` holds, for the books: the
     model's blocks by kind (``block_kinds``) and a slot's bytes as
-    rows a position (all blocks, one position) and as fixed state."""
+    rows a position (all blocks that keep one, one position), as fixed
+    state and, for a model with rings, as its rings whole."""
     import collections
 
     names = blocks.layer_names(blocks.arch_of(params),
                                len(params["blocks"]))
     slots = state["lengths"].shape[0]
+    rings = _ring_names(params)
     rows = sum(leaf.nbytes // (slots * leaf.shape[-1])
-               for name in _kv_names(state) for leaf in state[name])
+               for name in _kv_names(state) if name not in rings
+               for leaf in state[name])
     fixed = sum(leaf.nbytes // slots
                 for leaf in jax.tree.leaves(state.get(FIXED, {})))
-    return {"block_kinds": dict(collections.Counter(names)),
-            "slot_row_bytes_per_position": int(rows),
-            "slot_fixed_state_bytes": int(fixed)}
+    holds = {"block_kinds": dict(collections.Counter(names)),
+             "slot_row_bytes_per_position": int(rows),
+             "slot_fixed_state_bytes": int(fixed)}
+    if rings:
+        holds["slot_ring_bytes"] = int(sum(
+            leaf.nbytes // slots for name in rings for leaf in state[name]))
+    return holds
 
 
 def slot_attend_path(params, state):

@@ -527,6 +527,14 @@ class ServingHealth:
             if paths is not None:
                 # ... and by how they write the chunk's blocks
                 snap["counters"]["block_write_path"] = dict(paths)
+            paths = getattr(getattr(deploy, "decoder", None),
+                            "prompt_paths", None)
+            if paths is not None:
+                # the admissions by how their grouped blocks attend the
+                # prompts, and the prompts past a window
+                snap["counters"]["prompt_attend_path"] = dict(paths)
+                snap["counters"]["admits_past_window"] = \
+                    deploy.decoder.admits_past_window
             rollout = getattr(deploy, "_rollout", None)
             if rollout is not None:
                 snap["rollout"] = rollout.snapshot()
@@ -853,7 +861,8 @@ class ContinuousDecoder:
         from veles_tpu.parallel.decode import (SLOT_SPAN_TILE,
                                                init_slot_state,
                                                quantize_params,
-                                               shard_slot_params)
+                                               shard_slot_params,
+                                               span_tile)
 
         if quantize not in (None, "none", "int8", "int8-kv"):
             raise ValueError("quantize must be None, 'int8' or "
@@ -922,7 +931,8 @@ class ContinuousDecoder:
         #: attended-span tile: each dispatch attends over
         #: ceil((longest live sequence + chunk)/tile)*tile positions
         #: instead of max_len — one compiled program per tile count
-        self.tile = int(tile if tile is not None else SLOT_SPAN_TILE)
+        #: (``decode.span_tile``: 128, wider past 2,048 positions)
+        self.tile = int(tile if tile is not None else span_tile(max_len))
         if self.tile < 1:
             raise ValueError("tile must be >= 1, got %d" % self.tile)
         #: paged KV pool (docs/paged_kv.md): the slab becomes a page
@@ -1076,6 +1086,17 @@ class ContinuousDecoder:
                 self.write_paths = {"kernel": 0, "loop": 0}
             if slot_state_path(params, self.state) is not None:
                 self.state_paths = {"kernel": 0, "xla": 0}
+        #: the admissions by how their prompts attend in the blocks of
+        #: grouped heads with no head norm (``blocks.prompt_attend_path``:
+        #: the splash ``kernel`` or ``xla``), and the requests whose
+        #: prompt was longer than a window block's window; None for a
+        #: model without such blocks
+        self.prompt_paths = None
+        self.admits_past_window = 0
+        if not self.paged:
+            from veles_tpu.parallel.blocks import prompt_attend_path
+            if prompt_attend_path(params, 1, 16, heads) is not None:
+                self.prompt_paths = {"kernel": 0, "xla": 0}
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1460,26 +1481,26 @@ class ContinuousDecoder:
         import jax
 
         from veles_tpu.parallel.blocks import arch_of
-        from veles_tpu.parallel.decode import (prefill_parts,
+        from veles_tpu.parallel.decode import (admit_rows, prefill_parts,
                                                slot_admit_many)
 
         admit = (self._aot.admit if self._aot is not None
                  else slot_admit_many)
         if not (self._queue and self._free):
             return
+        # a bucket's prompts in groups of at most admit_rows each
         groups = {}
-        order = []
         while self._queue and self._free:
             rid, prompt, _ = self._queue.popleft()
             slot = self._free.pop()
             bucket = self.bucket_for(len(prompt))
-            if bucket not in groups:
-                groups[bucket] = []
-                order.append(bucket)
-            groups[bucket].append((rid, prompt, slot))
+            held = groups.setdefault(bucket, [[]])
+            if len(held[-1]) == admit_rows(arch_of(self.params), bucket):
+                held.append([])
+            held[-1].append((rid, prompt, slot))
         now = time.monotonic()
-        for bucket in order:
-            group = groups[bucket]
+        for bucket, group in ((bucket, group) for bucket, held
+                              in groups.items() for group in held):
             rows = self._pad_group(group)
             prompts = numpy.zeros((len(rows), bucket), numpy.int32)
             for j, (_, prompt, _) in enumerate(rows):
@@ -1489,9 +1510,10 @@ class ContinuousDecoder:
                                 in_axes=(None, 0))(self.base_key, rids)
             x = self.embed_table[jnp.asarray(prompts)]
             # the feed-forward sees a part of the group at a time
-            said = self._book_moe_path(
-                len(rows) * bucket // prefill_parts(
-                    arch_of(self.params), len(rows), bucket))
+            parts = prefill_parts(arch_of(self.params), len(rows), bucket)
+            said = self._book_moe_path(len(rows) * bucket // parts)
+            said.update(self._book_prompt_path(
+                len(rows) // parts, bucket, [len(r[1]) for r in group]))
             if self.state_paths is not None:
                 # the path the state this admission sets will take
                 said["state_path"] = self._state_path()
@@ -2188,6 +2210,24 @@ class ContinuousDecoder:
             "admissions) by the tiling of the routed experts' products")
         return {"moe_expert_path": path}
 
+    def _book_prompt_path(self, rows, bucket, lens):
+        """Book one admission whose blocks take ``rows`` prompts of
+        ``bucket`` positions at once, ``lens`` the live prompts' own
+        lengths: how its grouped blocks attend the prompts and how many
+        of them pass a window block's window; returns what the
+        admission's span says of it (nothing for a model without
+        such blocks)."""
+        if self.prompt_paths is None:
+            return {}
+        from veles_tpu.parallel.blocks import arch_of, prompt_attend_path
+
+        path = prompt_attend_path(self.params, rows, bucket, self.heads)
+        self.prompt_paths[path] += 1
+        window = arch_of(self.params).window
+        past = sum(1 for n in lens if window and n > window)
+        self.admits_past_window += past
+        return {"prompt_attend_path": path, "rows_past_window": past}
+
     def _book_attend_path(self, n):
         """Book one decode dispatch of ``n`` steps of the dense slab
         by how its program attends the cache and writes the chunk's
@@ -2205,6 +2245,9 @@ class ContinuousDecoder:
             labels={"path": path}, help="decode dispatches of the dense "
             "slab (chunks, steps) by how the program attends the cache")
         said = {"attend_path": path}
+        if self.slot_holds.get("slot_ring_bytes"):
+            # the window blocks' rings are attended as the rest is
+            said["window_path"] = path
         if self.write_paths is not None:
             said["block_write_path"] = slot_write_path(self.state, n)
             self.write_paths[said["block_write_path"]] += 1
