@@ -71,7 +71,7 @@ def _rule_cases():
 
     def experts(rows, width, inner):
         leaf = jax.ShapeDtypeStruct((4, width, inner), jnp.bfloat16)
-        return lambda m: m.expert_path(rows, {"w_gate": leaf})
+        return lambda m: m.expert_plan(rows, {"w_gate": leaf})[0]
 
     return [
         ("flash-t4096-d128", "attention", True, qk(4096, 128), True),

@@ -16,7 +16,8 @@ path. Two checks keep that from recurring, neither needing a chip:
 Shapes are the serving shapes of record: 8 slots, 16 heads of 64 and
 128, page 128; int8 matvecs k1024 → n3072/4096/32768 at 8 decode rows;
 the routed experts' streaming kernel at 256 rows over 256 experts of
-2048 x 768 and over 32 of 2048 x 1792; the dense slab's attend at 16 slots, 16 heads of 64 and
+2048 x 768 and over 32 of 2048 x 1792, and both expert kernels over 16
+of 4096 x 4096 in slices of their inner width; the dense slab's attend at 16 slots, 16 heads of 64 and
 lanes of 1,024, and at 48 slots of 128 query heads over 8 K/V heads of 128 (a ring of 4,096 and
 rows of 8,704), with the ring's block write.
 """
@@ -115,6 +116,35 @@ def kernel_cases():
              _sds((count, width, inner), "bfloat16"),
              _sds((count, width, inner), "bfloat16"),
              _sds((count, inner, width), "bfloat16"))))
+    # and over the 16 held experts of 4096 x 4096 of the window cell,
+    # which the kernels take in slices of their inner width (the rule's
+    # for a v5e): a decode step of 48 slots x top-8 = 384 rows, and the
+    # largest part of an admission, 8,192 tokens x top-8
+    for many in (384, 65536):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe, "on_tpu", lambda: True)
+            patch.setattr(moe, "device_kind", lambda: "TPU v5 lite")
+            path, tile, sliced = moe.expert_plan(
+                many, {"w_gate": _sds((16, 4096, 4096), "bfloat16")})
+        if path == "streamed":
+            call = (lambda r, load, gate, up, down, sliced=sliced:
+                    moe.streamed_experts(
+                        r, moe.visit_table(load, r.shape[0]),
+                        {"w_gate": gate, "w_up": up, "w_down": down},
+                        sliced, interpret=False))
+        else:
+            call = (lambda r, load, gate, up, down, tile=tile,
+                    sliced=sliced: moe.tiled_experts(
+                        r, moe.tile_table(load, r.shape[0], tile),
+                        {"w_gate": gate, "w_up": up, "w_down": down},
+                        tile, sliced, interpret=False))
+        cases.append((
+            "moe_%s_experts_r%d_e16_4096x4096_f%d" % (path, many, sliced),
+            call,
+            (_sds((many, 4096), "bfloat16"), _sds((16,), "int32"),
+             _sds((16, 4096, 4096), "bfloat16"),
+             _sds((16, 4096, 4096), "bfloat16"),
+             _sds((16, 4096, 4096), "bfloat16"))))
     # the dense slab's ragged-length attend at the serving cell's
     # shapes: 16 slots, 16 heads of 64, lanes of 1,024, a span of 896
     from veles_tpu.ops import slab_attention
@@ -409,10 +439,11 @@ except Exception as exc:
     print("NO-TOPOLOGY %%s" %% exc)
     sys.exit(0)
 # this process sees the CPU: the rule and the kernel are told that the
-# programs compiled here are the chip's
+# programs compiled here are the chip's, a v5e's
 from veles_tpu.ops import moe
 moe.on_tpu = lambda: True
 moe.pallas_interpret = lambda: False
+moe.device_kind = lambda: "TPU v5 lite"
 import offchip_compile_arch
 with open(os.path.join(
         %(repo)r, "benchmark/configs/%(config)s.json")) as fin:
@@ -647,3 +678,33 @@ def test_the_retention_state_is_held_once_on_v5e():
     # the embedding table it does not take: under the allocator's limit
     table = 151936 * 5120 * 2
     assert admit["arguments"] + admit["temp"] + table < 16.9e9, result
+
+
+def test_the_window_cells_experts_take_the_kernels_in_slices_on_v5e(
+        tmp_path):
+    """Command A+'s held experts (16 of 4096 x 4096, two whole ones
+    past the VMEM) compiled for a described v5e: the chunk program over
+    every slot of the smallest span (48 slots x top-8 = 384 rows an
+    expert layer) holds the sliced streaming kernel once an expert
+    layer, in its own jit and under the experts' scope, and no
+    ``ragged-dot``; an admission of one prompt of 256 tokens (2,048
+    rows) holds the sliced tiled kernel once an expert layer and
+    neither of the others; no program copies the held experts."""
+    said = _compile_off_the_chip("command-a-plus-05-2026",
+                                 ["step:8:640", "admit:256:1"], tmp_path)
+    chunk = (tmp_path / "step_8_640.txt").read_text()
+    admit = (tmp_path / "admit_256_1.txt").read_text()
+    expert_layers = 4
+    assert "ragged-dot" not in chunk and "ragged-dot" not in admit
+    assert chunk.count(
+        'mlp/moe.experts/jit(_streamed)/moe.experts/'
+        'moe_streamed_experts/pallas_call"') == expert_layers
+    assert "moe_tiled_experts" not in chunk
+    assert "moe_streamed_experts" not in admit
+    assert admit.count(
+        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        == expert_layers
+    held = 16 * 3 * 4096 * 4096 * 2
+    for row in said.values():
+        assert row["temp_bytes"] < 0.6 * held, row
+        assert row["ragged_dots"] == 0, row

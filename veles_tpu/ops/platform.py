@@ -2,7 +2,7 @@
 
 The rule for choosing a kernel: the module that owns a kernel has one
 function that returns the choice, read off ``on_tpu()``, static shapes
-and the sharding or mesh — ``ops/moe.expert_path``,
+and the sharding or mesh — ``ops/moe.expert_plan``,
 ``ops/quant.use_int8_kernel``, ``ops/attention.use_flash``,
 ``ops/paged_attention.use_paged_kernel``,
 ``ops/slab_attention.use_slab_kernel``. No config key, flag,

@@ -151,16 +151,19 @@ def expert_blocks(params):
     return [i for i, blk in enumerate(params["blocks"]) if "router" in blk]
 
 
-def expert_path(params, tokens):
-    """The tiling the routed experts' products take
-    (``ops/moe.expert_path``) in a program whose feed-forward sees
-    ``tokens`` tokens at once; None for a model without routed
+def expert_plan(params, tokens):
+    """``(path, slices)``: the tiling the routed experts' products take
+    (``ops/moe.expert_plan``) in a program whose feed-forward sees
+    ``tokens`` tokens at once, and the slices of their inner width an
+    expert is taken in (1: whole); None for a model without routed
     experts."""
     routed = expert_blocks(params)
     if not routed:
         return None
-    return moe.expert_path(tokens * arch_of(params).top_k,
-                           params["blocks"][routed[0]]["experts"])
+    experts = params["blocks"][routed[0]]["experts"]
+    path, _, sliced = moe.expert_plan(tokens * arch_of(params).top_k,
+                                      experts)
+    return path, experts["w_gate"].shape[-1] // sliced
 
 
 def attend_path(params, state, sharding):

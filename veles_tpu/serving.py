@@ -1048,12 +1048,14 @@ class ContinuousDecoder:
         #: ``paths``: dispatches (chunks, steps and admissions alike)
         #: by the tiling their grouped products took, which the
         #: program's shapes decide when it is traced
-        #: (``ops/moe.expert_path``)
+        #: (``ops/moe.expert_plan``); ``slices``: the same dispatches by
+        #: the slices of the inner width their kernel took an expert in
+        #: (1: whole, as ``ragged_dot`` takes it)
         self.moe_load = None
         if expert_blocks(params):
             self.moe_load = {"assignments": None, "by_lanes": {},
                              "paths": {"streamed": 0, "tiled": 0,
-                                       "grouped": 0}}
+                                       "grouped": 0}, "slices": {}}
         #: the dense slab's decode dispatches (chunks and single
         #: steps) by how their program attends the cache: ``kernel``
         #: (each slot over its own length, ops/slab_attention.py) or
@@ -2195,20 +2197,22 @@ class ContinuousDecoder:
 
     def _book_moe_path(self, tokens):
         """Book one dispatch whose feed-forward sees ``tokens`` tokens
-        at once by the tiling its grouped products take; returns what
-        the dispatch's span says of it (nothing for a model without
-        routed experts)."""
+        at once by the tiling its grouped products take and the slices
+        its kernel takes an expert in; returns what the dispatch's span
+        says of them (nothing for a model without routed experts)."""
         if self.moe_load is None:
             return {}
-        from veles_tpu.parallel.blocks import expert_path
+        from veles_tpu.parallel.blocks import expert_plan
 
-        path = expert_path(self.params, tokens)
+        path, slices = expert_plan(self.params, tokens)
         self.moe_load["paths"][path] += 1
+        self.moe_load["slices"][slices] = \
+            self.moe_load["slices"].get(slices, 0) + 1
         self.metrics.incr(
             "veles_moe_expert_dispatches_total", 1,
             labels={"path": path}, help="dispatches (chunks, steps, "
             "admissions) by the tiling of the routed experts' products")
-        return {"moe_expert_path": path}
+        return {"moe_expert_path": path, "moe_expert_slices": slices}
 
     def _book_prompt_path(self, rows, bucket, lens):
         """Book one admission whose blocks take ``rows`` prompts of
@@ -2286,7 +2290,10 @@ class ContinuousDecoder:
                 "moe_by_lanes": {
                     str(lanes): list(row) for lanes, row
                     in sorted(self.moe_load["by_lanes"].items())},
-                "moe_expert_path": dict(self.moe_load["paths"])}
+                "moe_expert_path": dict(self.moe_load["paths"]),
+                "moe_expert_slices": {
+                    str(n): count for n, count
+                    in sorted(self.moe_load["slices"].items())}}
 
     def dispatch_chunk(self, chunk):
         """Admit what fits and enqueue one chunk WITHOUT waiting for
