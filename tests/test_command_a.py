@@ -275,16 +275,18 @@ def test_a_planted_fault_fails_the_comparison_the_program_passes(
 #: sha256 of the jaxpr text of each program at the configuration's
 #: rehearsal sizes, as the tree before the window and parallel blocks
 #: traced them (an admission of 2 rows of 16, a chunk of 4 steps over
-#: 32 positions of a 4 x 64 slab)
+#: 32 positions of a 4 x 64 slab), but for the routed experts' two
+#: gathers, which take their rows by clipped index
+#: (``ops/moe.routed_experts``)
 PROGRAMS = {
     "gpt2-medium.admit":
         "9eebe7b38cf3f7b4d0a0a6260c6ba5bc6b7f6e7ec72a99eb330723bb38c9d76e",
     "gpt2-medium.chunk":
         "1e1d0e3789428b48fdac701bcfd10d1e70d52922d5981dfcda7cf135e953e5fd",
     "lfm2-8b-a1b.admit":
-        "4edcd634f0adbdcafd22efab8044b3913018157d472a1dde34b9dce4bee8d1a8",
+        "9e09900f74384139c770c4d6640ae907009d2e45c25331774a0a0bfdaf660c5b",
     "lfm2-8b-a1b.chunk":
-        "8d0e1174ad1eeb3fb4e05e5ad6e749baddaa62793401b70df41fd0583f361e0f",
+        "0c28567c1c843837eb2733c959220bea013cae1f0e1ced5adc00f272bfefdc4b",
 }
 
 

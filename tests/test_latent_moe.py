@@ -363,11 +363,12 @@ def test_a_planted_fault_reads_not_correct(reference, config, model,
     else:
         whole = moe.routed_experts
 
-        def dropping(h, chosen, weights, experts, held=None, live=None):
+        def dropping(h, chosen, weights, experts, held=None, live=None,
+                     routed=None):
             # every other token is over some expert's capacity
             keep = jnp.arange(h.shape[0]) % 2 == 0
             return whole(h, chosen, weights, experts, held,
-                         keep if live is None else live & keep)
+                         keep if live is None else live & keep, routed)
 
         monkeypatch.setattr(moe, "routed_experts", dropping)
     rng = numpy.random.RandomState(9)

@@ -214,6 +214,38 @@ def test_the_held_quarters_add_up_to_the_layer(experts, wide, monkeypatch,
                                      numpy.asarray(load))
 
 
+@pytest.mark.parametrize("routing", ["fits", "overflows", "not_live"])
+def test_a_held_share_takes_only_its_rows_where_they_fit(experts,
+                                                          monkeypatch,
+                                                          routing):
+    """A quarter of the experts held of all of them routed: the held
+    rows, ``held_rows`` of them (twice the even share, in whole tiles),
+    go through the tiled kernel and come back scaled; a batch that
+    sends the quarter more takes every row. Both are the loop's."""
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    h, chosen, weights = _tokens(6, 600)
+    live = None
+    if routing == "overflows":
+        chosen = jnp.tile(jnp.arange(TOP_K, dtype=jnp.int32), (600, 1))
+    elif routing == "not_live":
+        live = jnp.asarray(numpy.arange(600) % 5 != 0)
+    quarter = COUNT // 4
+    held = jax.tree.map(lambda w: w[:quarter], experts)
+    rows = moe.held_rows(chosen.size, held, COUNT)
+    assert rows == 640 and moe.expert_plan(rows, held)[0] == "tiled"
+    y, load = moe.routed_experts(h, chosen, weights, held,
+                                 held=(0, quarter), live=live,
+                                 routed=COUNT)
+    assert (int(jnp.sum(load)) <= rows) == (routing != "overflows")
+    numpy.testing.assert_allclose(
+        numpy.asarray(y), numpy.asarray(_loop(
+            h, chosen, weights, held, held=(0, quarter), live=live)),
+        **CLOSE)
+    # every expert held, or a share whose rows would stream: all rows
+    assert moe.held_rows(chosen.size, experts, COUNT) is None
+    assert moe.held_rows(200 * TOP_K, held, COUNT) is None
+
+
 @pytest.mark.parametrize("path, tokens, inner", [
     ("streamed", 19, INNER), ("tiled", 270, INNER),
     ("streamed", 19, WIDE), ("tiled", 270, WIDE)])

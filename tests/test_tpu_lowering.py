@@ -19,7 +19,8 @@ the routed experts' streaming kernel at 256 rows over 256 experts of
 2048 x 768 and over 32 of 2048 x 1792, and both expert kernels over 16
 of 4096 x 4096 in slices of their inner width; the dense slab's attend at 16 slots, 16 heads of 64 and
 lanes of 1,024, and at 48 slots of 128 query heads over 8 K/V heads of 128 (a ring of 4,096 and
-rows of 8,704), with the ring's block write.
+rows of 8,704), with the ring's block write; the retention state's
+step at 16 slots of 8 K/V heads.
 """
 
 import os
@@ -503,7 +504,8 @@ def test_chunk_streams_the_experts_and_an_admission_tiles_them_on_v5e(
     assert admit.count('custom_call_target="tpu_custom_call"') \
         == expert_layers
     assert admit.count(
-        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        'mlp/moe.experts/jit(_tiled)/moe.experts/'
+        'moe_tiled_experts/pallas_call"') \
         == expert_layers
     one_layer = 3 * 256 * 2048 * 768 * 2
     assert said["step:8:1408"]["temp_bytes"] < 0.1 * one_layer, said
@@ -535,7 +537,8 @@ def test_a_kind_per_block_compiles_for_v5e_at_published_widths(tmp_path):
     assert "moe_streamed_experts" not in admit
     assert "ragged-dot" not in admit
     assert admit.count(
-        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        'mlp/moe.experts/jit(_tiled)/moe.experts/'
+        'moe_tiled_experts/pallas_call"') \
         == expert_layers
     for scope in ("attn.qkv/conv.in", "attn.attend/conv.mix",
                   "attn.out/conv.out", "cache.append/cache.state",
@@ -702,7 +705,8 @@ def test_the_window_cells_experts_take_the_kernels_in_slices_on_v5e(
     assert "moe_tiled_experts" not in chunk
     assert "moe_streamed_experts" not in admit
     assert admit.count(
-        'mlp/moe.experts/jit(_tiled)/moe_tiled_experts/pallas_call"') \
+        'mlp/moe.experts/jit(_tiled)/moe.experts/'
+        'moe_tiled_experts/pallas_call"') \
         == expert_layers
     held = 16 * 3 * 4096 * 4096 * 2
     for row in said.values():

@@ -484,7 +484,8 @@ def _tiled(table, rows, experts, tile, slice_width, interpret):
     inner axis. A function jitted on its own, so that a program with an
     expert layer in every block lowers the kernel once and calls it
     (lowered once a layer it costs each of an expert model's admit
-    programs seconds of every set-up: PERF.md §6)."""
+    programs seconds of every set-up: PERF.md §6). The experts' scope
+    is named again inside, as :func:`_streamed` names it."""
     n_rows, width = rows.shape
     inner = experts["w_gate"].shape[-1]
     assert n_rows % tile == 0, (n_rows, tile)
@@ -504,7 +505,7 @@ def _tiled(table, rows, experts, tile, slice_width, interpret):
     def live(i, ids, tiles, first, end):
         return end[i] > first[i]
 
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -519,7 +520,10 @@ def _tiled(table, rows, experts, tile, slice_width, interpret):
                                          slice_width)),
         name="moe_tiled_experts",
         interpret=interpret,
-    )(*table, rows, experts["w_gate"], experts["w_up"], experts["w_down"])
+    )
+    with jax.named_scope("moe.experts"):
+        return call(*table, rows, experts["w_gate"], experts["w_up"],
+                    experts["w_down"])
 
 
 def tiled_experts(rows, table, experts, tile=ROW_TILE, slice_width=None,
@@ -551,14 +555,47 @@ def grouped_experts(rows, load, experts):
     return lax.ragged_dot(inner, experts["w_down"], load)
 
 
-def routed_experts(h, chosen, weights, experts, held=None, live=None):
+#: the rows a held share's products take, as a multiple of its even
+#: share of the assignments (``held_rows``): a batch that routes more
+#: than this to the experts held here takes every row instead
+HELD_HEADROOM = 2
+
+
+def held_rows(n_rows, experts, routed):
+    """The rows that the products of a chip's held share of ``routed``
+    experts take for ``n_rows`` assignments: ``HELD_HEADROOM`` times the
+    share that even routing sends to them, in whole tiles of the tiled
+    kernel, or None where that is no fewer than ``n_rows`` or where
+    either number of rows would not take the tiled kernel (a decode
+    step's few rows stream as they did)."""
+    count = experts["w_gate"].shape[0]
+    if routed is None or count >= routed:
+        return None
+    path, tile, _ = expert_plan(n_rows, experts)
+    if path != "tiled":
+        return None
+    rows = -(-HELD_HEADROOM * n_rows * count // routed // tile) * tile
+    if rows >= n_rows or expert_plan(rows, experts)[:2] != (path, tile):
+        return None
+    return rows
+
+
+def routed_experts(h, chosen, weights, experts, held=None, live=None,
+                   routed=None):
     """The held experts' part of the layer for tokens ``h`` (N, E):
     ``(y (N, E), load (n_held,) int32)``. ``experts`` holds the
     stacked ``w_gate``/``w_up`` (count, E, F) and ``w_down``
     (count, F, E) of experts ``first .. first + count``; ``live``
     (N,) bool leaves a token out altogether (a padded position, an
     idle slot: it reads no expert and counts in no load). ``load`` is
-    the assignments each held expert got."""
+    the assignments each held expert got.
+
+    Where the chip holds a share of ``routed`` experts (held ``count <
+    routed``) and :func:`held_rows` gives fewer rows than there are
+    assignments, the rows gathered, the products and the result carried
+    back are that many whenever the batch's assignments to the held
+    experts fit, each result row scaled by its weight and carried back
+    in ``h``'s type; a batch that sends them more takes every row."""
     count = experts["w_gate"].shape[0]
     first = 0 if held is None else held[0]
     n, top_k = chosen.shape
@@ -577,30 +614,63 @@ def routed_experts(h, chosen, weights, experts, held=None, live=None):
         load = jnp.sum(key[:, None] == jnp.arange(count), axis=0,
                        dtype=jnp.int32)
         source = order // top_k
-        if path == "streamed":
-            source = _whole_tiles(source, tile)
-            visits = visit_table(load, n * top_k)
-        elif path == "tiled":
-            source = _whole_tiles(source, tile)
-            table = tile_table(load, source.shape[0], tile)
-        rows = jnp.take(h, source, axis=0)
-    with jax.named_scope("moe.experts"):
-        if path == "streamed":
-            out = streamed_experts(rows, visits, experts, sliced)
-        elif path == "tiled":
-            out = tiled_experts(rows, table, experts, tile, sliced)
-        else:
-            out = grouped_experts(rows, load, experts)
-    with jax.named_scope("moe.combine"):
-        # back in the tokens' order; a row past the last group holds
-        # whatever the grouped product left there: selected away, not
-        # multiplied by 0
-        share = jnp.where(mine, weights, 0.0)
-        out = jnp.take(out, back, axis=0).reshape(n, top_k, -1)
-        y = jnp.sum(jnp.where(mine[..., None],
-                              out.astype(jnp.float32) * share[..., None],
-                              0.0), axis=1)
-    return y.astype(h.dtype), load
+
+    def every_row(_):
+        with jax.named_scope("moe.dispatch"):
+            src = source
+            if path == "streamed":
+                src = _whole_tiles(source, tile)
+                visits = visit_table(load, n * top_k)
+            elif path == "tiled":
+                src = _whole_tiles(source, tile)
+                table = tile_table(load, src.shape[0], tile)
+            # every index is in range: "clip" gathers without the
+            # select over the whole (assignments, E) result that
+            # "fill" puts after it
+            rows = jnp.take(h, src, axis=0, mode="clip")
+        with jax.named_scope("moe.experts"):
+            if path == "streamed":
+                out = streamed_experts(rows, visits, experts, sliced)
+            elif path == "tiled":
+                out = tiled_experts(rows, table, experts, tile, sliced)
+            else:
+                out = grouped_experts(rows, load, experts)
+        with jax.named_scope("moe.combine"):
+            # back in the tokens' order; a row past the last group
+            # holds whatever the grouped product left there: selected
+            # away, not multiplied by 0
+            share = jnp.where(mine, weights, 0.0)
+            out = jnp.take(out, back, axis=0, mode="clip").reshape(
+                n, top_k, -1)
+            y = jnp.sum(jnp.where(mine[..., None],
+                                  out.astype(jnp.float32)
+                                  * share[..., None], 0.0), axis=1)
+        return y.astype(h.dtype)
+
+    narrow = held_rows(n * top_k, experts, routed)
+    if narrow is None:
+        return every_row(None), load
+
+    def held_only(_):
+        with jax.named_scope("moe.dispatch"):
+            rows = jnp.take(h, source[:narrow], axis=0, mode="clip")
+        with jax.named_scope("moe.experts"):
+            out = tiled_experts(rows, tile_table(load, narrow, tile),
+                                experts, tile, sliced)
+        with jax.named_scope("moe.combine"):
+            share = jnp.where(mine, weights, 0.0).reshape(-1)
+            scaled = (out * jnp.take(share, order[:narrow])[:, None]
+                      ).astype(h.dtype)
+            # an assignment that is not held here points past the rows
+            # computed (clipped) and is selected away
+            out = jnp.take(scaled, back, axis=0, mode="clip").reshape(
+                n, top_k, -1)
+            y = jnp.sum(jnp.where(mine[..., None],
+                                  out.astype(jnp.float32), 0.0), axis=1)
+        return y.astype(h.dtype)
+
+    y = lax.cond(jnp.sum(load) <= narrow, held_only, every_row, None)
+    return y, load
 
 
 def expert_layer(h, p, top_k, scale, held=None, live=None, eps=0.0,
@@ -614,7 +684,8 @@ def expert_layer(h, p, top_k, scale, held=None, live=None, eps=0.0,
     load)``."""
     chosen, weights = route(h, p["router"], p.get("router_bias"), top_k,
                             scale, eps)
-    y, load = routed_experts(h, chosen, weights, p["experts"], held, live)
+    y, load = routed_experts(h, chosen, weights, p["experts"], held, live,
+                             routed=p["router"].shape[-1])
     if "shared" in p:
         with jax.named_scope("moe.shared"):
             shared = swiglu(h, p["shared"])

@@ -47,7 +47,13 @@ with no head norm: ``"swa"`` rotates q and k (RoPE) and attends the last
 ``k_ring``/``v_ring`` that are a RING of that many positions a slot
 (position ``p`` at ``p mod window``), so that its cache stops growing
 at the window; ``"nope"`` takes no position encoding and attends the
-whole sequence from rows a position (``k_all``/``v_all``). The
+whole sequence from rows a position (``k_all``/``v_all``), its output
+gated elementwise where the block has a ``wgate`` leaf. ``"kda"`` is
+the gated delta rule with a decay a key channel (``ops/delta_rule.py``,
+Kimi Delta Attention): q, k and v through short depthwise convolutions,
+a float32 state a head that each position decays, corrects along its
+key and reads with its query, so that a slot's past is that state and
+the convolutions' last inputs, and no row a position. The
 feed-forward kind is read off the block's own leaves: ``w1`` GELU MLP,
 ``w_gate`` SwiGLU, ``router`` the routed experts of ``ops/moe.py``
 (with shared experts where the block has a ``shared`` leaf, their sum
@@ -66,7 +72,8 @@ The named scopes are the ones the per-layer readers know
 ``mla.kv``, ``mla.rope``, ``mla.absorb``, ``gqa.norm``, ``gqa.rope``,
 ``conv.in``, ``conv.mix``, ``conv.out``, ``cache.state``, ``ret.gate``,
 ``ret.phi``, ``ret.state``, ``ret.chunk``, ``swa.rope``, ``swa.ring``,
-``nope.attend``, ``cache.ring``, ``moe.*``).
+``nope.attend``, ``cache.ring``, ``gqa.gate``, ``kda.gate``, ``kda.conv``,
+``kda.chunk``, ``kda.state``, ``kda.norm``, ``moe.*``).
 """
 
 import dataclasses
@@ -74,7 +81,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from veles_tpu.ops import moe, retention, slab_attention
+from veles_tpu.ops import delta_rule, moe, retention, slab_attention
 from veles_tpu.ops.attention import (attention, grouped_attention,
                                      prompt_path)
 from veles_tpu.ops.quant import int8_cache_attend, matmul_any
@@ -97,7 +104,8 @@ class Arch:
     #: the width of a head of ``"swa"``/``"nope"`` where it is not the
     #: hidden size over the heads (0: it is)
     head_dim: int = 0
-    # gated short convolution: taps of the depthwise kernel
+    #: taps of a block's depthwise convolutions (``"conv"``; ``"kda"``'s
+    #: of q, k and v)
     conv_taps: int = 3
     # power retention: the degree of the score (2 is the one there is)
     power: int = 2
@@ -119,6 +127,10 @@ class Arch:
     #: prompt positions (rows x bucket) one admission takes at most; a
     #: larger group of a bucket is admitted in several (0: no limit)
     admit_tokens: int = 0
+    #: the least admission bucket: a shorter prompt pads to it, so its
+    #: bucket's programs are never built (0: the power of two at or
+    #: above the prompt's length)
+    prompt_bucket: int = 0
     #: positions a ``"swa"`` block attends, the query's own included
     window: int = 0
     #: one norm of the block's input read by attention and the
@@ -214,6 +226,19 @@ def state_path(params, state, sharding):
     if not held:
         return None
     return retention.state_path(held[0], sharding)
+
+
+def prompt_chunks(params, rows, t):
+    """Chunks of ``ops/delta_rule.CHUNK`` positions that an admission
+    of ``rows`` prompts of ``t`` positions runs through the delta
+    rule's chunked form, over its ``"kda"`` blocks; None for a model
+    without such a block."""
+    blocks = sum(1 for kind in block_kinds(arch_of(params),
+                                           len(params["blocks"]))
+                 if kind is DeltaRule)
+    if not blocks:
+        return None
+    return blocks * rows * -(-t // delta_rule.CHUNK)
 
 
 def require_gpt2(params, what, lacks=None, tier=None):
@@ -414,6 +439,15 @@ class Kind:
         """A fixed-state ``leaf`` (S, ...) with the state of ``slots``
         (B,) set whole to ``value`` (B, ...): an admission's write."""
         return leaf.at[slots].set(value)
+
+    @classmethod
+    def prompt(cls, arch, blk, q, rows, live):
+        """The prompt's attend and what the cache keeps of it, ``(att,
+        kept)``; ``kept`` None: :func:`block_forward` asks ``keep`` once
+        the block's rest is done. A kind whose one pass over the prompt
+        gives both (``"kda"``: the state after each row's true length
+        is the chunk scan's carry) says so here."""
+        return cls.attend_prompt(arch, blk, q, rows), None
 
 
 class FusedQKV(Kind):
@@ -921,13 +955,33 @@ class Retention(Kind):
     out = Grouped.out
 
 
+def _gate_of(q):
+    """``(q, gate)`` of what a ``"nope"`` block's ``project`` gave: a
+    gated block's query travels with its gate's pre-activation (None:
+    the block has no gate)."""
+    return q if isinstance(q, tuple) else (q, None)
+
+
+def _gated(att, gate):
+    """``att ⊙ sigmoid(gate)`` in float32, in ``att``'s type (``att``
+    as it is where ``gate`` is None)."""
+    if gate is None:
+        return att
+    with jax.named_scope("attn.out"), jax.named_scope("gqa.gate"):
+        return (att.astype(jnp.float32) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))).astype(att.dtype)
+
+
 class Global(Kind):
     """``"nope"``: grouped-query attention (``heads`` query heads over
     ``arch.kv_heads`` K/V heads) with no head norm and no position
     encoding, over the whole sequence: rows a position in leaves of its
     own (``names``), ``kv_heads * head_dim`` wide. Leaves of a block:
     ``attn_norm``, ``wq`` (E, H·D), ``wk``/``wv`` (E, H_kv·D), ``wout``
-    (H·D, E); no bias."""
+    (H·D, E); no bias. A block with a ``wgate`` leaf (E, H·D) gates its
+    attention's output elementwise before ``wout``, ``att ⊙ sigmoid(h .
+    wgate)`` (``gqa.gate``): its query travels with the gate's
+    pre-activation, ``(q, gate)``, from ``project`` to the attend."""
     leaf = "k_all"
     names = ("k_all", "v_all")
     #: the scope of its attend, inside ``attn.attend``
@@ -967,6 +1021,9 @@ class Global(Kind):
                 with jax.named_scope("swa.rope"):
                     q = rope(q, positions, arch.rope_theta)
                     k = rope(k, positions, arch.rope_theta)
+            if "wgate" in blk:
+                with jax.named_scope("gqa.gate"):
+                    q = (q, h @ blk["wgate"])
             return q, dict(zip(cls.names, (k, v)))
 
     @classmethod
@@ -991,10 +1048,12 @@ class Global(Kind):
         """Causal attention over the prompt (within the window), the
         K/V heads as they are (``ops/attention.grouped_attention``)."""
         k, v = cls.names
+        q, gate = _gate_of(q)
         with jax.named_scope("attn.attend"), jax.named_scope(cls.scope):
             att = grouped_attention(q, rows[k], rows[v],
                                     window=cls.window(arch))
-            return att.reshape(att.shape[:2] + (-1,))
+            att = att.reshape(att.shape[:2] + (-1,))
+        return _gated(att, gate)
 
     @classmethod
     def attend_cached(cls, arch, blk, q, read, staged, mask, mask_staged):
@@ -1002,9 +1061,11 @@ class Global(Kind):
         (``_grouped_cached``), ``mask`` saying which of the window's
         positions each slot sees."""
         k, v = cls.names
+        q, gate = _gate_of(q)
         with jax.named_scope("attn.attend"), jax.named_scope(cls.scope):
-            return _grouped_cached(arch, q, read[k], read[v], staged[k],
-                                   staged[v], mask, mask_staged)
+            att = _grouped_cached(arch, q, read[k], read[v], staged[k],
+                                  staged[v], mask, mask_staged)
+        return _gated(att, gate)
 
     @classmethod
     def attend_ragged(cls, q, leaves, staged, lengths, span, mask_staged,
@@ -1014,6 +1075,7 @@ class Global(Kind):
         group of query heads (``ops/slab_attention.slab_attend``), a
         ring's entries as ``ring`` says; the staged columns, a K/V head
         repeated for its group, joined in one softmax."""
+        q, gate = _gate_of(q)
         slots, _, heads, head_dim = q.shape
         k, v = cls.names
         groups = leaves[k].shape[1] // head_dim
@@ -1028,7 +1090,8 @@ class Global(Kind):
                                               lengths, span, ring=ring,
                                               scope=cls.scope),
                 apart(staged[k]), apart(staged[v]), mask_staged)
-            return att.reshape(slots, 1, -1)
+            att = att.reshape(slots, 1, -1)
+        return _gated(att, gate)
 
     out = Grouped.out
 
@@ -1057,10 +1120,172 @@ class Windowed(Global):
         return arch.window
 
 
+def _l2(x):
+    """``x / |x|`` over its last axis in float32 (``+ 1e-6`` under the
+    root)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+class DeltaRule(Kind):
+    """``"kda"``: the gated delta rule with a decay a key channel (Kimi
+    Delta Attention, ``ops/delta_rule.py``) over ``heads`` heads of
+    ``Arch.head_dim``, q, k and v alike. From ``h = norm(x)``: the three
+    streams ``h . w_qkv`` (E, 3·H·D), each channel through a causal
+    depthwise convolution of ``Arch.conv_taps`` taps (``conv_w``
+    (taps, 3·H·D), no bias) and SiLU, q and k of unit length a head, q
+    scaled by ``1/sqrt(D)``; the log-decays ``g = -exp(A_log) ·
+    softplus((h . w_fa) . w_fb + dt_bias)`` a channel (``A_log`` (H,),
+    ``dt_bias`` (H·D,) float32) and the write strength ``b =
+    write_scale · sigmoid(h . w_beta)`` a head; the state's
+    answer ``o`` RMS-normed a head (``o_norm`` (D,)), gated by
+    ``sigmoid((h . w_ga) . w_gb)``, then ``wout`` (H·D, E).
+
+    It keeps no row a position. A slot's FIXED state is two leaves: the
+    delta rule's ``kda_S`` ``(H, D, D)`` float32 (4 MB a layer at 64
+    heads of 128) and the convolutions' ``kda_conv``, the last ``taps -
+    1`` inputs of the three streams side by side, oldest first, ``((taps
+    - 1) · 3·H·D,)`` in the serving type. Scopes: ``kda.gate`` (the
+    decays, the write strength and the output gate's projection, in
+    ``attn.qkv``), ``kda.conv`` (the convolutions, their state's roll and
+    the norms of q and k, in ``attn.qkv``), ``kda.chunk`` (the prompt's
+    chunked form) and ``kda.state`` (a step's pass of the state) in
+    ``attn.attend``, ``kda.norm`` (the gated head norm, in
+    ``attn.out``)."""
+    fixed = True
+    leaf = "kda_S"
+    #: the write strength's scale: ``b`` in (0, 2), so that ``I - b k
+    #: kᵀ`` may have a negative eigenvalue (Solar Open 2's
+    #: ``kda_allow_neg_eigval``, the one model of this kind)
+    write_scale = 2.0
+    lacks = {
+        "paged": "a page holds the k/v rows of some positions and the "
+                 "page table says which; this kind keeps no row a "
+                 "position, only a state a slot (the delta rule's S and "
+                 "the convolutions' tails), which no table indexes",
+        "prefix": "a cached prefix is its pages; this kind's prefix is "
+                  "the state after it (S, 4 MB a slot a layer at 64 "
+                  "heads of 128, and the convolutions' last inputs), "
+                  "which would have to be snapshot when the prefix ends "
+                  "and copied into the slot on a hit",
+        "int8": "quantize_params knows wqkv, w1, w2 and the int8 cache "
+                "k/v rows; this kind has w_qkv, the low-rank decay and "
+                "gate, and a float32 state that the delta rule corrects "
+                "at every position, which int8 rows cannot hold",
+        "mesh": "slot_state_specs shards k/v leaves over heads and "
+                "gives a fixed leaf no spec; this kind's S and "
+                "convolution tails would shard over heads",
+    }
+
+    @staticmethod
+    def leaves(arch, heads, head_dim, dtype, quantized=False):
+        d = arch.head_dim or head_dim
+        return {"kda_S": ((heads, d, d), jnp.float32),
+                "kda_conv": (((arch.conv_taps - 1) * 3 * heads * d,),
+                             dtype)}
+
+    @classmethod
+    def project(cls, arch, blk, x, heads, positions):
+        """``(gate, rows)``: the output gate's pre-activation (B, T,
+        H·D) and ``{"conv": the three streams before their convolution
+        (B, T, 3·H·D), "g": log-decays (B, T, H, D) float32, "beta":
+        (B, T, H) float32}``."""
+        batch, t, _ = x.shape
+        with jax.named_scope("attn.qkv"):
+            h = norm(arch, x, blk["attn_norm"])
+            mixed = h @ blk["w_qkv"]
+            with jax.named_scope("kda.gate"):
+                rate = jnp.einsum(
+                    "btr,rc->btc", h @ blk["w_fa"], blk["w_fb"],
+                    preferred_element_type=jnp.float32) + blk["dt_bias"]
+                g = -jnp.exp(blk["A_log"])[:, None] * jax.nn.softplus(
+                    rate.reshape(batch, t, heads, -1))
+                beta = cls.write_scale * jax.nn.sigmoid(jnp.einsum(
+                    "bte,eh->bth", h, blk["w_beta"],
+                    preferred_element_type=jnp.float32))
+                gate = (h @ blk["w_ga"]) @ blk["w_gb"]
+        return gate, {"conv": mixed, "g": g, "beta": beta}
+
+    @staticmethod
+    def _streams(arch, blk, inputs, heads):
+        """q, k, v (..., H, D) in the inputs' type from the taps'
+        ``inputs`` (..., 3·H·D), oldest first: the convolution and SiLU
+        in float32, q and k of unit length, q scaled."""
+        taps = blk["conv_w"].astype(jnp.float32)
+        mixed = jax.nn.silu(sum(taps[j] * part.astype(jnp.float32)
+                                for j, part in enumerate(inputs)))
+        q, k, v = (part.reshape(part.shape[:-1] + (heads, -1))
+                   for part in jnp.split(mixed, 3, axis=-1))
+        q = _l2(q) * q.shape[-1] ** -0.5
+        dtype = inputs[-1].dtype
+        return q.astype(dtype), _l2(k).astype(dtype), v.astype(dtype)
+
+    @staticmethod
+    def _finish(arch, blk, o, gate):
+        """The answer ``o`` (..., H, D) RMS-normed a head and gated:
+        (..., H·D) in the gate's type."""
+        with jax.named_scope("attn.out"), jax.named_scope("kda.norm"):
+            normed = rms_norm(o.astype(jnp.float32), blk["o_norm"],
+                              arch.eps)
+            return (normed.reshape(gate.shape) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(gate.dtype)
+
+    @classmethod
+    def prompt(cls, arch, blk, q, rows, live):
+        """The whole right-padded prompt in one pass: ``(att, kept)``,
+        ``kept`` the state and the convolutions' tails after each row's
+        TRUE length (``live`` (B, T); None: all of it)."""
+        mixed = rows["conv"]
+        t, back = mixed.shape[1], arch.conv_taps - 1
+        heads = rows["beta"].shape[-1]
+        with jax.named_scope("attn.qkv"), jax.named_scope("kda.conv"):
+            padded = jnp.pad(mixed, ((0, 0), (back, 0), (0, 0)))
+            qq, kk, vv = cls._streams(
+                arch, blk, [padded[:, j:j + t] for j in range(back + 1)],
+                heads)
+            tail = ShortConv.keep(arch, {"conv": mixed}, live)
+        with jax.named_scope("attn.attend"), jax.named_scope("kda.chunk"):
+            y, held = delta_rule.prompt(qq, kk, vv, rows["g"],
+                                        rows["beta"], live)
+        return cls._finish(arch, blk, y, q), {"kda_S": held,
+                                              "kda_conv": tail["conv"]}
+
+    @staticmethod
+    def columns(state, rows):
+        return {name: rows[name].astype(state[name][0].dtype)
+                for name in ("kda_S", "kda_conv")}
+
+    put = Retention.put
+
+    @classmethod
+    def step(cls, arch, blk, q, rows, fixed, active, sharding=None):
+        """One new position a slot: ``(att (S, 1, H·D), fixed)``. The
+        convolutions see the inputs the slot carries and the new ones,
+        and roll by one; the state goes through the chip once
+        (``ops/delta_rule.step``). A lane that is not ``active`` keeps
+        both as they were."""
+        held, new = fixed["kda_conv"], rows["conv"][:, 0]
+        back = arch.conv_taps - 1
+        heads = rows["beta"].shape[-1]
+        with jax.named_scope("attn.qkv"), jax.named_scope("kda.conv"):
+            qq, kk, vv = cls._streams(
+                arch, blk, jnp.split(held, back, axis=-1) + [new], heads)
+            rolled = jnp.concatenate(
+                [held[:, new.shape[-1]:], new.astype(held.dtype)], -1)
+            conv = jnp.where(active[:, None], rolled, held)
+        with jax.named_scope("attn.attend"), jax.named_scope("kda.state"):
+            y, state = delta_rule.step(
+                qq, kk, vv, rows["g"][:, 0], rows["beta"][:, 0],
+                fixed["kda_S"], active)
+        att = cls._finish(arch, blk, y, q[:, 0])
+        return att[:, None], {"kda_S": state, "kda_conv": conv}
+
+    out = Grouped.out
+
+
 #: a block's kind by the name a model declares for it (``Arch.layers``)
 KINDS = {"mha": FusedQKV, "mla": Latent, "gqa": Grouped,
          "conv": ShortConv, "ret": Retention, "swa": Windowed,
-         "nope": Global}
+         "nope": Global, "kda": DeltaRule}
 
 
 def _kind(name):
@@ -1168,6 +1393,6 @@ def block_forward(arch, blk, x, heads, positions, live=None, kind=None):
     if kind is None:
         kind, = block_kinds(arch, 1)
     q, rows = kind.project(arch, blk, x, heads, positions)
-    x, _ = block_rest(arch, blk, kind, x,
-                      kind.attend_prompt(arch, blk, q, rows), live)
-    return x, kind.keep(arch, rows, live)
+    att, kept = kind.prompt(arch, blk, q, rows, live)
+    x, _ = block_rest(arch, blk, kind, x, att, live)
+    return x, kind.keep(arch, rows, live) if kept is None else kept

@@ -522,6 +522,11 @@ class ServingHealth:
             if paths is not None:
                 # ... and by how they take the retention state through
                 snap["counters"]["state_path"] = dict(paths)
+            chunks = getattr(getattr(deploy, "decoder", None),
+                             "kda_prompt_chunks", None)
+            if chunks is not None:
+                # the admissions' chunks through the delta rule's form
+                snap["counters"]["kda_prompt_chunks"] = chunks
             paths = getattr(getattr(deploy, "decoder", None),
                             "write_paths", None)
             if paths is not None:
@@ -1095,10 +1100,18 @@ class ContinuousDecoder:
         #: model without such blocks
         self.prompt_paths = None
         self.admits_past_window = 0
+        #: chunks of ``ops/delta_rule.CHUNK`` positions that admissions
+        #: ran through the delta rule's chunked form, over the ``"kda"``
+        #: blocks (``blocks.prompt_chunks``); None for a model without
+        #: such blocks
+        self.kda_prompt_chunks = None
         if not self.paged:
-            from veles_tpu.parallel.blocks import prompt_attend_path
+            from veles_tpu.parallel.blocks import (prompt_attend_path,
+                                                   prompt_chunks)
             if prompt_attend_path(params, 1, 16, heads) is not None:
                 self.prompt_paths = {"kernel": 0, "xla": 0}
+            if prompt_chunks(params, 1, 16) is not None:
+                self.kda_prompt_chunks = 0
         self._layout_said = False
         self.pool = None
         self._paged_fns = None
@@ -1463,10 +1476,14 @@ class ContinuousDecoder:
 
     def bucket_for(self, n):
         """The admission bucket an ``n``-token prompt (or tail)
-        actually prefills under: the power-of-two bucket clamped to
-        ``max_len`` — ONE definition for the admit paths, the
-        page-reservation bound and the request ledger's attribution."""
-        return min(self._bucket(n), self.max_len)
+        actually prefills under: the power-of-two bucket, at least the
+        model's ``Arch.prompt_bucket``, clamped to ``max_len`` — ONE
+        definition for the admit paths, the page-reservation bound and
+        the request ledger's attribution."""
+        from veles_tpu.parallel.blocks import arch_of
+
+        return min(max(self._bucket(n), arch_of(self.params).prompt_bucket),
+                   self.max_len)
 
     def _admit_pending(self):
         if self.paged:
@@ -1516,6 +1533,11 @@ class ContinuousDecoder:
             said = self._book_moe_path(len(rows) * bucket // parts)
             said.update(self._book_prompt_path(
                 len(rows) // parts, bucket, [len(r[1]) for r in group]))
+            if self.kda_prompt_chunks is not None:
+                from veles_tpu.parallel.blocks import prompt_chunks
+                said["kda_prompt_chunks"] = prompt_chunks(
+                    self.params, len(rows), bucket)
+                self.kda_prompt_chunks += said["kda_prompt_chunks"]
             if self.state_paths is not None:
                 # the path the state this admission sets will take
                 said["state_path"] = self._state_path()
